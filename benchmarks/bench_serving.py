@@ -21,11 +21,6 @@ reports:
     XLA compile time is excluded;
   * the **prefix-hit rate** of the shared-prefix schedule on the
     chunked config (sessions re-using previously prefilled pages);
-  * **tensor parallelism**: tp=1 vs tp=4 tokens/s and per-device KV
-    bytes, measured in a subprocess forced to 4 host devices (the
-    ``--xla_force_host_platform_device_count`` flag must precede jax
-    init, so the sharded engine can't run in this process) — token
-    parity sharded-vs-unsharded asserted as a by-product;
   * **speculative decoding**: accept-rate and effective tokens/s at
     spec_k in {0, 2, 4} on a decode-heavy prompt-lookup harness, with
     bit-identical streams asserted and an effective-throughput gate
@@ -273,63 +268,6 @@ def _latency_bench(cfg, qp, plans, quick: bool) -> dict:
     return out
 
 
-# child script for the tensor-parallel measurement: the forced device
-# count only takes effect before jax initializes, so it cannot run in
-# this (already-1-device) process
-_TP_CHILD = """\
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import sys
-sys.path.insert(0, {src!r})
-sys.path.insert(0, {here!r})
-import json
-import jax
-assert jax.device_count() == 4, jax.device_count()
-from bench_serving import _build, _engine, _prompts, _serve
-quick = {quick!r}
-# tp=4 must divide Hkv: lift the reduced config's head counts to 4/4
-cfg, qp, plans = _build(quick, n_heads=4, n_kv_heads=4)
-prompts = _prompts(cfg, quick)
-max_new = 4 if quick else 8
-pool = dict(cache_mode="paged", page_size=16, num_pages=7)
-out = {{"devices": jax.device_count()}}
-toks = {{}}
-for tp in (1, 4):
-    c, t = _serve(cfg, qp, plans, prompts, max_new, tp=tp, **pool)
-    toks[tp] = t
-    eng = _engine(cfg, qp, plans, tp=tp, **pool)
-    d = eng.describe()["tp"]
-    out["tp%d" % tp] = {{
-        "tokens_per_s": c["tokens_per_s"],
-        "mode": d["mode"],
-        "kv_bytes": c["kv_bytes"],
-        "per_device_kv_bytes": d["per_device_kv_bytes"],
-    }}
-out["parity"] = toks[1] == toks[4]
-assert out["parity"], "tp=4 token streams diverged from tp=1"
-assert out["tp4"]["mode"] == "sharded", out["tp4"]
-print("TPJSON " + json.dumps(out))
-"""
-
-
-def _tp_bench(quick: bool) -> dict:
-    import subprocess
-    import sys
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    src = os.path.abspath(os.path.join(here, "..", "src"))
-    code = _TP_CHILD.format(src=src, here=here, quick=quick)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)      # the child sets its own, pre-import
-    out = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True, timeout=900,
-                         env=env)
-    assert out.returncode == 0, out.stderr[-3000:]
-    line = [ln for ln in out.stdout.splitlines()
-            if ln.startswith("TPJSON ")][-1]
-    return json.loads(line[len("TPJSON "):])
-
-
 def run(quick: bool = False):
     cfg, qp, plans = _build(quick)
     prompts = _prompts(cfg, quick)
@@ -366,12 +304,11 @@ def run(quick: bool = False):
     assert kv4_reduction >= 1.8, (
         f"int4 KV pages reduce kv_bytes only {kv4_reduction:.2f}x "
         "(gate: >= 1.8x at equal page count)")
-    tp = _tp_bench(quick)
     spec = _spec_bench(cfg, qp, plans, quick)
     latency = _latency_bench(cfg, qp, plans, quick)
 
     with open(JSON_PATH, "w") as f:
-        json.dump({"configs": configs, "parity": parity, "tp": tp,
+        json.dump({"configs": configs, "parity": parity,
                    "spec": spec, "latency": latency, "arch": cfg.name,
                    "quick": quick},
                   f, indent=2)
@@ -410,15 +347,6 @@ def run(quick: bool = False):
                      1e-9))
     rows.append(("serving_chunked_prefill_speedup", round(speedup, 2),
                  "chunked vs token-streaming prefill tokens/s"))
-    for name in ("tp1", "tp4"):
-        rows.append((f"serving_tokens_per_s[{name}]",
-                     tp[name]["tokens_per_s"],
-                     f"mode={tp[name]['mode']}, 4-device child, "
-                     "parity verified"))
-    rows.append(("serving_per_device_kv_bytes[tp4]",
-                 tp["tp4"]["per_device_kv_bytes"],
-                 f"of {tp['tp4']['kv_bytes']} global (Hkv/4 heads of "
-                 "every page per device)"))
     for k in (0, 2, 4):
         c = spec["k%d" % k]
         note = "spec off (baseline)" if k == 0 else (

@@ -25,14 +25,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 NUM = (int, float)
 
-# one tp degree of the tensor-parallel measurement (4-device child)
-TP_CONFIG = {
-    "tokens_per_s": NUM,
-    "mode": str,                    # "sharded" / "gathered" / "off"
-    "kv_bytes": int,
-    "per_device_kv_bytes": int,
-}
-
 SERVING_CONFIG = {
     "tokens": int,
     "tokens_per_s": NUM,
@@ -95,12 +87,6 @@ SCHEMAS = {
     "BENCH_serving.json": {
         "configs": {...: SERVING_CONFIG},
         "parity": bool,
-        "tp": {
-            "devices": int,
-            "parity": bool,
-            "tp1": TP_CONFIG,
-            "tp4": TP_CONFIG,
-        },
         "spec": {
             "k0": SPEC_CONFIG,
             "k2": SPEC_CONFIG,

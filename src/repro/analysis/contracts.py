@@ -80,13 +80,84 @@ class LaunchReport:
     scalar_prefetch: tuple = ()
 
 
-def fit_block(blk: int, dim: int) -> int:
-    """Pure twin of ``ops.backends.pallas._fit_block``: the largest
-    block <= ``blk`` dividing ``dim``."""
-    blk = min(blk, dim)
-    while dim % blk:
-        blk -= 1
-    return blk
+def fit_block(blk: int, dim: int, align: int = 1) -> int:
+    """The block the backends launch with: the largest divisor of
+    ``dim`` that is <= ``blk`` and a multiple of ``align`` — or ``dim``
+    itself when none is (a whole-dim block is always chip-legal, see
+    :func:`tpu_block_violations`).  ``align=1`` is the plain largest
+    divisor <= ``blk``."""
+    if dim <= blk:
+        return dim
+    for b in range(blk - blk % align, 0, -align):
+        if dim % b == 0:
+            return b
+    return dim
+
+
+#: VMEM one kernel launch may use on a TPU v5e, as the topology
+#: compile measures it (``tests/test_chip_compile.py`` rehearses the
+#: edges): a matmul whose pipelined blocks come to about 50 MiB
+#: compiles and one of about 67 MiB runs out of VMEM; a folded-wo
+#: attention launch with a 15 MiB ``wo`` block compiles and one with
+#: 16 MiB (Llama-3-8B's 4096 x 4096) does not.  ``vmem_bytes`` below is
+#: the estimate held to it.
+VMEM_BUDGET = 64 << 20
+
+#: the TPU's native (sublane, lane) tile: a block's last two dims must
+#: each divide by these — or equal the array's own dims
+TPU_TILE = (8, 128)
+
+
+def tpu_block_violations(name: str, block, array) -> list:
+    """The chip compiler's block-shape rule (Mosaic), stated offline.
+
+    A ``BlockSpec`` block of an N-d operand is legal only if its last
+    two dims are each a multiple of the native tile — 8 sublanes, 128
+    lanes — or equal to the array's dims there.  A 1-d block must span
+    the whole array (the compiler lays 1-d blocks out as one row and
+    refuses partial ones).  Interpret mode checks none of this, which
+    is why the kernels once passed every CPU test and failed to compile
+    on the chip.  Returns one human-readable reason per violation."""
+    block, array = tuple(block), tuple(array)
+    if len(block) != len(array):
+        return [f"{name}: block {block} has a different rank from its "
+                f"array {array}"]
+    if len(block) == 1:
+        if block != array:
+            return [f"{name}: 1-d block {block} must span the whole "
+                    f"array {array}"]
+        return []
+    out = []
+    for pos, tile in zip((-2, -1), TPU_TILE):
+        b, a = block[pos], array[pos]
+        if b != a and b % tile:
+            out.append(f"{name}: block {block} over {array} — dim {pos} "
+                       f"({b}) must be a multiple of {tile} or equal "
+                       f"{a} (TPU last-two-dims rule)")
+    return out
+
+
+def vmem_violations(op: str, vmem: int) -> list:
+    """The VMEM clause: a launch whose estimate exceeds
+    :data:`VMEM_BUDGET` is refused here, before the chip's compiler
+    refuses it."""
+    if vmem <= VMEM_BUDGET:
+        return []
+    return [f"{op}: VMEM estimate {vmem / 2**20:.1f} MiB exceeds the "
+            f"{VMEM_BUDGET >> 20} MiB budget"]
+
+
+def check_blocks(op: str, blocks: dict) -> LaunchReport:
+    """Validate a launch's operand blocks against the chip's block
+    rule alone: ``blocks`` maps operand name -> ``(block_shape,
+    array_shape)``.  The per-kernel checks below run every block they
+    launch through this rule; it is public so a layout can be judged
+    before a kernel exists for it."""
+    reasons = []
+    for name, (block, array) in blocks.items():
+        reasons += tpu_block_violations(name, block, array)
+    return LaunchReport(op=op, ok=not reasons, fused=not reasons,
+                        reasons=tuple(reasons))
 
 
 # ---------------------------------------------------------------- policy --
@@ -142,15 +213,21 @@ def _check_int8_matmul(m, n, k, bm=128, bn=128, bk=512, out_bits=8,
     if packed and (k % 2 or bk % 2):
         reasons.append("packed weights pair nibbles along K: K and bk "
                        f"must be even (got K={k}, bk={bk})")
-    # packed operands halve the weight-block bytes: the w block is
-    # (bk // 2, bn) int8 nibbles, unpacked in-register
-    vmem = bm * bk + (bk // 2 if packed else bk) * bn \
-        + bm * bn * 4                               # x8 + w + acc scratch
-    vmem += bm * bn * (1 if out_bits <= 8 else 4)   # output block
+    kw, bkw = (k // 2, bk // 2) if packed else (k, bk)
+    blocks = {"x8": ((bm, bk), (m, k)), "w": ((bkw, bn), (kw, n)),
+              "out": ((bm, bn), (m, n))}
     if has_bias:
-        vmem += bn * 4
+        blocks["bias32"] = ((1, bn), (1, n))
     if per_channel:
-        vmem += bn * 4
+        blocks["b_vec"] = ((1, bn), (1, n))
+    reasons += check_blocks("int8_matmul", blocks).reasons
+    # pipelined blocks are double-buffered; packed operands halve the
+    # weight block: (bk // 2, bn) int8 nibbles, unpacked in-register
+    blk = bm * bk + (bk // 2 if packed else bk) * bn \
+        + bm * bn * (1 if out_bits <= 8 else 4)     # x8 + w + out
+    blk += bn * 4 * (int(has_bias) + int(per_channel))
+    vmem = 2 * blk + bm * bn * 4                    # + acc scratch
+    reasons += vmem_violations("int8_matmul", vmem)
     return LaunchReport(
         op="int8_matmul_packed" if packed else "int8_matmul",
         ok=not reasons, fused=not reasons,
@@ -171,9 +248,67 @@ def _attn_common(h, hkv, reasons):
         reasons.append(f"GQA requires Hkv | H: got H={h}, Hkv={hkv}")
 
 
+def _attn_blocks(q_rows, rows, h, hkv, d, kv_lead, kv_rows, bkv, kv_d,
+                 per_channel, fold, n_out):
+    """The fused attention launches' operand blocks (all heads per
+    block; head-major output), as ``check_blocks`` input.  ``q_rows`` /
+    ``rows``: the query axis length and its block; ``kv_lead`` /
+    ``kv_rows``: the cache's leading dims (batch or pages, length or
+    page size)."""
+    blocks = {
+        "q": ((1, rows, h, d), (1, q_rows, h, d)),
+        "k": ((1, bkv, hkv, kv_d), (kv_lead, kv_rows, hkv, kv_d)),
+        "v": ((1, bkv, hkv, kv_d), (kv_lead, kv_rows, hkv, kv_d)),
+    }
+    if per_channel:
+        blocks["b_vec"] = ((h, d), (h, d))
+    if fold:
+        blocks["wo"] = ((h * d, n_out), (h * d, n_out))
+        blocks["wo_vec"] = ((1, n_out), (1, n_out))
+        blocks["out"] = ((1, rows, n_out), (1, q_rows, n_out))
+    else:
+        blocks["out"] = ((1, h, rows, d), (1, h, q_rows, d))
+    return blocks
+
+
+def _attn_vmem(rows, h, hkv, d, bkv, kv_d, out_elem, per_channel, fold,
+               n_out):
+    """Per-step VMEM estimate of a fused attention launch: q / k / v /
+    output blocks and epilogue operands, each double-buffered, plus the
+    per-head m/s/acc scratch.  A folded ``wo`` block counts four times:
+    two pipeline buffers, and the per-head ``(D, N)`` slab reads stage
+    about as much again (the topology compile of a 16 MiB block runs
+    out of VMEM where a 50 MiB matmul does not)."""
+    blk = rows * h * d + 2 * bkv * hkv * kv_d       # q + k + v blocks
+    if per_channel:
+        blk += h * d * 4
+    if fold:
+        blk += 2 * n_out * 4 + rows * n_out * out_elem  # vectors + out
+    else:
+        blk += h * rows * d * out_elem
+    vmem = 2 * blk + 2 * h * rows * 4 + h * rows * d * 4  # + m/s/acc
+    if fold:
+        vmem += 4 * h * d * n_out + rows * n_out * 4  # wo + accumulator
+    return vmem
+
+
+def can_fold_wo(rows, h, hkv, d, bkv, n_out, kv_d=None,
+                per_channel=False) -> bool:
+    """Folded-wo policy (pallas_fused backend): fold the o-projection
+    into the attention launch only while the whole ``(H·D, N)`` block
+    keeps the launch inside :data:`VMEM_BUDGET`; otherwise the backend
+    runs the attention kernel unfolded and ``wo`` through its own
+    matmul, with identical integers."""
+    return _attn_vmem(rows, h, hkv, d, bkv, d if kv_d is None else kv_d,
+                      1, per_channel, True, n_out) <= VMEM_BUDGET
+
+
 def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
                          out_bits=8, per_channel=False,
                          min_block=MIN_BLOCK, online=False):
+    """The fused prefill launch (``online=True``: the ``pallas``
+    backend's one-pass kernel, which is off the chip path and not held
+    to the chip's block rule)."""
     op = "int_attention_online" if online else "int_attention"
     bq, bkv = min(bq, sq), min(bkv, skv)    # the kernels' own clamping
     reasons, policy = [], []
@@ -185,21 +320,24 @@ def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
     if sq % bq or skv % bkv:
         reasons.append(f"blocks must divide (Sq,Skv)=({sq},{skv}): "
                        f"(bq,bkv)=({bq},{bkv})")
+    if not online:
+        reasons += check_blocks(op, _attn_blocks(
+            sq, bq, h, hkv, d, b, skv, bkv, d, per_channel, False,
+            0)).reasons
     if not can_tile(sq, skv, bq, bkv, min_block):
         policy.append(f"tiling policy declines: sq={sq}, skv={skv}, "
                       f"bq={bq}, bkv={bkv}, min_block={min_block}")
     out_elem = 1 if (online or out_bits <= 8) else 4
-    vmem = (bq * d + 2 * bkv * d                    # q + k + v blocks
-            + bq * d * out_elem                     # output block
-            + 2 * bq * 4 + bq * d * 4)              # m/s/acc scratch
-    if per_channel:
-        vmem += d * 4
+    vmem = _attn_vmem(bq, h, hkv, d, bkv, d, out_elem, per_channel,
+                      False, 0)
+    if not online:
+        reasons += vmem_violations(op, vmem)
     if sq % bq or skv % bkv:
         grid = ()
     elif online:
         grid = (b, h, sq // bq, skv // bkv)
     else:
-        grid = (b, h, sq // bq, 3, skv // bkv)
+        grid = (b, sq // bq, 3, skv // bkv)
     return LaunchReport(
         op=op, ok=not reasons, fused=not (reasons or policy),
         reasons=tuple(reasons + policy), grid=grid,
@@ -240,6 +378,11 @@ def _check_int_decode_attention(b, sq, h, hkv, d, L=None, bkv=128,
     if fold and not n_out:
         reasons.append("folded wo projection needs n_out (= wo_w8 "
                        "output channels)")
+    kv_d = d // 2 if kv_pack else d
+    reasons += check_blocks("int_decode_attention", _attn_blocks(
+        sq, sq, h, hkv, d, max(num_pages, 1) if paged else b,
+        page_size if paged else L, bkv, kv_d, per_channel, fold,
+        n_out)).reasons
     if not can_tile_decode(sq, L, d, bkv, min_block):
         policy.append(f"tiling policy declines: sq={sq}, L={L}, d={d}, "
                       f"bkv={bkv}, min_block={min_block}")
@@ -248,23 +391,14 @@ def _check_int_decode_attention(b, sq, h, hkv, d, L=None, bkv=128,
         prefetch.append(("pages", (b, max_pages)))
     if kv_pack:
         # per-page dequant shifts ride as two more scalar-prefetch
-        # operands; K/V blocks hold (bkv, d // 2) nibbles
+        # operands; K/V blocks hold (bkv, Hkv, d // 2) nibbles
         prefetch.append(("k_shift", (num_pages,)))
         prefetch.append(("v_shift", (num_pages,)))
-    kv_elem = d // 2 if kv_pack else d
-    vmem = (sq * d + 2 * bkv * kv_elem              # q + k + v blocks
-            + 2 * sq * 4 + sq * d * 4)              # m/s/acc scratch
-    if per_channel:
-        vmem += d * 4
-    if fold:
-        vmem += (d * n_out                          # wo weight slab
-                 + sq * d                           # int8 attention tile
-                 + sq * n_out * 4                   # wo accumulator
-                 + sq * n_out)                      # output block
-    else:
-        vmem += sq * d * (1 if out_bits <= 8 else 4)
-    grid = (b, h, 3, L // bkv) if not (L % bkv if not paged
-                                       else page_size % bkv) else ()
+    vmem = _attn_vmem(sq, h, hkv, d, bkv, kv_d,
+                      1 if out_bits <= 8 else 4, per_channel, fold, n_out)
+    reasons += vmem_violations("int_decode_attention", vmem)
+    grid = (b, 3, L // bkv) if not (L % bkv if not paged
+                                    else page_size % bkv) else ()
     return LaunchReport(
         op="int_decode_attention", ok=not reasons,
         fused=not (reasons or policy), reasons=tuple(reasons + policy),
@@ -296,23 +430,21 @@ def _check_int_paged_prefill(b, c, h, hkv, d, max_pages, page_size,
     if fold and not n_out:
         reasons.append("folded wo projection needs n_out (= wo_w8 "
                        "output channels)")
+    kv_d = d // 2 if kv_pack else d
+    reasons += check_blocks("int_paged_prefill", _attn_blocks(
+        c, bq, h, hkv, d, max(num_pages, 1), page_size, bkv, kv_d,
+        per_channel, fold, n_out)).reasons
     if not can_tile_prefill(L, d, bq, bkv, min_block):
         policy.append(f"tiling policy declines: L={L}, d={d}, bq={bq}, "
                       f"bkv={bkv}, min_block={min_block}")
-    kv_elem = d // 2 if kv_pack else d
-    vmem = (bq * d + 2 * bkv * kv_elem
-            + 2 * bq * 4 + bq * d * 4)
-    if per_channel:
-        vmem += d * 4
-    if fold:
-        vmem += (d * n_out + bq * d + bq * n_out * 4 + bq * n_out)
-    else:
-        vmem += bq * d * (1 if out_bits <= 8 else 4)
+    vmem = _attn_vmem(bq, h, hkv, d, bkv, kv_d,
+                      1 if out_bits <= 8 else 4, per_channel, fold, n_out)
+    reasons += vmem_violations("int_paged_prefill", vmem)
     prefetch = [("pos_end", (b,)), ("pages", (b, max_pages))]
     if kv_pack:
         prefetch.append(("k_shift", (num_pages,)))
         prefetch.append(("v_shift", (num_pages,)))
-    grid = (b, c // bq, h, 3, L // bkv) \
+    grid = (b, c // bq, 3, L // bkv) \
         if not (c % bq or page_size % bkv) else ()
     return LaunchReport(
         op="int_paged_prefill", ok=not reasons,
@@ -473,7 +605,8 @@ def require_request(prompt_len: int, max_new_tokens: int, cache_len: int,
 
 __all__ = [
     "KernelContractError", "LaunchReport", "MAX_SKV_ONLINE", "MIN_BLOCK",
-    "RequestInfeasible", "can_tile", "can_tile_decode",
-    "can_tile_prefill", "check_launch", "check_request",
+    "RequestInfeasible", "TPU_TILE", "can_tile", "can_tile_decode",
+    "can_tile_prefill", "check_blocks", "check_launch", "check_request",
     "check_tp_launch", "fit_block", "require_launch", "require_request",
+    "tpu_block_violations",
 ]

@@ -234,7 +234,7 @@ def check_int_attention(ia, seq_len: int, layer: str,
                         op: str = "int_attention"):
     t = _Track()
     out, exact = _attention_core(ia, seq_len, layer, op, t)
-    bq = contracts.fit_block(128, seq_len)
+    bq = contracts.fit_block(128, seq_len, 8)
     bkv = contracts.fit_block(128, seq_len)
     fused = contracts.can_tile(seq_len, seq_len, bq, bkv)
     path = "fused" if fused else \
@@ -244,12 +244,15 @@ def check_int_attention(ia, seq_len: int, layer: str,
 
 def check_int_decode_attention(ia, cache_len: int, layer: str,
                                sq: int = MAX_SQ, kv_pack: bool = False,
+                               page_size: int = 0,
                                op: str = "int_decode_attention"):
+    """``page_size``: the paged pool's page (the KV block tiles a page);
+    0 = the contiguous layout (the block tiles ``cache_len``)."""
     t = _Track()
     kv_qmax = INT4_KV.qmax if kv_pack else 127
     out, exact = _attention_core(ia, cache_len, layer, op, t,
                                  kv_qmax=kv_qmax)
-    bkv = contracts.fit_block(128, cache_len)
+    bkv = contracts.fit_block(128, page_size or cache_len)
     fused = contracts.can_tile_decode(sq, cache_len, ia.head_dim, bkv)
     path = "fused" if fused else \
         ("fallback:two-pass-streaming" if not exact else "fallback:oracle")
@@ -260,11 +263,15 @@ def check_int_decode_attention(ia, cache_len: int, layer: str,
 def check_int_paged_prefill(ia, cache_len: int, layer: str,
                             chunk: int = 256, page_size: int = 64,
                             wo=None, n_heads: int = 0,
+                            n_kv_heads: int = 0, n_out: int = 0,
                             kv_pack: bool = False,
                             op: str = "int_paged_prefill"):
     """``wo``: the o-projection ``LinearPlan`` when certifying the
     folded-wo launch epilogue (int8 attention tile → int8 matmul →
-    per-channel requant inside the same kernel)."""
+    per-channel requant inside the same kernel).  With the head counts
+    and ``n_out`` the report notes a projection too wide to fold into
+    the chip's VMEM (the backend then runs it unfolded, same integers:
+    ``contracts.can_fold_wo``)."""
     t = _Track()
     kv_qmax = INT4_KV.qmax if kv_pack else 127
     out, exact = _attention_core(ia, cache_len, layer, op, t,
@@ -277,13 +284,18 @@ def check_int_paged_prefill(ia, cache_len: int, layer: str,
             IntRange.symmetric(t.vals[-1][1]), wo.c, wo.pre,
             b_max=plan_b_max(wo), what="folded wo requant",
             op=op, layer=layer))
-    bq = contracts.fit_block(128, chunk)
+    bq = contracts.fit_block(128, chunk, 8)
     bkv = contracts.fit_block(128, page_size)
     fused = contracts.can_tile_prefill(cache_len, ia.head_dim, bq, bkv)
     path = "fused" if fused else \
         ("fallback:two-pass-streaming" if not exact else "fallback:oracle")
+    notes = ["int4 kv pages"] if kv_pack else []
+    if wo is not None and n_out and not contracts.can_fold_wo(
+            bq, n_heads, n_kv_heads, ia.head_dim, bkv, n_out,
+            kv_d=ia.head_dim // 2 if kv_pack else ia.head_dim):
+        notes.append("wo unfolded (VMEM)")
     return out, OpReport(op, layer, t.worst, path=path,
-                         note="int4 kv pages" if kv_pack else "")
+                         note="; ".join(notes))
 
 
 def check_requant_spec(spec, r: IntRange, op: str, layer: str,
@@ -351,11 +363,14 @@ def _check_mamba(m, cfg, ops, assumptions):
 
 
 def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
-                   calib: dict = None) -> ConfigReport:
+                   calib: dict = None, page_size: int = 64,
+                   chunk: int = 256) -> ConfigReport:
     """Statically certify one :class:`repro.models.common.ArchConfig`:
     every op of the integer datapath at worst case, at ``(seq_len,
-    cache_len)``.  Raises :class:`BitBudgetError` (typed: op + layer +
-    worst value) on any int32 overflow; returns the report otherwise."""
+    cache_len)``, with the serving engine's paged pool geometry
+    (``page_size``, prefill ``chunk``) for the predicted kernel paths.
+    Raises :class:`BitBudgetError` (typed: op + layer + worst value) on
+    any int32 overflow; returns the report otherwise."""
     from repro.quant.plans import LinearPlan, build_layer_plans
     plans = build_layer_plans(cfg, calib)
     ops, assumptions = [], [
@@ -398,19 +413,24 @@ def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
                      layer="attn.out")
         if cfg.is_causal:
             _, rep = check_int_decode_attention(
-                plans.attn.attn, cache_len, "attn.decode")
+                plans.attn.attn, cache_len, "attn.decode",
+                page_size=page_size)
             ops.append(rep)
             _, rep = check_int_decode_attention(
                 plans.attn.attn, cache_len, "attn.decode[kv4]",
-                kv_pack=True)
+                kv_pack=True, page_size=page_size)
             ops.append(rep)
             _, rep = check_int_paged_prefill(
                 plans.attn.attn, cache_len, "attn.prefill",
-                wo=plans.attn.out, n_heads=cfg.n_heads)
+                chunk=chunk, page_size=page_size,
+                wo=plans.attn.out, n_heads=cfg.n_heads,
+                n_kv_heads=cfg.n_kv_heads, n_out=cfg.d_model)
             ops.append(rep)
             _, rep = check_int_paged_prefill(
                 plans.attn.attn, cache_len, "attn.prefill[kv4]",
-                wo=plans.attn.out, n_heads=cfg.n_heads, kv_pack=True)
+                chunk=chunk, page_size=page_size,
+                wo=plans.attn.out, n_heads=cfg.n_heads,
+                n_kv_heads=cfg.n_kv_heads, n_out=cfg.d_model, kv_pack=True)
             ops.append(rep)
     elif plans.ffn is not None:
         # no attention projections: certify the packed weight tier on

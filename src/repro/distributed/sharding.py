@@ -29,23 +29,8 @@ LOGICAL_RULES = {
 }
 
 
-def get_abstract_mesh():
-    """The mesh currently in scope, or an empty mesh.
-
-    jax >= 0.5 exposes ``jax.sharding.get_abstract_mesh``; on older
-    releases the ``with Mesh(...)`` context lives in the thread-resources
-    env, whose physical mesh carries the same ``empty`` / ``axis_names`` /
-    ``axis_sizes`` surface this module needs.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as _mesh_lib
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
 def current_axes() -> Tuple[str, ...]:
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return ()
     return tuple(mesh.axis_names)
@@ -84,7 +69,7 @@ def shard_residual(x):
     axes = current_axes()
     if not axes:
         return x
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     msize = dict(zip(mesh.axis_names, mesh.axis_sizes)).get("model", 1)
     if x.ndim >= 2 and msize > 1 and x.shape[1] % msize == 0 \
             and x.shape[1] >= msize * 16:
@@ -154,7 +139,7 @@ def constrain_like_params(tree):
     FSDP-sharded stacked weights out of the while loop (gathering every
     layer at once — 100+ GiB); with the in-body constraint the gather
     applies to one layer's slice at a time."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return tree
     from repro.launch.shardings import param_pspecs  # lazy: avoid cycle

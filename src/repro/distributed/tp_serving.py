@@ -56,17 +56,6 @@ from repro.ops.spec import QuantLinearParams
 TP_AXIS = "tp"
 
 
-def shard_map_fn():
-    """The shard_map entry point, version-compatible: ``jax.shard_map``
-    on new releases, ``jax.experimental.shard_map.shard_map`` on 0.4.x."""
-    import jax
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def tp_arch_supported(cfg: ArchConfig) -> bool:
     """Whether the head-sharded serving step serves this arch: every
     sublayer must be plain self-attention (+ dense FFN or MoE — both run
@@ -80,8 +69,8 @@ def tp_arch_supported(cfg: ArchConfig) -> bool:
 def validate_tp(cfg: ArchConfig, tp: int) -> None:
     """Typed validation of a tensor-parallel degree (engine / CLI
     boundary — fail here, not as a kernel-shape error inside a launch).
-    Device availability is checked separately (the exact single-device
-    gather lowering needs no devices at all)."""
+    Device availability is checked by the engine, once the lowering is
+    known (the exact single-device gather lowering needs no devices)."""
     if tp < 1:
         raise ValueError(f"tp must be >= 1, got {tp}")
     if tp == 1:

@@ -9,7 +9,10 @@ re-targeted to the TPU MXU:
     fused bias + dyadic-requant + clip on the *last* K-step while the tile
     is still VMEM-resident — the INT32 accumulator never round-trips HBM;
   * per-channel weight scales are a (N,) vector of dyadic multipliers
-    blocked along with the output columns.
+    blocked along with the output columns — passed as ``(1, N)`` with
+    ``(1, bn)`` blocks, like the bias, because the chip's compiler
+    refuses partial 1-d blocks (``analysis.contracts.
+    tpu_block_violations``).
 
 Block shapes default to MXU-aligned (128, 128) tiles with bk=512 int8 —
 VMEM per step: bm*bk + bk*bn (int8) + bm*bn*4 (int32 acc) = 192 KiB,
@@ -26,6 +29,7 @@ from jax.experimental import pallas as pl
 
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.dyadic import Dyadic
+from repro.kernels import resolve_interpret
 
 
 def _rshift_round(x, s: int):
@@ -74,12 +78,12 @@ def _mm_kernel(*refs, n_k: int, has_bias: bool, has_bvec: bool,
     def _epilogue():
         acc = acc_ref[...]
         if has_bias:
-            acc = acc + bias_ref[...].astype(jnp.int32)[None, :]
+            acc = acc + bias_ref[...].astype(jnp.int32)     # (1, bn)
         if raw:                                        # int32 accumulator out
             o_ref[...] = acc.astype(out_dtype)
             return
         if has_bvec:                                   # per-channel requant
-            b = bvec_ref[...].astype(jnp.int32)[None, :]
+            b = bvec_ref[...].astype(jnp.int32)            # (1, bn)
             out = _requant_tile(acc, b, dn_c, dn_pre)
         else:                                          # per-tensor requant
             out = _requant_tile(acc, jnp.int32(dn_b), dn_c, dn_pre)
@@ -91,13 +95,16 @@ def int8_matmul_pallas(x8, w8, bias32=None, dn: Dyadic = None,
                        b_vec=None, c: int = 0, pre: int = 0,
                        out_bits: int = 8, out_dtype=jnp.int8,
                        bm: int = 128, bn: int = 128, bk: int = 512,
-                       packed: bool = False, interpret: bool = True):
+                       packed: bool = False,
+                       interpret: Optional[bool] = None):
     """x8: (M, K) int8; w8: (K, N) int8; bias32: (N,) int32 or None.
 
     Epilogue: ``dn`` (per-tensor) / (``b_vec``, c, pre) (per-channel) /
     neither (**raw**: the int32 accumulator plus bias is written out,
     ``out_dtype`` must be int32).  M/K/N must divide by the (clamped)
-    block shapes.
+    block shapes, and the blocks must be chip-legal: bm a multiple of 8,
+    bn and bk multiples of 128, or the whole dim
+    (``ops.backends.pallas._matmul_blocks`` fits them).
 
     ``packed=True`` switches the weight operand to int4 nibbles:
     ``w8`` is the ``(K // 2, N)`` packed array
@@ -141,12 +148,10 @@ def int8_matmul_pallas(x8, w8, bias32=None, dn: Dyadic = None,
                      lambda i, j, s: (s, j)),
     ]
     args = [x8, w8]
-    if bias32 is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, s: (j,)))
-        args.append(bias32)
-    if b_vec is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, s: (j,)))
-        args.append(b_vec)
+    for vec in (bias32, b_vec):
+        if vec is not None:
+            in_specs.append(pl.BlockSpec((1, bn), lambda i, j, s: (0, j)))
+            args.append(jnp.asarray(vec, jnp.int32).reshape(1, n))
 
     from jax.experimental.pallas import tpu as pltpu
     return pl.pallas_call(
@@ -156,5 +161,5 @@ def int8_matmul_pallas(x8, w8, bias32=None, dn: Dyadic = None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)

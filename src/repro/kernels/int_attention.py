@@ -24,6 +24,7 @@ to 2^16; the wrapper asserts L <= 65536.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,7 @@ from jax.experimental import pallas as pl
 
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
+from repro.kernels import resolve_interpret
 from repro.kernels.int_softmax import _exp16_tile, _rshift_round
 
 NEG = -(2 ** 30)
@@ -106,7 +108,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref, *,
 
 def int_attention_pallas(q8, k8, v8, plan: IAttnPlan, causal: bool = True,
                          window: int = 0, bq: int = 128, bkv: int = 128,
-                         out_bits: int = 8, interpret: bool = True):
+                         out_bits: int = 8,
+                         interpret: Optional[bool] = None):
     """q8: (B, Sq, H, D) int8; k8/v8: (B, Skv, Hkv, D) int8 (GQA: Hkv | H).
 
     Returns int8 (B, Sq, H, D) at plan.s_out.
@@ -142,5 +145,5 @@ def int_attention_pallas(q8, k8, v8, plan: IAttnPlan, causal: bool = True,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, d), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q8, k8, v8)

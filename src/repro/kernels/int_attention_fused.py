@@ -29,6 +29,15 @@ three :class:`repro.ops.RequantSpec` forms —
     flattened (head, head_dim) output channels
   * raw         — the int32 accumulator is written untouched
 
+Tiling: the caches keep their public ``(..., L, Hkv, D)`` layout, and
+every block takes *all* heads — queries ``(1, bq, H, D)``, K/V ``(1, bkv,
+Hkv, D)``, per-channel multipliers ``(H, D)`` — so each block's last two
+dims equal its array's, which is what the chip's compiler demands (a
+one-head ``(1, bkv, 1, D)`` block is refused; see
+``analysis.contracts.tpu_block_violations``).  The kernel body loops over
+the heads, loading each KV head's tile once per block for its whole GQA
+group.  ``bq`` / ``bkv`` are free of the (8, 128) tile rule.
+
 Bit budgets (mirroring ``core.softmax``): row sums need Skv ≤ 2¹⁵ so
 ``Σ e16 ≤ 2³⁰`` stays int32-exact; the P·V accumulator is bounded by
 ``(2⁷ + Skv/2)·127`` (normalised probabilities + rounding), int32-safe at
@@ -39,6 +48,7 @@ fall back to the two-pass path beyond it (see
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +58,7 @@ from repro.analysis.budgets import MAX_ROWSUM_LEN
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
 from repro.core.softmax import PROB_SHIFT, RECIP_BITS
+from repro.kernels import resolve_interpret
 from repro.kernels.int_softmax import _exp16_tile, _rshift_round
 from repro.ops.spec import PER_CHANNEL, PER_TENSOR, RequantSpec
 
@@ -58,17 +69,31 @@ NEG = -(2 ** 30)
 MAX_SKV = MAX_ROWSUM_LEN
 
 
-def _streaming_attn_body(phase, kv_step, n_kv, q8, k8, v8, live, blk_live,
-                         o_ref, m_ref, s_ref, acc_ref, b_ref, *,
-                         plan: IAttnPlan, requant: RequantSpec):
-    """The shared three-sweep streaming datapath + requant epilogue.
+def _streaming_attn_body(phase, kv_step, n_kv, live, blk_live, m_ref,
+                         s_ref, acc_ref, b_ref, *, n_heads: int, group: int,
+                         q_of, k_of, v_of, emit, plan: IAttnPlan,
+                         requant: RequantSpec, finish=None):
+    """The shared three-sweep streaming datapath + requant epilogue, over
+    every head of one query block.
+
+    The launches block K/V as ``(1, bkv, Hkv, D)`` and queries as
+    ``(1, rows, H, D)`` — all heads at once, so the blocks' last two
+    dims equal the arrays' (the chip's block rule) — and this body
+    loops over the heads inside the kernel: KV head ``g`` is loaded
+    once per block (``k_of(g)`` / ``v_of(g)``, an int8 ``(bkv, D)``
+    tile) and serves its ``group`` query heads (``q_of(h)``, ``(rows,
+    D)``).  Scratch is per head: ``m_ref`` / ``s_ref`` ``(H, rows, 1)``,
+    ``acc_ref`` ``(H, rows, D)``.  On the last step ``emit(h, tile)``
+    receives each head's requantized tile, then ``finish()`` runs.
 
     Everything downstream of mask construction is identical between the
-    prefill kernel and the decode kernel (``int_decode_attention.py``)
-    — only ``live`` (element mask) and ``blk_live`` (whole-block skip
-    predicate) differ, so both kernels delegate here and a numerics
+    prefill kernels and the decode kernel (``int_decode_attention.py``)
+    — only ``live`` (element mask, shared by all heads) and
+    ``blk_live`` (whole-block skip predicate) differ, so a numerics
     change lands in exactly one place.
     """
+    n_kv_heads = n_heads // group
+
     @pl.when((phase == 0) & (kv_step == 0))
     def _init_max():
         m_ref[...] = jnp.full_like(m_ref, NEG)
@@ -81,43 +106,93 @@ def _streaming_attn_body(phase, kv_step, n_kv, q8, k8, v8, live, blk_live,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _scores():
-        s = jax.lax.dot_general(q8, k8, (((1,), (1,)), ((), ())),
+    def _scores(h, k8):
+        s = jax.lax.dot_general(q_of(h), k8, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.int32)
         return jnp.where(live, s, jnp.int32(NEG))
 
-    def _e16():
-        e16 = _exp16_tile(_scores() - m_ref[...], plan.sm)
+    def _e16(h, k8):
+        e16 = _exp16_tile(_scores(h, k8) - m_ref[h], plan.sm)
         return jnp.where(live, e16, 0)
+
+    def _heads(g):
+        return range(g * group, (g + 1) * group)
 
     @pl.when((phase == 0) & blk_live)
     def _sweep_max():
-        m_ref[...] = jnp.maximum(m_ref[...],
-                                 jnp.max(_scores(), axis=-1, keepdims=True))
+        for g in range(n_kv_heads):
+            k8 = k_of(g)
+            for h in _heads(g):
+                m_ref[h] = jnp.maximum(
+                    m_ref[h], jnp.max(_scores(h, k8), axis=-1,
+                                      keepdims=True))
 
     @pl.when((phase == 1) & blk_live)
     def _sweep_sum():
-        s_ref[...] = s_ref[...] + jnp.sum(_e16(), axis=-1, keepdims=True)
+        for g in range(n_kv_heads):
+            k8 = k_of(g)
+            for h in _heads(g):
+                s_ref[h] = s_ref[h] + jnp.sum(_e16(h, k8), axis=-1,
+                                              keepdims=True)
 
     @pl.when((phase == 2) & blk_live)
     def _sweep_av():
-        r = jnp.int32(1 << RECIP_BITS) // jnp.maximum(s_ref[...], 1)
-        p = _rshift_round(_e16() * r, RECIP_BITS - PROB_SHIFT)
-        p8 = jnp.clip(p, 0, 127).astype(jnp.int8)
-        acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
-            p8, v8, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        for g in range(n_kv_heads):
+            k8, v8 = k_of(g), v_of(g)
+            for h in _heads(g):
+                r = jnp.int32(1 << RECIP_BITS) // jnp.maximum(s_ref[h], 1)
+                p = _rshift_round(_e16(h, k8) * r, RECIP_BITS - PROB_SHIFT)
+                p8 = jnp.clip(p, 0, 127).astype(jnp.int8)
+                acc_ref[h] = acc_ref[h] + jax.lax.dot_general(
+                    p8, v8, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.int32)
 
     @pl.when((phase == 2) & (kv_step == n_kv - 1))
     def _epilogue():
-        acc = acc_ref[...]                      # int32 at 2^-7 * s_v
-        if requant.is_raw:
-            o_ref[0, :, 0, :] = acc
-            return
-        b_row = None if b_ref is None \
-            else b_ref[0, :].astype(jnp.int32)[None, :]
-        o_ref[0, :, 0, :] = _requant_tile(acc, requant,
-                                          b_row).astype(o_ref.dtype)
+        for h in range(n_heads):
+            acc = acc_ref[h]                    # int32 at 2^-7 * s_v
+            if requant.is_raw:
+                emit(h, acc)
+                continue
+            b_row = None if b_ref is None \
+                else b_ref[h:h + 1, :].astype(jnp.int32)
+            emit(h, _requant_tile(acc, requant, b_row))
+        if finish is not None:
+            finish()
+
+
+def _head_store(o_ref):
+    """``emit`` for an unfolded launch: head ``h``'s tile into the
+    head-major ``(1, H, rows, D)`` output block (the wrappers swap the
+    ``(B, H, rows, D)`` result back to ``(B, rows, H, D)`` in XLA — a
+    head-strided int8 store is not something the chip's compiler can
+    lay out)."""
+    def emit(h, tile):
+        o_ref[0, h] = tile.astype(o_ref.dtype)
+    return emit
+
+
+def _wo_fold(o_ref, wo_ref, wob_ref, wobv_ref, wacc_ref, *, d: int,
+             wo_spec: RequantSpec):
+    """``(emit, finish)`` for a launch with the o-projection folded in:
+    each head's int8 tile is contracted against its ``(D, N)`` row slab
+    of the whole-``wo`` block and summed over heads in the ``(rows, N)``
+    int32 scratch; ``finish`` adds ``bias32`` and applies the wo
+    ``RequantSpec`` into the ``(1, rows, N)`` output block."""
+    def emit(h, tile):
+        part = jax.lax.dot_general(tile.astype(jnp.int8),
+                                   wo_ref[h * d:(h + 1) * d, :],
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.int32)
+        wacc_ref[...] = part if h == 0 else wacc_ref[...] + part
+
+    def finish():
+        acc = wacc_ref[...]
+        if wob_ref is not None:
+            acc = acc + wob_ref[...]
+        b_row = None if wobv_ref is None else wobv_ref[...]
+        o_ref[0] = _requant_tile(acc, wo_spec, b_row).astype(o_ref.dtype)
+    return emit, finish
 
 
 def _requant_tile(acc, requant: RequantSpec, b_row=None):
@@ -146,12 +221,24 @@ def _unpack_kv_tile(p8, shift):
     then a per-page requant left-shift.  All arithmetic in int32 with
     explicit sign extension — bit-exact twin of
     ``repro.ops.packed.unpack_kv_pool`` on the gathered layout.  The
-    shifted magnitudes stay ≤ 7·2⁴ = 112, int8-safe by construction."""
+    shifted magnitudes stay ≤ 7·2⁴ = 112, int8-safe by construction.
+
+    The lane interleave is two exact 0/1 selection matmuls (nibble
+    ``i`` → lane ``2i`` / ``2i + 1``) rather than a stack + reshape: the
+    chip's compiler lowers a lane interleave into a long shuffle
+    sequence (minutes of compile per kernel), the MXU does it in one
+    pass each."""
     rows, half = p8.shape
     p32 = p8.astype(jnp.int32)
-    lo = ((p32 & 15) ^ 8) - 8
-    hi = (((p32 >> 4) & 15) ^ 8) - 8
-    q = jnp.stack([lo, hi], axis=-1).reshape(rows, 2 * half)
+    lo = (((p32 & 15) ^ 8) - 8).astype(jnp.int8)
+    hi = ((((p32 >> 4) & 15) ^ 8) - 8).astype(jnp.int8)
+    src = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+    dims = (((1,), (0,)), ((), ()))
+    q = (jax.lax.dot_general(lo, (dst == 2 * src).astype(jnp.int8), dims,
+                             preferred_element_type=jnp.int32)
+         + jax.lax.dot_general(hi, (dst == 2 * src + 1).astype(jnp.int8),
+                               dims, preferred_element_type=jnp.int32))
     return (q << shift).astype(jnp.int8)
 
 
@@ -175,21 +262,59 @@ def _epilogue_setup(requant, plan: IAttnPlan, out_bits: int, b_vec,
     return requant, has_bvec, b2, out_dtype
 
 
+def _const_map(*_):
+    return (0, 0)
+
+
+def _wo_fold_setup(requant: RequantSpec, wo_w8, wo_bias32, wo_b_vec,
+                   wo_spec, h: int, d: int):
+    """Wrapper side of the folded o-projection (decode and paged
+    prefill): validate, and build the whole-``wo`` operands — the
+    ``(H·D, N)`` weight and the ``(1, N)`` bias / per-channel
+    multipliers, each one constant-index block (fetched once per
+    launch; their last two dims equal the arrays').  Returns ``(specs,
+    args, n_out, out_dtype, has_bias, has_bvec)``."""
+    assert wo_spec is not None, "folded wo projection needs wo_spec"
+    assert not requant.is_raw and requant.out_bits <= 8, \
+        "wo folding needs an int8 attention epilogue"
+    wo_w8 = jnp.asarray(wo_w8)
+    n_out = wo_w8.shape[-1]
+    assert wo_w8.shape == (h * d, n_out), (wo_w8.shape, h, d)
+    has_bias = wo_bias32 is not None
+    has_bvec = wo_spec.kind == PER_CHANNEL
+    if has_bvec and wo_b_vec is None:
+        raise ValueError("per-channel wo_spec needs the wo_b_vec "
+                         "multiplier vector")
+    specs = [pl.BlockSpec((h * d, n_out), _const_map)]
+    args = [wo_w8]
+    for vec, on in ((wo_bias32, has_bias), (wo_b_vec, has_bvec)):
+        if on:
+            specs.append(pl.BlockSpec((1, n_out), _const_map))
+            args.append(jnp.asarray(vec, jnp.int32).reshape(1, n_out))
+    out_dtype = jnp.int8 if (not wo_spec.is_raw
+                             and wo_spec.out_bits <= 8) else jnp.int32
+    return specs, args, n_out, out_dtype, has_bias, has_bvec
+
+
+def _attn_scratch(h: int, rows: int, d: int):
+    from jax.experimental.pallas import tpu as pltpu
+    return [pltpu.VMEM((h, rows, 1), jnp.int32),     # row max, per head
+            pltpu.VMEM((h, rows, 1), jnp.int32),     # row sum
+            pltpu.VMEM((h, rows, d), jnp.int32)]     # P·V accumulator
+
+
 def _fused_kernel(q_ref, k_ref, v_ref, *rest, plan: IAttnPlan,
                   requant: RequantSpec, has_bvec: bool, n_kv: int,
-                  bq: int, bkv: int, causal: bool, window: int):
+                  bq: int, bkv: int, causal: bool, window: int,
+                  n_heads: int, group: int):
     if has_bvec:
         b_ref, o_ref, m_ref, s_ref, acc_ref = rest
     else:
         b_ref = None
         o_ref, m_ref, s_ref, acc_ref = rest
-    q_blk = pl.program_id(2)
-    phase = pl.program_id(3)
-    kv_step = pl.program_id(4)
-
-    q8 = q_ref[0, :, 0, :]                      # (bq, d) int8
-    k8 = k_ref[0, :, 0, :]                      # (bkv, d) int8
-    v8 = v_ref[0, :, 0, :]
+    q_blk = pl.program_id(1)
+    phase = pl.program_id(2)
+    kv_step = pl.program_id(3)
 
     qi = q_blk * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
     ki = kv_step * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
@@ -207,20 +332,28 @@ def _fused_kernel(q_ref, k_ref, v_ref, *rest, plan: IAttnPlan,
     else:
         blk_live = True
 
-    _streaming_attn_body(phase, kv_step, n_kv, q8, k8, v8, live, blk_live,
-                         o_ref, m_ref, s_ref, acc_ref, b_ref,
-                         plan=plan, requant=requant)
+    _streaming_attn_body(
+        phase, kv_step, n_kv, live, blk_live, m_ref, s_ref, acc_ref, b_ref,
+        n_heads=n_heads, group=group,
+        q_of=lambda h: q_ref[0, :, h, :],           # (bq, d) int8
+        k_of=lambda g: k_ref[0, :, g, :],           # (bkv, d) int8
+        v_of=lambda g: v_ref[0, :, g, :],
+        emit=_head_store(o_ref), plan=plan, requant=requant)
 
 
 def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
                         b_vec=None, causal: bool = True, window: int = 0,
                         bq: int = 128, bkv: int = 128, out_bits: int = 8,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """q8: (B, Sq, H, D) int8; k8/v8: (B, Skv, Hkv, D) int8 (GQA: Hkv | H).
 
     ``requant``: a :class:`RequantSpec` for the epilogue (default: the
     plan's per-tensor ``dn_out``); ``b_vec``: int32 per-channel
     multipliers, shape (H*D,) or (H, D), required iff per-channel.
+
+    Grid ``(B, Sq/bq, 3, Skv/bkv)``: every step takes all heads of a
+    ``(bq, H, D)`` query block and a ``(bkv, Hkv, D)`` KV block and
+    loops over the heads in-kernel (``_streaming_attn_body``).
 
     Returns (B, Sq, H, D): int8 when the epilogue clips to ≤ 8 bits,
     int32 otherwise (raw / wide output).  Bit-exact against
@@ -235,7 +368,8 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
     _, skv, hkv, _ = k8.shape
     require_launch(check_launch(
         "int_attention", b=b, sq=sq, skv=skv, h=h, hkv=hkv, d=d,
-        bq=bq, bkv=bkv, out_bits=out_bits))
+        bq=bq, bkv=bkv, out_bits=out_bits,
+        per_channel=requant is not None and requant.kind == PER_CHANNEL))
     group = h // hkv
     bq = min(bq, sq)
     bkv = min(bkv, skv)
@@ -246,35 +380,32 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
 
     kernel = functools.partial(
         _fused_kernel, plan=plan, requant=requant, has_bvec=has_bvec,
-        n_kv=n_kv, bq=bq, bkv=bkv, causal=causal, window=window)
+        n_kv=n_kv, bq=bq, bkv=bkv, causal=causal, window=window,
+        n_heads=h, group=group)
 
     in_specs = [
-        pl.BlockSpec((1, bq, 1, d),
-                     lambda bi, hi, qi, ph, ki: (bi, qi, hi, 0)),
-        pl.BlockSpec((1, bkv, 1, d),
-                     lambda bi, hi, qi, ph, ki: (bi, ki, hi // group, 0)),
-        pl.BlockSpec((1, bkv, 1, d),
-                     lambda bi, hi, qi, ph, ki: (bi, ki, hi // group, 0)),
+        pl.BlockSpec((1, bq, h, d), lambda bi, qi, ph, ki: (bi, qi, 0, 0)),
+        pl.BlockSpec((1, bkv, hkv, d),
+                     lambda bi, qi, ph, ki: (bi, ki, 0, 0)),
+        pl.BlockSpec((1, bkv, hkv, d),
+                     lambda bi, qi, ph, ki: (bi, ki, 0, 0)),
     ]
     args = [q8, k8, v8]
     if has_bvec:
-        in_specs.append(
-            pl.BlockSpec((1, d), lambda bi, hi, qi, ph, ki: (hi, 0)))
+        in_specs.append(pl.BlockSpec((h, d), _const_map))
         args.append(b2)
 
-    from jax.experimental.pallas import tpu as pltpu
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(b, h, sq // bq, 3, n_kv),
+        grid=(b, sq // bq, 3, n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, 1, d),
-                               lambda bi, hi, qi, ph, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, d), jnp.int32)],
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, h, bq, d),
+                               lambda bi, qi, ph, ki: (bi, 0, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), out_dtype),
+        scratch_shapes=_attn_scratch(h, bq, d),
+        interpret=resolve_interpret(interpret),
     )(*args)
+    return jnp.swapaxes(out, 1, 2)
 
 
 # ===================================================== paged prefill =======
@@ -299,17 +430,17 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
 # so C is bounded by VMEM tiling only, not by MAX_SQ.
 #
 # The folded wo projection (``wo_w8=``) mirrors the decode kernel's:
-# query blocks sit *outside* the head grid dimension so the per-q-block
-# ``(bq, N)`` VMEM accumulator sums that block's o-projection across the
-# heads before the last head applies bias + the wo RequantSpec.
+# every grid step holds all heads of its query block, so the last step
+# sums the block's per-head o-projection slabs in a ``(bq, N)`` VMEM
+# accumulator and applies bias + the wo RequantSpec (``_wo_fold``).
 
 
 def _paged_prefill_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
                           has_bvec: bool, n_kv: int, c: int, bq: int,
                           bkv: int, fold: bool, wo_spec,
                           wo_has_bias: bool, wo_has_bvec: bool,
-                          n_heads: int, packed_kv: bool = False,
-                          sub: int = 1):
+                          n_heads: int, group: int, d: int,
+                          packed_kv: bool = False, sub: int = 1):
     refs = list(refs)
     vl_ref = refs.pop(0)
     # page table: read by index maps only — except under packed KV,
@@ -329,30 +460,17 @@ def _paged_prefill_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
             wobv_ref = refs.pop(0)
     o_ref = refs.pop(0)
     m_ref, s_ref, acc_ref = refs.pop(0), refs.pop(0), refs.pop(0)
-    attn_out = refs.pop(0) if fold else o_ref
     wacc_ref = refs.pop(0) if fold else None
 
     bi = pl.program_id(0)
     q_blk = pl.program_id(1)
-    head = pl.program_id(2)
-    phase = pl.program_id(3)
-    kv_step = pl.program_id(4)
+    phase = pl.program_id(2)
+    kv_step = pl.program_id(3)
     vl = vl_ref[bi]
     base = vl - c                       # chunk's first global position
 
-    q8 = q_ref[0, :, 0, :]              # (bq, d) int8
-    if packed_kv:
-        # re-derive the physical page exactly as the KV index map did
-        # (same dead-block clamp) and dequantize the nibble tile with
-        # that page's requant shift, in-register
-        last = jnp.maximum(pl.cdiv(vl, bkv) - 1, 0)
-        kc = jnp.minimum(kv_step, last)
-        page = pt_ref[bi, kc // sub]
-        k8 = _unpack_kv_tile(k_ref[0, :, 0, :], ks_ref[page])
-        v8 = _unpack_kv_tile(v_ref[0, :, 0, :], vs_ref[page])
-    else:
-        k8 = k_ref[0, :, 0, :]          # (bkv, d) int8
-        v8 = v_ref[0, :, 0, :]
+    k_of, v_of = _kv_loaders(k_ref, v_ref, pt_ref, ks_ref, vs_ref, vl,
+                             kv_step, bkv, sub, packed_kv)
 
     # causal-over-history mask: chunk row i at global position base +
     # q_blk*bq + i sees logical cache positions <= its own.  ki is the
@@ -368,37 +486,40 @@ def _paged_prefill_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
     # always <= vl - 1, so the causal bound subsumes the vl bound)
     blk_live = kv_step * bkv <= base + q_blk * bq + bq - 1
 
-    _streaming_attn_body(phase, kv_step, n_kv, q8, k8, v8, live, blk_live,
-                         attn_out, m_ref, s_ref, acc_ref, b_ref,
-                         plan=plan, requant=requant)
-
     if fold:
-        @pl.when((phase == 2) & (kv_step == n_kv - 1))
-        def _wo_accumulate():
-            o8 = attn_out[0, :, 0, :]
-            part = jax.lax.dot_general(o8, wo_ref[...],
-                                       (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.int32)
-            prev = jnp.where(head == 0, jnp.zeros_like(part),
-                             wacc_ref[...])
-            wacc_ref[...] = prev + part
+        emit, finish = _wo_fold(o_ref, wo_ref, wob_ref, wobv_ref, wacc_ref,
+                                d=d, wo_spec=wo_spec)
+    else:
+        emit, finish = _head_store(o_ref), None
+    _streaming_attn_body(
+        phase, kv_step, n_kv, live, blk_live, m_ref, s_ref, acc_ref, b_ref,
+        n_heads=n_heads, group=group, q_of=lambda h: q_ref[0, :, h, :],
+        k_of=k_of, v_of=v_of, emit=emit, finish=finish, plan=plan,
+        requant=requant)
 
-        @pl.when((phase == 2) & (kv_step == n_kv - 1)
-                 & (head == n_heads - 1))
-        def _wo_epilogue():
-            acc = wacc_ref[...]
-            if wo_has_bias:
-                acc = acc + wob_ref[0, :][None, :]
-            b_row = None if wobv_ref is None \
-                else wobv_ref[0, :].astype(jnp.int32)[None, :]
-            o_ref[0, :, :] = _requant_tile(acc, wo_spec,
-                                           b_row).astype(o_ref.dtype)
+
+def _kv_loaders(k_ref, v_ref, pt_ref, ks_ref, vs_ref, vl, kv_step,
+                bkv: int, sub: int, packed_kv: bool):
+    """``(k_of, v_of)``: KV head ``g``'s ``(bkv, D)`` int8 tile of the
+    current ``(1, bkv, Hkv, D)`` block.  Under packed int4 KV the
+    physical page is re-derived exactly as the KV index map did (same
+    dead-block clamp) and the nibble tile dequantizes with that page's
+    requant shift, in-register — packed pages never exist as dense int8
+    outside the launch."""
+    if not packed_kv:
+        return (lambda g: k_ref[0, :, g, :]), (lambda g: v_ref[0, :, g, :])
+    last = jnp.maximum(pl.cdiv(vl, bkv) - 1, 0)
+    page = pt_ref[pl.program_id(0), jnp.minimum(kv_step, last) // sub]
+    k_shift, v_shift = ks_ref[page], vs_ref[page]
+    return (lambda g: _unpack_kv_tile(k_ref[0, :, g, :], k_shift),
+            lambda g: _unpack_kv_tile(v_ref[0, :, g, :], v_shift))
 
 
 def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
                             pages, page_size: int, requant=None,
                             b_vec=None, bq: int = 128, bkv: int = 128,
-                            out_bits: int = 8, interpret: bool = True,
+                            out_bits: int = 8,
+                            interpret: Optional[bool] = None,
                             wo_w8=None, wo_bias32=None, wo_b_vec=None,
                             wo_spec=None, kv_shifts=None):
     """q8: (B, C, H, D) int8 chunk queries; k_pool/v_pool: physical
@@ -419,6 +540,10 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
     ``wo_b_vec`` / ``wo_spec``): fold the o-projection into the launch,
     exactly as the decode kernel — the attention epilogue must clip to
     ≤ 8 bits, and the return becomes ``(B, C, N)``.
+
+    Grid ``(B, C/bq, 3, L/bkv)``; each step takes all heads of a query
+    block and of a KV block (``_streaming_attn_body``), so the folded
+    projection sums a query block's heads within its last step.
 
     Returns (B, C, H, D) — or (B, C, N) folded.  Bit-exact against
     ``kernels.ref.ref_int_paged_prefill``'s attention output for the
@@ -443,10 +568,13 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
         v_shift = jnp.asarray(kv_shifts[1], jnp.int32)
         assert k_shift.shape == v_shift.shape == (num_pages,), \
             (k_shift.shape, v_shift.shape, num_pages)
+    fold = wo_w8 is not None
     require_launch(check_launch(
         "int_paged_prefill", b=b, c=c, h=h, hkv=hkv, d=d,
         max_pages=pages.shape[1], page_size=ps, bq=bq, bkv=bkv,
-        out_bits=out_bits, kv_pack=packed_kv, num_pages=num_pages))
+        out_bits=out_bits, kv_pack=packed_kv, num_pages=num_pages,
+        per_channel=requant is not None and requant.kind == PER_CHANNEL,
+        fold=fold, n_out=jnp.shape(wo_w8)[-1] if fold else 0))
     group = h // hkv
     bq = min(bq, c)
     bkv = min(bkv, ps)
@@ -457,29 +585,19 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
     requant, has_bvec, b2, out_dtype = _epilogue_setup(
         requant, plan, out_bits, b_vec, h, d)
 
-    fold = wo_w8 is not None
+    wo_specs, wo_args, n_out = [], [], 0
     wo_has_bias = wo_has_bvec = False
     if fold:
-        assert wo_spec is not None, "folded wo projection needs wo_spec"
-        assert not requant.is_raw and requant.out_bits <= 8, \
-            "wo folding needs an int8 attention epilogue"
-        wo_w8 = jnp.asarray(wo_w8)
-        n_out = wo_w8.shape[-1]
-        assert wo_w8.shape == (h * d, n_out), (wo_w8.shape, h, d)
-        wo_has_bias = wo_bias32 is not None
-        wo_has_bvec = wo_spec.kind == PER_CHANNEL
-        if wo_has_bvec and wo_b_vec is None:
-            raise ValueError("per-channel wo_spec needs the wo_b_vec "
-                             "multiplier vector")
-        out_dtype = jnp.int8 if (not wo_spec.is_raw
-                                 and wo_spec.out_bits <= 8) else jnp.int32
+        (wo_specs, wo_args, n_out, out_dtype, wo_has_bias,
+         wo_has_bvec) = _wo_fold_setup(requant, wo_w8, wo_bias32, wo_b_vec,
+                                       wo_spec, h, d)
 
     kernel = functools.partial(
         _paged_prefill_kernel, plan=plan, requant=requant,
         has_bvec=has_bvec, n_kv=n_kv, c=c, bq=bq, bkv=bkv,
         fold=fold, wo_spec=wo_spec, wo_has_bias=wo_has_bias,
-        wo_has_bvec=wo_has_bvec, n_heads=h, packed_kv=packed_kv,
-        sub=sub)
+        wo_has_bvec=wo_has_bvec, n_heads=h, group=group, d=d,
+        packed_kv=packed_kv, sub=sub)
 
     def _kv_block(ki, vl):
         # clamp dead blocks to the slot's last live one before table
@@ -489,75 +607,56 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
         last = jnp.maximum(pl.cdiv(vl, bkv) - 1, 0)
         return jnp.minimum(ki, last)
 
-    # index maps: grid is (b, q_blk, head, phase, kv) — query blocks sit
-    # OUTSIDE the head dim so the folded-wo accumulator for one query
-    # block sweeps all heads consecutively (decode kernel: Sq <= 8 in
-    # scratch needs no q dim at all); scalar-prefetch refs (pos_end,
-    # pages[, k_shift, v_shift]) arrive as trailing args (``*_`` absorbs
-    # the shift refs under the packed layout).
-    def q_map(bi, qi, hi, ph, ki, vl, pt, *_):
-        return (bi, qi, hi, 0)
+    # index maps: grid is (b, q_blk, phase, kv); scalar-prefetch refs
+    # (pos_end, pages[, k_shift, v_shift]) arrive as trailing args
+    # (``*_`` absorbs the shift refs under the packed layout).
+    def q_map(bi, qi, ph, ki, vl, pt, *_):
+        return (bi, qi, 0, 0)
 
-    def kv_map(bi, qi, hi, ph, ki, vl, pt, *_):
+    def kv_map(bi, qi, ph, ki, vl, pt, *_):
         kc = _kv_block(ki, vl[bi])
-        return (pt[bi, kc // sub], kc % sub, hi // group, 0)
+        return (pt[bi, kc // sub], kc % sub, 0, 0)
 
-    def head_row_map(bi, qi, hi, ph, ki, vl, pt, *_):
-        return (hi, 0)
+    def out_map(bi, qi, ph, ki, vl, pt, *_):
+        return (bi, qi, 0) if fold else (bi, 0, qi, 0)
 
-    def one_row_map(bi, qi, hi, ph, ki, vl, pt, *_):
-        return (0, 0)
-
-    def out_map(bi, qi, hi, ph, ki, vl, pt, *_):
-        return (bi, qi, 0) if fold else (bi, qi, hi, 0)
-
-    kv_blk = (1, bkv, 1, d // 2 if packed_kv else d)
+    kv_blk = (1, bkv, hkv, d // 2 if packed_kv else d)
     in_specs = [
-        pl.BlockSpec((1, bq, 1, d), q_map),
+        pl.BlockSpec((1, bq, h, d), q_map),
         pl.BlockSpec(kv_blk, kv_map),
         pl.BlockSpec(kv_blk, kv_map),
     ]
     args = [q8, k_pool, v_pool]
     if has_bvec:
-        in_specs.append(pl.BlockSpec((1, d), head_row_map))
+        in_specs.append(pl.BlockSpec((h, d), _const_map))
         args.append(b2)
-    if fold:
-        in_specs.append(pl.BlockSpec((d, n_out), head_row_map))
-        args.append(wo_w8)
-        if wo_has_bias:
-            in_specs.append(pl.BlockSpec((1, n_out), one_row_map))
-            args.append(jnp.asarray(wo_bias32, jnp.int32).reshape(1, n_out))
-        if wo_has_bvec:
-            in_specs.append(pl.BlockSpec((1, n_out), one_row_map))
-            args.append(jnp.asarray(wo_b_vec, jnp.int32).reshape(1, n_out))
+    in_specs += wo_specs
+    args += wo_args
 
     from jax.experimental.pallas import tpu as pltpu
-    scratch = [pltpu.VMEM((bq, 1), jnp.int32),
-               pltpu.VMEM((bq, 1), jnp.int32),
-               pltpu.VMEM((bq, d), jnp.int32)]
+    scratch = _attn_scratch(h, bq, d)
     if fold:
-        # per-head attention tile (int8: asserted above) + the (bq, N)
-        # o-projection accumulator carried across the head grid dim
-        scratch += [pltpu.VMEM((1, bq, 1, d), jnp.int8),
-                    pltpu.VMEM((bq, n_out), jnp.int32)]
+        # the (bq, N) o-projection accumulator summed over the heads
+        scratch.append(pltpu.VMEM((bq, n_out), jnp.int32))
         out_specs = pl.BlockSpec((1, bq, n_out), out_map)
         out_shape = jax.ShapeDtypeStruct((b, c, n_out), out_dtype)
     else:
-        out_specs = pl.BlockSpec((1, bq, 1, d), out_map)
-        out_shape = jax.ShapeDtypeStruct((b, c, h, d), out_dtype)
+        out_specs = pl.BlockSpec((1, h, bq, d), out_map)    # head-major
+        out_shape = jax.ShapeDtypeStruct((b, h, c, d), out_dtype)
 
     scalar_args = (pos_end, pages, k_shift, v_shift) if packed_kv \
         else (pos_end, pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
-        grid=(b, c // bq, h, 3, n_kv),
+        grid=(b, c // bq, 3, n_kv),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*scalar_args, *args)
+    return out if fold else jnp.swapaxes(out, 1, 2)

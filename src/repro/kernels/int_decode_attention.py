@@ -34,9 +34,9 @@ bit-identical to gathering the pages into the contiguous layout first.
 
 **Folded wo projection** (``wo_w8=``): the decode epilogue can absorb
 the attention output projection — per head, the requantized int8
-``(Sq, D)`` tile is contracted against that head's ``(D, N)`` slab of
-``wo`` and accumulated across the head grid dimension in VMEM scratch;
-the *last* head adds ``bias32`` and applies the wo ``RequantSpec``
+``(Sq, D)`` tile is contracted against that head's ``(D, N)`` row slab
+of the whole-``wo`` block and summed over the heads in VMEM scratch;
+then ``bias32`` is added and the wo ``RequantSpec`` applied
 (typically per-channel over the N output channels, the same two-stage
 rounding the attention epilogue already implements).  The launch then
 returns the ``(B, Sq, N)`` projected output directly — one kernel for
@@ -64,6 +64,7 @@ The folded-wo scratch adds ``(Sq, N)`` int32 (N = H·D out channels).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,10 +74,12 @@ from repro.analysis.budgets import MAX_ROWSUM_LEN
 from repro.analysis.budgets import MAX_SQ as _MAX_SQ
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
-from repro.kernels.int_attention_fused import (_epilogue_setup,
-                                               _requant_tile,
+from repro.kernels import resolve_interpret
+from repro.kernels.int_attention_fused import (_attn_scratch, _const_map,
+                                               _epilogue_setup, _head_store,
+                                               _kv_loaders,
                                                _streaming_attn_body,
-                                               _unpack_kv_tile)
+                                               _wo_fold, _wo_fold_setup)
 from repro.ops.spec import PER_CHANNEL, RequantSpec
 
 # both budgets are owned by repro.analysis.budgets; re-exported here
@@ -88,7 +91,7 @@ MAX_SKV = MAX_ROWSUM_LEN    # row-sum int32 budget: L * 2^15 <= 2^30
 def _decode_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
                    has_bvec: bool, n_kv: int, sq: int, bkv: int,
                    paged: bool, fold: bool, wo_spec, wo_has_bias: bool,
-                   wo_has_bvec: bool, n_heads: int,
+                   wo_has_bvec: bool, n_heads: int, group: int, d: int,
                    packed_kv: bool = False, sub: int = 1):
     refs = list(refs)
     vl_ref = refs.pop(0)
@@ -111,31 +114,15 @@ def _decode_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
             wobv_ref = refs.pop(0)
     o_ref = refs.pop(0)
     m_ref, s_ref, acc_ref = refs.pop(0), refs.pop(0), refs.pop(0)
-    # with the folded projection the per-head attention tile lands in
-    # VMEM scratch (same (1, sq, 1, d) indexing as the real output ref)
-    attn_out = refs.pop(0) if fold else o_ref
     wacc_ref = refs.pop(0) if fold else None
 
     bi = pl.program_id(0)
-    head = pl.program_id(1)
-    phase = pl.program_id(2)
-    kv_step = pl.program_id(3)
+    phase = pl.program_id(1)
+    kv_step = pl.program_id(2)
     vl = vl_ref[bi]
 
-    q8 = q_ref[0, :, 0, :]                      # (sq, d) int8
-    if packed_kv:
-        # re-derive the physical page exactly as the KV index map did
-        # (same dead-block clamp) and dequantize the nibble tile with
-        # that page's requant shift, in-register — packed pages never
-        # exist as dense int8 outside the launch
-        last = jnp.maximum(pl.cdiv(vl, bkv) - 1, 0)
-        kc = jnp.minimum(kv_step, last)
-        page = pt_ref[bi, kc // sub]
-        k8 = _unpack_kv_tile(k_ref[0, :, 0, :], ks_ref[page])
-        v8 = _unpack_kv_tile(v_ref[0, :, 0, :], vs_ref[page])
-    else:
-        k8 = k_ref[0, :, 0, :]                  # (bkv, d) int8
-        v8 = v_ref[0, :, 0, :]
+    k_of, v_of = _kv_loaders(k_ref, v_ref, pt_ref, ks_ref, vs_ref, vl,
+                             kv_step, bkv, sub, packed_kv)
 
     # stepped occupancy mask: row i sees vl - (sq-1-i) positions (sq=1:
     # the plain pos < valid_len cache-occupancy mask).  ki is the
@@ -152,38 +139,22 @@ def _decode_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
     # valid_len == 0 writes requant(0) (matching the all-masked oracle).
     blk_live = kv_step * bkv < vl
 
-    _streaming_attn_body(phase, kv_step, n_kv, q8, k8, v8, live, blk_live,
-                         attn_out, m_ref, s_ref, acc_ref, b_ref,
-                         plan=plan, requant=requant)
-
     if fold:
-        @pl.when((phase == 2) & (kv_step == n_kv - 1))
-        def _wo_accumulate():
-            # this head's slab of the o-projection: (sq, d) @ (d, n_out)
-            o8 = attn_out[0, :, 0, :]
-            part = jax.lax.dot_general(o8, wo_ref[...],
-                                       (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.int32)
-            prev = jnp.where(head == 0, jnp.zeros_like(part),
-                             wacc_ref[...])
-            wacc_ref[...] = prev + part
-
-        @pl.when((phase == 2) & (kv_step == n_kv - 1)
-                 & (head == n_heads - 1))
-        def _wo_epilogue():
-            acc = wacc_ref[...]
-            if wo_has_bias:
-                acc = acc + wob_ref[0, :][None, :]
-            b_row = None if wobv_ref is None \
-                else wobv_ref[0, :].astype(jnp.int32)[None, :]
-            o_ref[0, :, :] = _requant_tile(acc, wo_spec,
-                                           b_row).astype(o_ref.dtype)
+        emit, finish = _wo_fold(o_ref, wo_ref, wob_ref, wobv_ref, wacc_ref,
+                                d=d, wo_spec=wo_spec)
+    else:
+        emit, finish = _head_store(o_ref), None
+    _streaming_attn_body(
+        phase, kv_step, n_kv, live, blk_live, m_ref, s_ref, acc_ref, b_ref,
+        n_heads=n_heads, group=group, q_of=lambda h: q_ref[0, :, h, :],
+        k_of=k_of, v_of=v_of, emit=emit, finish=finish, plan=plan,
+        requant=requant)
 
 
 def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
                                valid_len, requant=None, b_vec=None,
                                bkv: int = 128, out_bits: int = 8,
-                               interpret: bool = True,
+                               interpret: Optional[bool] = None,
                                pages=None, page_size: int = 0,
                                wo_w8=None, wo_bias32=None, wo_b_vec=None,
                                wo_spec=None, kv_shifts=None):
@@ -245,12 +216,15 @@ def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
         v_shift = jnp.asarray(kv_shifts[1], jnp.int32)
         assert k_shift.shape == v_shift.shape == (num_pages,), \
             (k_shift.shape, v_shift.shape, num_pages)
+    fold = wo_w8 is not None
     require_launch(check_launch(
         "int_decode_attention", b=b, sq=sq, h=h, hkv=hkv, d=d,
         L=None if paged else L, bkv=bkv,
         max_pages=pages.shape[1] if paged else 0,
         page_size=page_size, out_bits=out_bits, kv_pack=packed_kv,
-        num_pages=num_pages))
+        num_pages=num_pages,
+        per_channel=requant is not None and requant.kind == PER_CHANNEL,
+        fold=fold, n_out=jnp.shape(wo_w8)[-1] if fold else 0))
     group = h // hkv
     bkv = min(bkv, ps if paged else L)
     sub = ps // bkv if paged else 1     # KV sub-blocks per physical page
@@ -260,28 +234,18 @@ def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
     requant, has_bvec, b2, out_dtype = _epilogue_setup(
         requant, plan, out_bits, b_vec, h, d)
 
-    fold = wo_w8 is not None
+    wo_specs, wo_args, n_out = [], [], 0
     wo_has_bias = wo_has_bvec = False
     if fold:
-        assert wo_spec is not None, "folded wo projection needs wo_spec"
-        assert not requant.is_raw and requant.out_bits <= 8, \
-            "wo folding needs an int8 attention epilogue"
-        wo_w8 = jnp.asarray(wo_w8)
-        n_out = wo_w8.shape[-1]
-        assert wo_w8.shape == (h * d, n_out), (wo_w8.shape, h, d)
-        wo_has_bias = wo_bias32 is not None
-        wo_has_bvec = wo_spec.kind == PER_CHANNEL
-        if wo_has_bvec and wo_b_vec is None:
-            raise ValueError("per-channel wo_spec needs the wo_b_vec "
-                             "multiplier vector")
-        out_dtype = jnp.int8 if (not wo_spec.is_raw
-                                 and wo_spec.out_bits <= 8) else jnp.int32
+        (wo_specs, wo_args, n_out, out_dtype, wo_has_bias,
+         wo_has_bvec) = _wo_fold_setup(requant, wo_w8, wo_bias32, wo_b_vec,
+                                       wo_spec, h, d)
 
     kernel = functools.partial(
         _decode_kernel, plan=plan, requant=requant, has_bvec=has_bvec,
         n_kv=n_kv, sq=sq, bkv=bkv, paged=paged, fold=fold, wo_spec=wo_spec,
         wo_has_bias=wo_has_bias, wo_has_bvec=wo_has_bvec, n_heads=h,
-        packed_kv=packed_kv, sub=sub)
+        group=group, d=d, packed_kv=packed_kv, sub=sub)
 
     def _kv_block(ki, vl):
         # clamp dead blocks to the slot's last live block: the pipeline
@@ -290,78 +254,49 @@ def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
         last = jnp.maximum(pl.cdiv(vl, bkv) - 1, 0)
         return jnp.minimum(ki, last)
 
-    # index maps: scalar-prefetch refs arrive as trailing args — one
-    # (valid_len) for the contiguous layout, two (valid_len, pages) for
-    # the paged layout, where the KV map translates logical block →
-    # physical (page, sub-block) through the prefetched table.
+    # index maps over the (b, phase, kv) grid: scalar-prefetch refs
+    # arrive as trailing args — one (valid_len) for the contiguous
+    # layout, two (valid_len, pages) for the paged layout, where the KV
+    # map translates logical block → physical (page, sub-block) through
+    # the prefetched table (``*_`` absorbs the k_shift/v_shift refs
+    # under the packed int4 layout; the kernel body reads those).
     if paged:
-        # ``*_`` absorbs the k_shift/v_shift scalar-prefetch refs under
-        # the packed int4 layout (read by the kernel body, not the maps)
-        def q_map(bi, hi, ph, ki, vl, pt, *_):
-            return (bi, 0, hi, 0)
-
-        def kv_map(bi, hi, ph, ki, vl, pt, *_):
+        def kv_map(bi, ph, ki, vl, pt, *_):
             kc = _kv_block(ki, vl[bi])
-            return (pt[bi, kc // sub], kc % sub, hi // group, 0)
-
-        def head_row_map(bi, hi, ph, ki, vl, pt, *_):
-            return (hi, 0)
-
-        def one_row_map(bi, hi, ph, ki, vl, pt, *_):
-            return (0, 0)
-
-        def out_map(bi, hi, ph, ki, vl, pt, *_):
-            return (bi, 0, 0) if fold else (bi, 0, hi, 0)
+            return (pt[bi, kc // sub], kc % sub, 0, 0)
     else:
-        def q_map(bi, hi, ph, ki, vl):
-            return (bi, 0, hi, 0)
+        def kv_map(bi, ph, ki, vl, *_):
+            return (bi, _kv_block(ki, vl[bi]), 0, 0)
 
-        def kv_map(bi, hi, ph, ki, vl):
-            return (bi, _kv_block(ki, vl[bi]), hi // group, 0)
+    def q_map(bi, *_):
+        return (bi, 0, 0, 0)
 
-        def head_row_map(bi, hi, ph, ki, vl):
-            return (hi, 0)
+    def out_map(bi, *_):
+        return (bi, 0, 0) if fold else (bi, 0, 0, 0)     # head-major
 
-        def one_row_map(bi, hi, ph, ki, vl):
-            return (0, 0)
-
-        def out_map(bi, hi, ph, ki, vl):
-            return (bi, 0, 0) if fold else (bi, 0, hi, 0)
-
-    kv_blk = (1, bkv, 1, d // 2 if packed_kv else d)
+    kv_blk = (1, bkv, hkv, d // 2 if packed_kv else d)
     in_specs = [
-        pl.BlockSpec((1, sq, 1, d), q_map),
+        pl.BlockSpec((1, sq, h, d), q_map),
         pl.BlockSpec(kv_blk, kv_map),
         pl.BlockSpec(kv_blk, kv_map),
     ]
     args = [q8, k8_cache, v8_cache]
     if has_bvec:
-        in_specs.append(pl.BlockSpec((1, d), head_row_map))
+        in_specs.append(pl.BlockSpec((h, d), _const_map))
         args.append(b2)
-    if fold:
-        in_specs.append(pl.BlockSpec((d, n_out), head_row_map))
-        args.append(wo_w8)
-        if wo_has_bias:
-            in_specs.append(pl.BlockSpec((1, n_out), one_row_map))
-            args.append(jnp.asarray(wo_bias32, jnp.int32).reshape(1, n_out))
-        if wo_has_bvec:
-            in_specs.append(pl.BlockSpec((1, n_out), one_row_map))
-            args.append(jnp.asarray(wo_b_vec, jnp.int32).reshape(1, n_out))
+    in_specs += wo_specs
+    args += wo_args
 
     from jax.experimental.pallas import tpu as pltpu
-    scratch = [pltpu.VMEM((sq, 1), jnp.int32),
-               pltpu.VMEM((sq, 1), jnp.int32),
-               pltpu.VMEM((sq, d), jnp.int32)]
+    scratch = _attn_scratch(h, sq, d)
     if fold:
-        # per-head attention tile (int8: asserted above) + the (Sq, N)
-        # o-projection accumulator carried across the head grid dim
-        scratch += [pltpu.VMEM((1, sq, 1, d), jnp.int8),
-                    pltpu.VMEM((sq, n_out), jnp.int32)]
+        # the (Sq, N) o-projection accumulator summed over the heads
+        scratch.append(pltpu.VMEM((sq, n_out), jnp.int32))
         out_specs = pl.BlockSpec((1, sq, n_out), out_map)
         out_shape = jax.ShapeDtypeStruct((b, sq, n_out), out_dtype)
     else:
-        out_specs = pl.BlockSpec((1, sq, 1, d), out_map)
-        out_shape = jax.ShapeDtypeStruct((b, sq, h, d), out_dtype)
+        out_specs = pl.BlockSpec((1, h, sq, d), out_map)
+        out_shape = jax.ShapeDtypeStruct((b, h, sq, d), out_dtype)
 
     if packed_kv:
         scalar_args = (valid_len, pages, k_shift, v_shift)
@@ -371,14 +306,15 @@ def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
         scalar_args = (valid_len,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
-        grid=(b, h, 3, n_kv),
+        grid=(b, 3, n_kv),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*scalar_args, *args)
+    return out if fold else jnp.swapaxes(out, 1, 2)

@@ -8,13 +8,16 @@ paper's Fig. 14).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.analysis.contracts import fit_block
 from repro.core.dyadic import Dyadic
 from repro.core.intmath import IGeluPlan
+from repro.kernels import resolve_interpret
 
 
 def _rshift_round(x, s: int):
@@ -38,24 +41,36 @@ def _gelu_kernel(x_ref, o_ref, *, plan: IGeluPlan, dn_out: Dyadic,
     o_ref[...] = jnp.clip(out, out_lo, out_hi).astype(o_ref.dtype)
 
 
+#: lane width of the flattened elementwise layout (a multiple of 128)
+LANES = 512
+
+
 def int_gelu_pallas(q, plan: IGeluPlan, dn_out: Dyadic, out_bits: int = 8,
-                    block: int = 4096, interpret: bool = True):
-    """q: int32 (...,) any shape; returns int32 clipped to out_bits."""
+                    block: int = 4096, interpret: Optional[bool] = None):
+    """q: int32 (...,) any shape; returns int32 clipped to out_bits.
+
+    The flattened input is zero-padded to whole ``(8, LANES)`` tiles and
+    viewed as ``(rows, LANES)``; each grid step takes ``(br, LANES)``
+    rows with ``br`` a multiple of 8 (about ``block`` elements) — the
+    chip-legal layout for an elementwise kernel.  Padding is sliced off
+    the result, so any shape works."""
     shape = q.shape
     n = q.size
-    blk = min(block, n)
-    while n % blk:
-        blk -= 1
-    x2 = q.reshape(n // blk, blk)
+    lanes = LANES if n >= 8 * LANES else 128    # small: one narrow tile
+    tile = 8 * lanes
+    n_pad = -(-n // tile) * tile
+    rows = n_pad // lanes
+    x2 = jnp.pad(q.reshape(-1), (0, n_pad - n)).reshape(rows, lanes)
+    br = fit_block(max(block // lanes, 8), rows, 8)
     kernel = functools.partial(
         _gelu_kernel, plan=plan, dn_out=dn_out,
         out_lo=-(1 << (out_bits - 1)), out_hi=(1 << (out_bits - 1)) - 1)
     out = pl.pallas_call(
         kernel,
-        grid=(n // blk,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, blk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // blk, blk), jnp.int32),
-        interpret=interpret,
+        grid=(rows // br,),
+        in_specs=[pl.BlockSpec((br, lanes), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((br, lanes), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(x2)
-    return out.reshape(shape)
+    return out.reshape(-1)[:n].reshape(shape)

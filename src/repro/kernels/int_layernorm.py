@@ -8,12 +8,15 @@ the reciprocal + per-channel gamma/beta output phase.
 """
 from __future__ import annotations
 
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.analysis.contracts import fit_block
 from repro.core.norms import INormPlan
+from repro.kernels import resolve_interpret
 
 
 def _rshift_round(x, s: int):
@@ -72,24 +75,25 @@ def _ln_kernel(x_ref, g_ref, b_ref, o_ref, *, plan: INormPlan,
 
 def int_layernorm_pallas(q, q_gamma, q_beta, plan: INormPlan,
                          out_bits: int = 8, block_rows: int = 8,
-                         interpret: bool = True):
-    """q: (..., d) int32 at plan.s_in -> int32 clipped to out_bits."""
+                         interpret: Optional[bool] = None):
+    """q: (..., d) int32 at plan.s_in -> int32 clipped to out_bits.
+
+    Rows are zero-padded to a multiple of 8 and blocked ``(br, d)`` with
+    ``br`` a multiple of 8 — chip-legal for any row count (a zero row
+    normalizes to ``beta``; padding is sliced off)."""
     shape = q.shape
     d = shape[-1]
     assert d == plan.d, (d, plan.d)
     rows = q.size // d
-    x2 = q.reshape(rows, d)
-    br = min(block_rows, rows)
-    while rows % br:
-        br -= 1
+    rows_pad = -(-rows // 8) * 8
+    x2 = jnp.pad(q.reshape(rows, d), ((0, rows_pad - rows), (0, 0)))
+    br = fit_block(max(block_rows, 8), rows_pad, 8)
     has_beta = q_beta is not None
     args = [x2, q_gamma] + ([q_beta] if has_beta else [])
     in_specs = [pl.BlockSpec((br, d), lambda i: (i, 0)),
                 pl.BlockSpec((d,), lambda i: (0,))]
     if has_beta:
         in_specs.append(pl.BlockSpec((d,), lambda i: (0,)))
-    else:
-        args = [x2, q_gamma]
 
     def kernel(*refs):
         if has_beta:
@@ -102,10 +106,10 @@ def int_layernorm_pallas(q, q_gamma, q_beta, plan: INormPlan,
 
     out = pl.pallas_call(
         kernel,
-        grid=(rows // br,),
+        grid=(rows_pad // br,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.int32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((rows_pad, d), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(*args)
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)

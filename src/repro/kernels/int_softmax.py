@@ -12,12 +12,15 @@ probabilities at 2^-7 (see core.softmax for the scale plan).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.analysis.contracts import fit_block
 from repro.core.softmax import ISoftmaxPlan, PROB_SHIFT, RECIP_BITS
+from repro.kernels import resolve_interpret
 
 
 def _rshift_round(x, s: int):
@@ -64,29 +67,33 @@ def _softmax_kernel(x_ref, o_ref, *, plan: ISoftmaxPlan, masked: bool,
 
 
 def int_softmax_pallas(scores, plan: ISoftmaxPlan, valid_len: int = -1,
-                       block_rows: int = 8, interpret: bool = True):
+                       block_rows: int = 8,
+                       interpret: Optional[bool] = None):
     """scores: (..., rows, row_len) int32 -> int8 probs, same shape.
 
     ``valid_len`` >= 0 masks trailing positions (static padding mask);
     data-dependent masks are handled by the attention kernel instead.
+    Rows are zero-padded to a multiple of 8 and blocked ``(br, row_len)``
+    with ``br`` a multiple of 8 (chip-legal for any row count; padding
+    is sliced off).
     """
     shape = scores.shape
     rows = 1
     for d in shape[:-1]:
         rows *= d
     row_len = shape[-1]
-    x2 = scores.reshape(rows, row_len)
-    br = min(block_rows, rows)
-    while rows % br:
-        br -= 1
+    rows_pad = -(-rows // 8) * 8
+    x2 = jnp.pad(scores.reshape(rows, row_len),
+                 ((0, rows_pad - rows), (0, 0)))
+    br = fit_block(max(block_rows, 8), rows_pad, 8)
     kernel = functools.partial(_softmax_kernel, plan=plan,
                                masked=valid_len >= 0, valid_len=valid_len)
     out = pl.pallas_call(
         kernel,
-        grid=(rows // br,),
+        grid=(rows_pad // br,),
         in_specs=[pl.BlockSpec((br, row_len), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, row_len), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, row_len), jnp.int8),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((rows_pad, row_len), jnp.int8),
+        interpret=resolve_interpret(interpret),
     )(x2)
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)

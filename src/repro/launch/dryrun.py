@@ -28,7 +28,7 @@ from repro.configs.registry import ASSIGNED, get_config
 from repro.launch import shardings as shd
 from repro.launch import steps as steps_mod
 from repro import ops as rops
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.models.common import SHAPES, ShapeConfig
 from repro.optim.adamw import AdamWConfig
@@ -57,7 +57,7 @@ def lower_cell(cfg, shape: ShapeConfig, mesh, zero1=None):
     """Returns (lowered, jit_fn, arg_specs) for one cell."""
     zero1 = True if zero1 is None else zero1
     fsdp = cfg.param_count() > 2e10
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = AdamWConfig(zero1=zero1)
             pspec = M.params_spec(cfg)
@@ -76,9 +76,8 @@ def lower_cell(cfg, shape: ShapeConfig, mesh, zero1=None):
                           "aux": P()}
             fn = jax.jit(
                 step,
-                in_shardings=shd.as_shardings((p_sh, o_sh, b_sh), mesh),
-                out_shardings=shd.as_shardings((p_sh, o_sh, metrics_sh),
-                                               mesh),
+                in_shardings=(p_sh, o_sh, b_sh),
+                out_shardings=(p_sh, o_sh, metrics_sh),
                 donate_argnums=(0, 1))
             lowered = fn.lower(pspec, ospec, batch)
             return lowered
@@ -97,8 +96,7 @@ def lower_cell(cfg, shape: ShapeConfig, mesh, zero1=None):
                 args.append(rspec)
                 shards.append(jax.tree.map(
                     lambda _: jax.sharding.PartitionSpec(), rspec))
-            fn = jax.jit(step, in_shardings=shd.as_shardings(
-                tuple(shards), mesh))
+            fn = jax.jit(step, in_shardings=tuple(shards))
             return fn.lower(*args)
         # decode
         step = steps_mod.make_decode_step(cfg, plans, shape.seq_len, ops)
@@ -116,8 +114,7 @@ def lower_cell(cfg, shape: ShapeConfig, mesh, zero1=None):
             args.append(rspec)
             shards.append(jax.tree.map(
                 lambda _: jax.sharding.PartitionSpec(), rspec))
-        fn = jax.jit(step, in_shardings=shd.as_shardings(tuple(shards),
-                                                         mesh),
+        fn = jax.jit(step, in_shardings=tuple(shards),
                      donate_argnums=(1,))
         return fn.lower(*args)
 

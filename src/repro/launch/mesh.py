@@ -8,30 +8,23 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types only exists on jax >= 0.5 (explicit-sharding work)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
-
-
-def set_mesh(mesh):
-    """Context manager scoping ``mesh``: jax.set_mesh on new jax, the
-    Mesh object itself (which is a context manager) on older releases."""
-    fn = getattr(jax, "set_mesh", None)
-    return fn(mesh) if fn is not None else mesh
+def _auto(n_axes: int) -> tuple:
+    """Every axis in Auto mode: the compiler propagates shardings from
+    the ``in_shardings`` / ``with_sharding_constraint`` annotations."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary test meshes (e.g. (2,2) on 4 fake devices)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=_auto(len(axes)))
 
 
 def data_axes(mesh) -> tuple:

@@ -7,7 +7,16 @@ Usage:
 
 Without --ckpt-dir the driver quantizes a fresh (random-init) model —
 useful for throughput measurement; with one it restores the trained
-params saved by launch.train.
+params saved by launch.train.  Prompts are ``--prompt-len`` random
+tokens (seeded); ``--shared-prefix N`` makes the first half of them
+start with one common N-token prefix (the prefix cache's traffic).
+``--warmup N`` serves the schedule's first N prompts once before the
+timed window, so the compiles land there (and the prefix cache holds
+those prompts, as on a server that has seen them).  ``main(argv)``
+returns the front end's ``describe()`` plus the engine's
+(``"engine"``), every request's tokens (``"streams"``), with
+``--record-logits`` a digest of each request's logits (``"digests"``),
+and the two timings (``"warmup_s"``, ``"window_s"``).
 
 Requests flow through :class:`repro.serving.ServingFrontend` — the
 asyncio admission/streaming layer — rather than a hand-rolled drain
@@ -31,6 +40,7 @@ from repro import ops as rops
 from repro.analysis import contracts
 from repro.checkpoint import load_checkpoint
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import transformer as tf
 from repro.quant import convert
@@ -72,11 +82,52 @@ async def _serve(fe: ServingFrontend, prompts, args) -> list:
     return handles
 
 
-def main():
+def load_quantized(arch: str, reduced: bool = False, ckpt_dir=None):
+    """``(cfg, qparams, plans)`` for one model: the checkpoint in
+    ``ckpt_dir`` or, without one, seeded random weights drawn at the
+    served scale (``init_params(..., served=True)``), quantized to the
+    integer datapath."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = M.reduce_config(cfg, dtype="float32", vocab=1024)
+    params = tf.init_params(jax.random.key(0), cfg, served=not ckpt_dir)
+    if ckpt_dir:
+        params, meta = load_checkpoint(ckpt_dir, (params, None))
+        params = params[0]
+        print(f"restored step {meta['step']} from {ckpt_dir}")
+    print("quantizing to the integer datapath ...")
+    qp, plans = convert.quantize_params(params, cfg)
+    return cfg, qp, plans
+
+
+def _prompts(args, vocab: int) -> list:
+    rng = np.random.default_rng(0)
+    prompts = [[int(t) for t in rng.integers(1, vocab, args.prompt_len)]
+               for _ in range(args.requests)]
+    n = args.shared_prefix
+    for p in prompts[1:args.requests // 2]:
+        p[:n] = prompts[0][:n]
+    return prompts
+
+
+def main(argv=None, model=None) -> dict:
+    """Serve the prompts ``argv`` describes; see the module docstring.
+    ``model``: an already-quantized ``(cfg, qparams, plans)`` from
+    :func:`load_quantized` for the same ``--arch`` / ``--reduced``, so a
+    caller serving one model several times quantizes it once."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-3-4b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--prompt-len", type=int, default=4,
+                    help="tokens per (random, seeded) prompt")
+    ap.add_argument("--shared-prefix", type=int, default=0,
+                    help="the first half of the prompts share one "
+                         "common prefix of this many tokens")
+    ap.add_argument("--warmup", type=int, default=0,
+                    help="serve the schedule's first N prompts once "
+                         "before the timed window (the compiles land "
+                         "there; the prefix cache keeps those prompts)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=128)
@@ -110,9 +161,9 @@ def main():
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree: shard attention heads "
                          "over a tp-device mesh (must divide the "
-                         "arch's KV head count); backends without the "
-                         "tp_serving capability — or a box without the "
-                         "devices — serve through an exact single-"
+                         "arch's KV head count; the process needs tp "
+                         "devices); backends without the tp_serving "
+                         "capability serve through an exact single-"
                          "device lowering instead")
     ap.add_argument("--spec-k", type=int, default=0,
                     help="speculative decoding: draft up to K tokens per "
@@ -135,12 +186,18 @@ def main():
                     help="open-loop Poisson arrival rate in requests/s "
                          "(exp-distributed gaps); 0 = submit every "
                          "request up front (closed batch)")
+    ap.add_argument("--record-logits", action="store_true",
+                    help="fold every logits row a token was chosen from "
+                         "into one SHA-256 per request (returned as "
+                         "\"digests\"): holds two runs to identical "
+                         "logits, not only identical tokens")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--backend", default=None,
                     help="registered op backend (default: REPRO_BACKEND "
                          "env or the arch's kernel_backend); one of "
                          f"{rops.available_backends()}")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     # resolve up front: a typo'd --backend should fail before the
@@ -165,6 +222,8 @@ def main():
         ap.error("--timeout-s must be > 0 seconds")
     if args.arrival_rate < 0:
         ap.error("--arrival-rate must be >= 0 requests/s")
+    if not 0 <= args.shared_prefix <= args.prompt_len:
+        ap.error("--shared-prefix must be within [0, --prompt-len]")
     if args.reduced:
         cfg = M.reduce_config(cfg, dtype="float32", vocab=1024)
     # --tp validates against the FINAL config (--reduced shrinks the
@@ -192,20 +251,17 @@ def main():
     # the request shape every client will submit must be feasible on
     # the cache geometry this engine is about to build — reject at the
     # CLI boundary with the same typed check frontend.submit() applies
-    prompt_len = 4
     try:
-        contracts.require_request(prompt_len, args.max_new,
+        contracts.require_request(args.prompt_len, args.max_new,
                                   args.cache_len, window=cfg.window)
     except contracts.RequestInfeasible as e:
-        ap.error(f"--max-new {args.max_new} with --cache-len "
-                 f"{args.cache_len}: {e}")
-    params = tf.init_params(jax.random.key(0), cfg)
-    if args.ckpt_dir:
-        params, meta = load_checkpoint(args.ckpt_dir, (params, None))
-        params = params[0]
-        print(f"restored step {meta['step']} from {args.ckpt_dir}")
-    print("quantizing to the integer datapath ...")
-    qp, plans = convert.quantize_params(params, cfg)
+        ap.error(f"--prompt-len {args.prompt_len} / --max-new "
+                 f"{args.max_new} with --cache-len {args.cache_len}: {e}")
+    if model is not None and model[0] != cfg:
+        raise ValueError(f"model= holds {model[0].name}, not the config "
+                         f"--arch {args.arch} describes")
+    cfg, qp, plans = model if model is not None else load_quantized(
+        args.arch, args.reduced, args.ckpt_dir)
     n_int8 = sum(l.size for l in jax.tree.leaves(qp)
                  if hasattr(l, "dtype") and l.dtype == jnp.int8)
     print(f"  {n_int8/1e6:.1f}M int8 weights "
@@ -221,12 +277,19 @@ def main():
                         prefill_budget=args.prefill_budget,
                         prefix_cache=not args.no_prefix_cache,
                         tp=args.tp, spec_k=args.spec_k,
-                        spec_mode=args.spec_mode)
+                        spec_mode=args.spec_mode,
+                        record_logits=args.record_logits)
     print(f"engine: {eng.describe_str()}")
+    prompts = _prompts(args, cfg.vocab)
+    t0 = time.time()
+    if args.warmup:
+        # the schedule's first prompts, served once: the compiles land
+        # here, and the prefix cache holds them afterwards, as it would
+        # on a server that has seen them
+        asyncio.run(_serve(ServingFrontend(eng), prompts[:args.warmup],
+                           args))
+    warmup_s = time.time() - t0
     fe = ServingFrontend(eng, max_pending=args.max_pending)
-    rng = np.random.default_rng(0)
-    prompts = [[int(t) for t in rng.integers(1, cfg.vocab, prompt_len)]
-               for _ in range(args.requests)]
     t0 = time.time()
     handles = asyncio.run(_serve(fe, prompts, args))
     dt = time.time() - t0
@@ -258,8 +321,15 @@ def main():
               f"{px['tokens_reused']} prompt tokens reused")
     for h in [h for h in handles if h is not None][:4]:
         r = h.request
-        print(f"  req {h.uid} [{h.terminal}]: {r.prompt} -> "
+        print(f"  req {h.uid} [{h.terminal}]: {r.prompt[:8]}... -> "
               f"{r.out_tokens[:10]}...")
+    sha = [h and h.request.logits_sha for h in handles]
+    return {**d, "engine": eng.describe(),
+            "streams": [None if h is None else list(h.tokens)
+                        for h in handles],
+            "digests": [x.hexdigest() if x else None for x in sha]
+            if args.record_logits else None,
+            "warmup_s": warmup_s, "window_s": dt}
 
 
 if __name__ == "__main__":

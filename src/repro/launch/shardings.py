@@ -19,18 +19,6 @@ from repro.core.treepath import path_parts
 Pytree = Any
 
 
-def as_shardings(tree, mesh):
-    """PartitionSpec trees -> jit-compatible shardings.
-
-    jax >= 0.5 accepts raw PartitionSpecs in in_shardings/out_shardings;
-    older releases need them wrapped in NamedSharding."""
-    if hasattr(jax, "set_mesh"):
-        return tree
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s) if isinstance(s, P) else s,
-        tree, is_leaf=lambda x: isinstance(x, P))
-
-
 def _path_str(path) -> str:
     return "/".join(path_parts(path))
 

@@ -23,7 +23,8 @@ from repro.data.pipeline import make_train_iterator
 from repro.distributed.fault import FaultTolerantLoop, StragglerDetector
 from repro.launch import shardings as shd
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models import transformer as tf
 from repro.optim import adamw_init
@@ -58,6 +59,7 @@ def main():
                     help="after training, quantize and run one integer "
                          "prefill through the configured op backend")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -72,7 +74,7 @@ def main():
     opt_cfg = AdamWConfig(lr=args.lr, zero1=True)
     lr_fn = linear_warmup_cosine(max(args.steps // 10, 1), args.steps)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = tf.init_params(jax.random.key(0), cfg)
         p_sh = shd.param_pspecs(params, mesh,
                                 fsdp=cfg.param_count() > 2e10)

@@ -14,6 +14,7 @@ Layer grouping for scan:
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -78,7 +79,32 @@ def _init_sublayer(key, cfg: ArchConfig, mix: str, ff: Optional[str],
     return p
 
 
-def init_params(key, cfg: ArchConfig) -> Pytree:
+#: the scale of random weights that are served rather than trained
+#: (``init_params(..., served=True)``)
+SERVED_EMBED_STD = 0.1
+SERVED_BRANCH_GAIN = 0.5
+
+
+def init_params(key, cfg: ArchConfig, served: bool = False) -> Pytree:
+    """Random parameters: a fan-in init, which training starts from.
+
+    ``served=True`` rescales the same draw for random weights that are
+    served instead (``launch.serve`` without a checkpoint,
+    ``chip_smoke.py``), so the integer datapath computes on a living
+    stream at published widths and depths:
+
+      * the embedding at std ``SERVED_EMBED_STD`` — the fan-in
+        1/sqrt(vocab), about 0.004 at a published vocab, sits under the
+        integer norms' pre-shift on the residual grid, so every integer
+        activation is 0 and all logits are equal;
+      * the residual branches' output projections (``wo`` at its whole
+        H·hd fan-in, ``w2``) at gain ``SERVED_BRANCH_GAIN`` — at 40
+        layers the fan-in draw (``wo``'s fan-in is taken over heads
+        alone) saturates the residual bus at ±16, and with tied
+        embeddings a stream that the current token's embedding
+        dominates repeats one token; at this scale the layers pick
+        the next token and nothing clips.
+    """
     dtype = jnp.dtype(cfg.dtype)
     gl, ng, kinds = layer_group_spec(cfg)
     keys = jax.random.split(key, ng * gl + 8)
@@ -103,7 +129,26 @@ def init_params(key, cfg: ArchConfig) -> Pytree:
             _init_sublayer(ekeys[i], cfg, "attn", "ffn", False, dtype)
             for i in range(cfg.enc_layers)])]
         params["enc_final_norm"] = fl.init_norm(cfg, dtype)
-    return params
+    return _served_scale(params, cfg) if served else params
+
+
+def _served_scale(params, cfg: ArchConfig):
+    """The ``served=True`` rescaling of :func:`init_params`."""
+    v = cfg.padded_vocab()
+    gains = {"wo": SERVED_BRANCH_GAIN / math.sqrt(cfg.hd or 1),
+             "w2": SERVED_BRANCH_GAIN}
+
+    def scale(path, leaf):
+        g = gains.get(getattr(path[-1], "key", None), 1.0)
+        return leaf if g == 1.0 else (leaf * g).astype(leaf.dtype)
+    out = dict(params)
+    out["embed"] = (params["embed"] * (SERVED_EMBED_STD * math.sqrt(v))
+                    ).astype(params["embed"].dtype)
+    for name in ("layers", "enc_layers"):
+        if name in params:
+            out[name] = jax.tree_util.tree_map_with_path(scale,
+                                                         params[name])
+    return out
 
 
 # ===================================================== float forward ======
@@ -194,7 +239,8 @@ def embed_tokens(params, tokens, cfg: ArchConfig):
 def logits_fwd(params, x, cfg: ArchConfig, qat=False):
     x = fl.norm_fwd(params["final_norm"], x, cfg)
     x = fl.maybe_fq(x, cfg.s_act8, enabled=qat)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    # tied, or an encoder (its MLM head shares the word embedding)
+    w = params["lm_head"] if "lm_head" in params else params["embed"].T
     logits = jnp.einsum("bsd,dv->bsv", x, fl.fq_weight(w, 1, qat))
     return shard(logits, "batch", "seq", "vocab")
 

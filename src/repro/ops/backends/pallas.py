@@ -12,9 +12,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.analysis.contracts import fit_block as _fit_block
+from repro.kernels import resolve_interpret
 from repro.kernels.int8_matmul import int8_matmul_pallas
 from repro.kernels.int_attention import int_attention_pallas
 from repro.kernels.int_gelu import int_gelu_pallas
@@ -23,12 +24,20 @@ from repro.kernels.int_softmax import int_softmax_pallas
 from repro.ops import spec as _spec
 
 
-def _fit_block(blk: int, dim: int) -> int:
-    """Largest block <= blk that divides dim (kernels assert dim % blk)."""
-    blk = min(blk, dim)
-    while dim % blk:
-        blk -= 1
-    return blk
+def _matmul_blocks(opts: dict, m: int, n: int, k: int, packed=False):
+    """Requested matmul blocks fitted to chip-legal divisors: rows a
+    multiple of 8, lanes (bn, and bk — the x block's lane dim) a
+    multiple of 128, else the whole dim (``contracts.fit_block``).
+    Packed weights pair nibbles along K, so bk is fitted on K/2 pairs
+    and doubled (its half is the packed block's row dim)."""
+    bm = _fit_block(opts.pop("bm", 128), m, 8)
+    bn = _fit_block(opts.pop("bn", 128), n, 128)
+    want_k = opts.pop("bk", 512)
+    if packed:
+        bk = 2 * _fit_block(max(want_k // 2, 1), k // 2, 64)
+    else:
+        bk = _fit_block(want_k, k, 128)
+    return bm, bn, bk
 
 
 class PallasBackend:
@@ -55,9 +64,7 @@ class PallasBackend:
         self.blocks = {op: dict(kw) for op, kw in (blocks or {}).items()}
 
     def _interp(self) -> bool:
-        if self._interpret is not None:
-            return self._interpret
-        return jax.default_backend() != "tpu"
+        return resolve_interpret(self._interpret)
 
     def _opts(self, op: str, call_opts: dict) -> dict:
         merged = dict(self.blocks.get(op, {}))
@@ -79,9 +86,7 @@ class PallasBackend:
         opts = self._opts("int8_matmul", opts)
         m, k = x8.shape
         n = w8.shape[-1]
-        bm = _fit_block(opts.pop("bm", 128), m)
-        bn = _fit_block(opts.pop("bn", 128), n)
-        bk = _fit_block(opts.pop("bk", 512), k)
+        bm, bn, bk = _matmul_blocks(opts, m, n, k)
         if spec.kind == _spec.PER_TENSOR:
             out = int8_matmul_pallas(x8, w8, bias32, dn=spec.dn,
                                      out_bits=spec.out_bits,

@@ -14,7 +14,10 @@ backend additionally advertises the two optional decode capabilities
 (docs/KERNELS.md): ``paged_decode`` (the page table rides as a second
 scalar-prefetch operand and KV blocks translate through it in the index
 map) and ``decode_wo_fold`` (the o-projection + its per-channel requant
-run as the launch's epilogue).
+run as the launch's epilogue).  ``wo`` folds only while its whole
+``(H·D, N)`` block fits the chip's VMEM budget
+(``analysis.contracts.can_fold_wo``); a wider projection runs after an
+unfolded launch, through the matmul kernel, with the same integers.
 
 Shapes the kernel can't tile fall back to the existing two-pass path
 with identical numerics:
@@ -35,7 +38,8 @@ from repro.analysis import contracts as _contracts
 from repro.analysis.budgets import MAX_ROWSUM_LEN as MAX_SKV
 from repro.kernels import ref as _ref
 from repro.ops import spec as _spec
-from repro.ops.backends.pallas import PallasBackend, _fit_block
+from repro.ops.backends.pallas import (PallasBackend, _fit_block,
+                                       _matmul_blocks)
 from repro.ops.paged import gather_pages as _gather
 
 # NOTE: the fused kernel modules (kernels.int_attention_fused /
@@ -92,10 +96,7 @@ class PallasFusedBackend(PallasBackend):
         meta = qw.pack_meta
         m, k = x8.shape
         n = qw.n_dim
-        bm = _fit_block(opts.pop("bm", 128), m)
-        bn = _fit_block(opts.pop("bn", 128), n)
-        # nibble pairing needs an even K-block: fit on K/2 pairs, double
-        bk = 2 * _fit_block(max(opts.pop("bk", 512) // 2, 1), k // 2)
+        bm, bn, bk = _matmul_blocks(opts, m, n, k, packed=True)
         msr = meta.scheme == "msr4" and meta.n_outliers > 0
         if not msr:
             # pure-nibble weights: one launch, full fused epilogue
@@ -143,7 +144,8 @@ class PallasFusedBackend(PallasBackend):
         if requant is None:
             requant = _spec.RequantSpec.per_tensor(plan.dn_out, out_bits)
         sq, skv = q8.shape[1], k8.shape[1]
-        bq = _fit_block(opts.pop("bq", 128), sq)
+        # the head-major output block's row dim: a multiple of 8 or Sq
+        bq = _fit_block(opts.pop("bq", 128), sq, 8)
         bkv = _fit_block(opts.pop("bkv", 128), skv)
         if not self._can_tile(sq, skv, bq, bkv):
             return self._two_pass_fallback(q8, k8, v8, plan, causal,
@@ -206,14 +208,16 @@ class PallasFusedBackend(PallasBackend):
             kw.update(pages=pages, page_size=page_size)
         if kv_shifts is not None:
             kw.update(kv_shifts=kv_shifts)
-        if wo is not None:
+        fold = self._folds(wo, requant, sq, q8, k8_cache, bkv)
+        if fold:
             kw.update(wo_w8=wo.w8, wo_bias32=wo.bias32, wo_b_vec=wo.b_mult,
                       wo_spec=wo_spec)
-        return int_decode_attention_fused(q8, k8_cache, v8_cache, plan,
-                                          valid_len, requant=requant,
-                                          b_vec=b_vec, bkv=bkv,
-                                          interpret=self._interp(),
-                                          **kw, **opts)
+        o = int_decode_attention_fused(q8, k8_cache, v8_cache, plan,
+                                       valid_len, requant=requant,
+                                       b_vec=b_vec, bkv=bkv,
+                                       interpret=self._interp(),
+                                       **kw, **opts)
+        return o if fold or wo is None else self._apply_wo(o, wo, wo_spec)
 
     # ---------------------------------------------------- paged prefill --
 
@@ -257,7 +261,7 @@ class PallasFusedBackend(PallasBackend):
         k_pool = scatter_chunk(k_pool, k8_new, base_pos, pages, page_size)
         v_pool = scatter_chunk(v_pool, v8_new, base_pos, pages, page_size)
         pos_end = jnp.asarray(base_pos, jnp.int32) + c
-        bq = _fit_block(opts.pop("bq", 128), c)
+        bq = _fit_block(opts.pop("bq", 128), c, 8)
         bkv = _fit_block(opts.pop("bkv", 128), page_size)
         if not self._can_tile_prefill(L, d, bq, bkv):
             # exact fallback: dequantize the (post-scatter) packed pools
@@ -281,14 +285,39 @@ class PallasFusedBackend(PallasBackend):
         kw = {}
         if kv_shifts is not None:
             kw.update(kv_shifts=kv_shifts)
-        if wo is not None:
+        fold = self._folds(wo, requant, bq, q8, k_pool, bkv)
+        if fold:
             kw.update(wo_w8=wo.w8, wo_bias32=wo.bias32, wo_b_vec=wo.b_mult,
                       wo_spec=wo_spec)
         o = int_paged_prefill_fused(q8, k_pool, v_pool, plan, pos_end,
                                     pages, page_size, requant=requant,
                                     b_vec=b_vec, bq=bq, bkv=bkv,
                                     interpret=self._interp(), **kw, **opts)
+        if not (fold or wo is None):
+            o = self._apply_wo(o, wo, wo_spec)
         return o, k_pool, v_pool
+
+    # ------------------------------------------------ folded wo policy --
+
+    @staticmethod
+    def _folds(wo, requant, rows, q8, kv, bkv) -> bool:
+        """Whether ``wo`` folds into this launch: only while the whole
+        ``(H·D, N)`` block keeps it inside the chip's VMEM budget
+        (``contracts.can_fold_wo``)."""
+        return wo is not None and _contracts.can_fold_wo(
+            rows, q8.shape[2], kv.shape[2], q8.shape[3], bkv,
+            wo.w8.shape[-1], kv_d=kv.shape[3],
+            per_channel=requant.kind == _spec.PER_CHANNEL)
+
+    def _apply_wo(self, o8, wo, wo_spec):
+        """The o-projection of an unfolded launch through this backend's
+        matmul kernel — the integers the folded epilogue would give."""
+        b, rows = o8.shape[0], o8.shape[1]
+        acc = self.int8_matmul(o8.reshape(b * rows, -1), wo.w8, wo_spec,
+                               bias32=wo.bias32, b_vec=wo.b_mult)
+        if not wo_spec.is_raw and wo_spec.out_bits <= 8:
+            acc = acc.astype("int8")
+        return acc.reshape(b, rows, -1)
 
     # the fused-vs-fallback tiling policy is owned declaratively by
     # repro.analysis.contracts so offline certification predicts the

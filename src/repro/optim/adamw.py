@@ -39,13 +39,13 @@ class AdamWConfig:
 
 def _zero1_shard(x):
     """Spread a moment tensor over the data axis when a mesh is in scope."""
-    from repro.distributed.sharding import current_axes, get_abstract_mesh
+    from repro.distributed.sharding import current_axes
     from jax.sharding import PartitionSpec as P
     axes = current_axes()
     if "data" not in axes:
         return x
     # shard the first dim divisible by the data axis size
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dsize = dict(zip(mesh.axis_names, mesh.axis_sizes))["data"]
     spec = [None] * x.ndim
     for i, s in enumerate(x.shape):

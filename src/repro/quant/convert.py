@@ -103,10 +103,11 @@ def _q_ffn(p, plans: qplans.FfnPlan):
     return out
 
 
-def _q_moe(p, plans: qplans.MoePlan):
+def _q_moe(p, plans: qplans.MoePlan, s_router=None):
     out = {}
     w = np.asarray(jax.device_get(p["router"]), np.float64)
-    s_router = np.abs(w).max() / 127.0
+    if s_router is None:
+        s_router = np.abs(w).max() / 127.0
     out["router"] = QuantLinearParams(jnp.asarray(
         np.clip(np.round(w / s_router), -127, 127).astype(np.int8)))
     out["w1"], _ = _q_linear(p["w1"], plans.expert.up)
@@ -118,17 +119,20 @@ def _q_moe(p, plans: qplans.MoePlan):
     return out, s_router
 
 
-def _q_mamba(p, mp: qplans.MambaPlan, cfg: ArchConfig):
+def _q_mamba(p, mp: qplans.MambaPlan, cfg: ArchConfig, s_dtw=None,
+             s_conv=None):
     w = np.asarray(jax.device_get(p["in_proj"]), np.float64)
     n_zxbc = w.shape[-1] - cfg.ssm_heads
     out = {}
     out["in_proj"], _ = _q_linear(w[..., :n_zxbc], mp.in_proj)
     wdt = w[..., n_zxbc:]
-    s_dtw = float(np.abs(wdt).max()) / 127.0
+    if s_dtw is None:
+        s_dtw = float(np.abs(wdt).max()) / 127.0
     out["dt_proj"] = QuantLinearParams(jnp.asarray(
         np.clip(np.round(wdt / s_dtw), -127, 127).astype(np.int8)))
     cw = np.asarray(jax.device_get(p["conv_w"]), np.float64)
-    s_conv = float(np.abs(cw).max()) / 127.0
+    if s_conv is None:
+        s_conv = float(np.abs(cw).max()) / 127.0
     out["conv_w8"] = jnp.asarray(
         np.clip(np.round(cw / s_conv), -127, 127).astype(np.int8))
     a = np.exp(np.asarray(jax.device_get(p["A_log"]), np.float64))
@@ -149,27 +153,70 @@ def _q_mamba(p, mp: qplans.MambaPlan, cfg: ArchConfig):
 
 
 def _q_sublayer(p, plans: qplans.LayerPlans, cfg: ArchConfig, kind,
-                calib_sink: dict):
+                calib_sink: dict, scales=None):
+    """``scales``: per-tensor scales fixed over a whole layer stack
+    (``_stack_scales``) — absent, they are measured on ``p`` itself."""
     mix, ff, has_cross = kind
+    scales = scales or {}
     out = {"norm1": _q_norm(p["norm1"], plans.norm)}
     if mix in ("attn", "cross"):
         out["attn"] = _q_attn(p["attn"],
                               plans.attn if mix == "attn" else plans.cross)
     else:
-        out["ssm"], s_dtw, s_conv = _q_mamba(p["ssm"], plans.mamba, cfg)
+        out["ssm"], s_dtw, s_conv = _q_mamba(
+            p["ssm"], plans.mamba, cfg, scales.get("s_dtw"),
+            scales.get("s_conv"))
         calib_sink["s_dtw"] = s_dtw
         calib_sink["s_conv"] = s_conv
     if has_cross:
         out["cross"] = _q_attn(p["cross"], plans.cross)
         out["norm_cross"] = _q_norm(p["norm_cross"], plans.norm)
     if ff == "moe":
-        out["moe"], s_router = _q_moe(p["moe"], plans.moe)
+        out["moe"], s_router = _q_moe(p["moe"], plans.moe,
+                                      scales.get("s_router"))
         calib_sink["s_router"] = s_router
     elif ff == "ffn":
         out["norm2"] = _q_norm(p["norm2"], plans.norm)
         out["ffn"] = _q_ffn(p["ffn"], plans.ffn)
     if ff == "moe":
         out["norm2"] = _q_norm(p["norm2"], plans.norm)
+    return out
+
+
+def _q_stacked(p_stack, plans: qplans.LayerPlans, cfg: ArchConfig, kind):
+    """Quantize a layer stack (every leaf has the stack on axis 0) one
+    layer at a time and restack the integer results on the host.
+
+    Identical to ``_q_sublayer`` on the whole stack — per-channel scales
+    are per layer along the leading axis, and the few per-tensor scales
+    (MoE router, Mamba dt / conv) are measured once over the stack
+    first — but the float64 working copy is one layer's, not the
+    stack's: at published widths a stacked FFN matrix alone is
+    gigabytes of float64 (40 x 2048 x 8192 x 8 bytes for Granite-3-2B).
+    """
+    n = jax.tree.leaves(p_stack)[0].shape[0]
+    scales = _stack_scales(p_stack, cfg, kind)
+    slices = [jax.tree.map(np.asarray, _q_sublayer(
+        jax.tree.map(lambda t: t[i], p_stack), plans, cfg, kind, {},
+        scales)) for i in range(n)]
+    return jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *slices)
+
+
+def _stack_scales(p_stack, cfg: ArchConfig, kind) -> dict:
+    """The per-tensor scales ``_q_moe`` / ``_q_mamba`` take over a whole
+    stack, measured without a float64 copy (max |w| is exact in any
+    float format, so the value equals the float64 one)."""
+    mix, ff, _ = kind
+
+    def amax(x):
+        return float(jnp.abs(x).max()) / 127.0
+    out = {}
+    if ff == "moe":
+        out["s_router"] = amax(p_stack["moe"]["router"])
+    if mix not in ("attn", "cross"):
+        w = p_stack["ssm"]["in_proj"]
+        out["s_dtw"] = amax(w[..., w.shape[-1] - cfg.ssm_heads:])
+        out["s_conv"] = amax(p_stack["ssm"]["conv_w"])
     return out
 
 
@@ -193,20 +240,22 @@ def quantize_params(params: Pytree, cfg: ArchConfig
     qparams["embed_w8"] = jnp.asarray(np.clip(
         np.round(emb / plans.embed.s_emb), -127, 127).astype(np.int8))
     qparams["final_norm"] = _q_norm(params["final_norm"], plans.final_norm)
-    head_w = emb.T if cfg.tie_embeddings else np.asarray(
+    # no lm_head (tied, or an encoder whose MLM head shares the word
+    # embedding, as RoBERTa's does): the head is the embedding
+    head_w = emb.T if "lm_head" not in params else np.asarray(
         jax.device_get(params["lm_head"]), np.float64)
     s_head = _pc_scales(head_w, 1)
     qparams["head"] = QuantLinearParams(jnp.asarray(np.clip(
         np.round(head_w / s_head[None, :]), -127, 127).astype(np.int8)))
     qparams["head_scale"] = jnp.asarray(s_head.astype(np.float32))
     qparams["layers"] = [
-        _q_sublayer(params["layers"][j], plans, cfg, kinds[j], {})
+        _q_stacked(params["layers"][j], plans, cfg, kinds[j])
         for j in range(gl)
     ]
     if cfg.family == "encdec":
         qparams["enc_layers"] = [
-            _q_sublayer(params["enc_layers"][0], plans, cfg,
-                        ("attn", "ffn", False), {})]
+            _q_stacked(params["enc_layers"][0], plans, cfg,
+                       ("attn", "ffn", False))]
         qparams["enc_final_norm"] = _q_norm(params["enc_final_norm"],
                                             plans.norm)
     return qparams, plans

@@ -60,10 +60,11 @@ query-head slice of wq/wk/wv, wo combines int32 partial o-projections
 with an exact :func:`~repro.distributed.collectives.psum_int32` *before*
 the requant epilogue (so it rounds once), and everything host-side
 (allocator, page table, prefix index, scheduler) stays replicated
-because page ids are device-agnostic.  Sharding engages only when every
-backend advertises the ``tp_serving`` capability and the process has
-``tp`` devices; otherwise the engine serves ``tp > 1`` through an exact
-single-device gather lowering (same API, same tokens).  Token streams
+because page ids are device-agnostic.  Sharding engages when every
+backend advertises the ``tp_serving`` capability (the process must then
+have ``tp`` devices, or construction raises); with a backend that does
+not, the engine serves ``tp > 1`` through an exact single-device gather
+lowering (same API, same tokens).  Token streams
 are bit-exact across tp degrees: the datapath is all-integer, so the
 psum is order-independent and the replicated non-attention sublayers
 see identical inputs on every device.
@@ -84,6 +85,11 @@ overwritten by the next step).  Token streams are bit-exact with
 degree.  Greedy only: ``temperature > 0`` requests are rejected with a
 typed :class:`~repro.serving.speculate.SpeculationUnsupported`.
 
+Logit digests (``record_logits``): each request folds every logits row
+one of its tokens was chosen from into a running SHA-256
+(``Request.logits_sha``), so two engines — backends, tp degrees — can
+be held to identical logits, not only to identical argmaxes.
+
 Shapes (batch lanes, page pool, logical cache length, prefill chunk) are
 fixed at engine construction, so lanes and pages recycle without
 recompiling.
@@ -91,6 +97,7 @@ recompiling.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import math
 import warnings
 from typing import Callable, Dict, List, Optional
@@ -185,6 +192,10 @@ class Request:
     temperature: float = 0.0
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # running SHA-256 of the logits rows the tokens came from (engines
+    # built with record_logits=True)
+    logits_sha: Optional[object] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
 
 @dataclasses.dataclass
@@ -221,7 +232,8 @@ class ServingEngine:
                  prefill_chunk: Optional[int] = None,
                  prefill_budget: Optional[int] = None,
                  prefix_cache: bool = True, tp: int = 1,
-                 spec_k: int = 0, spec_mode: str = "ngram"):
+                 spec_k: int = 0, spec_mode: str = "ngram",
+                 record_logits: bool = False):
         if backend is not None:
             warnings.warn("ServingEngine(backend=...) is deprecated; pass "
                           "ops= (an OpSet or backend name)",
@@ -245,12 +257,12 @@ class ServingEngine:
         self.fold_wo = fold_wo
         self.ops = resolve_ops(ops, cfg)
         # tensor parallelism: typed validation always (tp must divide
-        # Hkv, arch must be head-shardable), then capability/device
-        # negotiation picks the lowering — shard_map over a ("tp",) mesh
-        # when every backend advertises ``tp_serving`` and the process
-        # has the devices, else the exact single-device gather lowering
-        # (tokens identical either way, so tp > 1 is never an error on a
-        # 1-device box)
+        # Hkv, arch must be head-shardable), then capability negotiation
+        # picks the lowering — shard_map over a ("tp",) mesh when every
+        # backend advertises ``tp_serving``, else the exact single-
+        # device gather lowering (tokens identical either way).  A
+        # sharded engine on a process with fewer than ``tp`` devices is
+        # an error, never a silent single-device fallback
         tp_serving.validate_tp(cfg, tp)
         # speculative decoding: typed validation at the boundary (k in
         # budget, arch verify-able, proposer registered) — the Sq=K+1
@@ -264,9 +276,14 @@ class ServingEngine:
         self._spec_drafted = 0
         self._spec_accepted = 0
         self.tp = tp
-        self.tp_sharded = (tp > 1
-                           and tp_serving.backends_support_tp(self.ops)
-                           and jax.device_count() >= tp)
+        self.tp_sharded = tp > 1 and tp_serving.backends_support_tp(
+            self.ops)
+        if self.tp_sharded and jax.device_count() < tp:
+            raise ValueError(
+                f"tp={tp} needs {tp} devices for head-sharded serving, "
+                f"but this process sees {jax.device_count()} "
+                f"({jax.default_backend()}); run on a host with the "
+                "devices or serve with tp=1")
         self.mesh = tp_serving.make_tp_mesh(tp) if self.tp_sharded \
             else None
         if self.tp_sharded and self.fold_wo:
@@ -291,6 +308,7 @@ class ServingEngine:
             self.ops.backend_for("int_paged_prefill"), "paged_prefill",
             False)
         self.rng = np.random.default_rng(seed)
+        self.record_logits = record_logits
         self.rope_tab = il.build_rope_table(cache_len + 1, cfg.hd,
                                             cfg.rope_theta) \
             if cfg.pos == "rope" else None
@@ -546,15 +564,15 @@ class ServingEngine:
         table) replicate, and the returned caches keep their sharding so
         the next step consumes them in place.  Logits come back
         replicated — every device computed the identical full-width
-        value after the exact wo psum (``check_rep=False``: the
+        value after the exact wo psum (``check_vma=False``: the
         replication invariant is by integer-exactness construction, and
-        rep-checking doesn't trace through the pallas launches)."""
+        the varying-axes check doesn't trace through the pallas
+        launches)."""
         host = tuple(P() for _ in range(n_host_args))
         in_specs = (self._qspecs, self._cspecs) + host
         out_specs = self._cspecs if caches_only else (P(), self._cspecs)
-        smap = tp_serving.shard_map_fn()
-        return smap(step, mesh=self.mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False)
+        return jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # ------------------------------------------------------ scheduling ---
 
@@ -1074,12 +1092,22 @@ class ServingEngine:
             self.pos[i] += 1
             sess.pos = int(self.pos[i])
             row = logits[i][:self.cfg.vocab]
+            self._record(req, row)
             nxt = self._sample(req, row)
             req.out_tokens.append(nxt)
             sess.last_token = nxt
             if len(req.out_tokens) >= req.max_new_tokens \
                     or self._at_cache_end(i):
                 self._retire(i)
+
+    def _record(self, req: Request, rows: np.ndarray):
+        """Fold the logits rows ``req``'s committed tokens were chosen
+        from into its running digest (``record_logits``)."""
+        if not self.record_logits:
+            return
+        if req.logits_sha is None:
+            req.logits_sha = hashlib.sha256()
+        req.logits_sha.update(np.ascontiguousarray(rows).tobytes())
 
     def _sample(self, req: Request, row: np.ndarray) -> int:
         """Next token from one lane's logits row.
@@ -1148,6 +1176,7 @@ class ServingEngine:
             while a < len(draft) and int(preds[a]) == draft[a]:
                 a += 1
             commit = [int(t) for t in preds[:a + 1]]
+            self._record(req, rows[:a + 1])
             self._spec_drafted += len(draft)
             self._spec_accepted += a
             req.out_tokens.extend(commit)

@@ -169,6 +169,70 @@ def test_check_launch_decode_paged_prefetch():
     assert not rep.ok and any("Sq <=" in r for r in rep.reasons)
 
 
+def test_tpu_block_rule_refuses_one_head_kv_layout():
+    """Regression: the attention kernels once blocked the cache one head
+    at a time, ``(1, bkv, 1, d)`` over ``(..., L, Hkv, d)`` — legal in
+    interpret mode, refused by the chip's compiler (a last-two-dims
+    block of (1, d) over (Hkv, d)).  The offline rule refuses it too;
+    the all-heads block the kernels use now passes."""
+    pool = (65, 128, 8, 64)
+    old = contracts.check_blocks("int_decode_attention",
+                                 {"k": ((1, 128, 1, 64), pool)})
+    assert not old.ok
+    assert any("last-two-dims" in r and "(1)" in r for r in old.reasons)
+    new = contracts.check_blocks("int_decode_attention",
+                                 {"k": ((1, 128, 8, 64), pool)})
+    assert new.ok
+    # the kernels' own launches at Granite widths pass the rule
+    rep = check_launch("int_decode_attention", b=8, sq=1, h=32, hkv=8,
+                       d=64, max_pages=8, page_size=128, num_pages=65,
+                       per_channel=True, fold=True, n_out=2048)
+    assert rep.ok and rep.fused and rep.grid == (8, 3, 8)
+
+
+def test_vmem_budget_refuses_a_fold_too_wide_for_the_chip():
+    """Llama-3-8B's folded ``wo`` block (4096 x 4096) ran the v5e
+    topology compile out of VMEM: the contract refuses that launch, the
+    backend policy unfolds it, and the certifier notes it.  Granite and
+    H2O-Danube widths still fold; the unfolded launch fits."""
+    from repro.configs.registry import get_config
+    kw = dict(b=8, sq=1, h=32, hkv=8, max_pages=8, page_size=128,
+              num_pages=65, per_channel=True)
+    llama = check_launch("int_decode_attention", d=128, fold=True,
+                         n_out=4096, **kw)
+    assert not llama.ok and any("VMEM" in r for r in llama.reasons)
+    assert check_launch("int_decode_attention", d=128, **kw).ok
+    assert check_launch("int_decode_attention", d=120, fold=True,
+                        n_out=3840, **kw).ok
+    assert not contracts.can_fold_wo(1, 32, 8, 128, 128, 4096)
+    assert contracts.can_fold_wo(256, 32, 8, 64, 128, 2048)
+    # a matmul whose whole-vocab N block ran out of VMEM is refused too
+    assert not check_launch("int8_matmul", m=8, n=65552, k=2048, bm=8,
+                            bn=65552, bk=512).ok
+    rep = interpret.certify_config(get_config("llama3-8b"), seq_len=512,
+                                   cache_len=1024, page_size=128,
+                                   chunk=256)
+    pre = [o for o in rep.ops if o.layer.startswith("attn.prefill")]
+    assert pre and all("wo unfolded" in o.note for o in pre)
+    assert not any(o.path.startswith("fallback") for o in pre)
+
+
+def test_tpu_block_rule_matmul_and_1d_blocks():
+    # a 1-d partial block (the old (bn,) bias block) is refused
+    assert contracts.tpu_block_violations("bias32", (128,), (2048,))
+    assert not contracts.tpu_block_violations("gamma", (768,), (768,))
+    # rows not a multiple of 8 (and not the whole dim) are refused
+    rep = check_launch("int8_matmul", m=64, n=256, k=256, bm=4, bn=128,
+                       bk=256)
+    assert not rep.ok and any("multiple of 8" in r for r in rep.reasons)
+    # fit_block with alignment picks a legal divisor, else the whole dim
+    assert contracts.fit_block(128, 200, 8) == 40
+    assert contracts.fit_block(128, 7 * 256, 8) == 128
+    assert contracts.fit_block(512, 768, 128) == 384
+    assert contracts.fit_block(128, 100, 128) == 100
+    assert contracts.fit_block(128, 1000) == 125     # plain divisor
+
+
 def test_check_launch_unknown_op():
     with pytest.raises(KeyError, match="unknown kernel op"):
         check_launch("int_conv", x=1)

@@ -187,6 +187,35 @@ def test_prefill_wo_fold_matches_unfolded_composition(rng, form):
     assert want.shape == (b, c, n_out)
 
 
+def test_prefill_wo_too_wide_to_fold_runs_unfolded(rng, monkeypatch):
+    """Past the chip's VMEM budget the prefill launch runs unfolded and
+    ``wo`` goes through the matmul kernel, with the folded integers."""
+    from repro.analysis import contracts
+    b, h, hkv, d, ps, num_pages, c = 2, 4, 2, 16, 16, 9, 16
+    n_out = h * d
+    plan = _plan(d)
+    q8 = _chunk(rng, b, c, h, d)
+    kn, vn = _chunk(rng, b, c, hkv, d), _chunk(rng, b, c, hkv, d)
+    kp, vp = _pool(rng, num_pages, ps, hkv, d)
+    pages = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    base = jnp.asarray([0, 21], jnp.int32)
+    spec = RequantSpec.per_channel(c=28, pre=7, out_bits=8)
+    wo = QuantLinearParams(
+        jnp.asarray(rng.integers(-127, 128, (h * d, n_out)), jnp.int8),
+        jnp.asarray(rng.integers(1000, 30000, (n_out,)), jnp.int32),
+        jnp.asarray(rng.integers(-500, 500, (n_out,)), jnp.int32))
+    args = (q8, kn, vn, kp, vp, plan, base, pages, ps)
+    folded, _, _ = FUSED.int_paged_prefill(*args, wo=wo, wo_spec=spec)
+    monkeypatch.setattr(contracts, "can_fold_wo", lambda *a, **k: False)
+    unfolded, kk, _ = FUSED.int_paged_prefill(*args, wo=wo, wo_spec=spec)
+    want, kr, _ = resolve_ops("ref").int_paged_prefill(*args, wo=wo,
+                                                        wo_spec=spec)
+    assert unfolded.dtype == jnp.int8 and unfolded.shape == (b, c, n_out)
+    assert np.array_equal(np.asarray(unfolded), np.asarray(want))
+    assert np.array_equal(np.asarray(folded), np.asarray(want))
+    assert np.array_equal(np.asarray(kk), np.asarray(kr))
+
+
 def test_prefill_wo_fold_rejects_non_int8_attention_epilogue(rng):
     plan = _plan(16)
     q8 = _chunk(rng, 1, 16, 2, 16)
@@ -476,13 +505,6 @@ def test_bench_json_schema_checker(tmp_path):
             "prefix_hit_rate": None,
         }},
         "parity": True, "arch": "llama3-8b", "quick": True,
-        "tp": {
-            "devices": 4, "parity": True,
-            "tp1": {"tokens_per_s": 10.0, "mode": "off",
-                    "kv_bytes": 1024, "per_device_kv_bytes": 1024},
-            "tp4": {"tokens_per_s": 9.0, "mode": "sharded",
-                    "kv_bytes": 1024, "per_device_kv_bytes": 256},
-        },
         "spec": {
             "k0": {"tokens_per_s": 10.0, "accept_rate": None,
                    "drafted": 0, "accepted": 0},
@@ -513,7 +535,7 @@ def test_bench_json_schema_checker(tmp_path):
     if os.path.exists(real):                # generated by bench runs
         assert check_file(real) == []
     del data["parity"]
-    del data["tp"]["tp4"]["per_device_kv_bytes"]
+    del data["spec"]["k2"]["accepted"]
     for cfg in data["configs"].values():
         cfg["tokens_per_s"] = "fast"
     # semantic violations the structural pass can't see: inverted
@@ -530,7 +552,7 @@ def test_bench_json_schema_checker(tmp_path):
     errors = check_file(str(bad))
     assert any("parity" in e for e in errors)
     assert any("tokens_per_s" in e for e in errors)
-    assert any("per_device_kv_bytes" in e for e in errors)
+    assert any("accepted" in e for e in errors)
     assert any("p50" in e and "p99" in e for e in errors)
     assert any("submitted" in e for e in errors)
     assert any("1.8x gate" in e for e in errors)

@@ -79,3 +79,28 @@ def test_integer_path_preserves_accuracy(trained):
     assert acc_f > 0.25, f"float model failed to learn ({acc_f})"
     assert acc_i > acc_f - 0.05, \
         f"integer path lost accuracy: float {acc_f:.3f} int {acc_i:.3f}"
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "roberta-base",
+                                  "jamba-v0.1-52b"])
+def test_layerwise_quantization_matches_stacked(arch):
+    """``quantize_params`` quantizes a layer stack one layer at a time
+    (bounded host memory at published depth); the integers must equal
+    quantizing the whole stack at once — scales are per layer along the
+    stack axis, so slicing cannot change a single value."""
+    from repro.models.transformer import layer_group_spec
+    cfg = M.reduce_config(get_config(arch), dtype="float32", vocab=256)
+    params = tf.init_params(jax.random.key(1), cfg)
+    qp, plans = convert.quantize_params(params, cfg)
+    gl, ng, kinds = layer_group_spec(cfg)
+    assert ng > 1                       # a real stack, not one layer
+    for j in range(gl):
+        whole = convert._q_sublayer(params["layers"][j], plans, cfg,
+                                    kinds[j], {})
+        got_leaves, got_def = jax.tree.flatten(qp["layers"][j])
+        want_leaves, want_def = jax.tree.flatten(whole)
+        assert got_def == want_def
+        for g, w in zip(got_leaves, want_leaves):
+            g, w = np.asarray(g), np.asarray(w)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.array_equal(g, w)

@@ -167,6 +167,43 @@ def test_wo_fold_matches_unfolded_composition(rng, form, paged):
     assert want.shape == (b, 1, n_out)
 
 
+@pytest.mark.parametrize("paged", [False, True])
+def test_wo_too_wide_to_fold_runs_unfolded(rng, monkeypatch, paged):
+    """A ``wo`` block past the chip's VMEM budget is not folded: the
+    kernel runs unfolded and the projection goes through the matmul
+    kernel — the same integers as the folded epilogue and the oracle."""
+    from repro.analysis import contracts
+    b, h, hkv, d, L = 2, 4, 2, 16, 64
+    n_out = h * d
+    plan = _plan(d)
+    q8 = jnp.asarray(rng.integers(-127, 128, (b, 1, h, d)), jnp.int8)
+    if paged:
+        ps, num_pages = 16, 9
+        kp, vp = _pool(rng, num_pages, ps, hkv, d)
+        pages = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+        kw = dict(pages=pages, page_size=ps)
+    else:
+        kp = jnp.asarray(rng.integers(-127, 128, (b, L, hkv, d)), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, (b, L, hkv, d)), jnp.int8)
+        kw = {}
+    vl = jnp.asarray([21, 64], jnp.int32)
+    spec = RequantSpec.per_channel(c=28, pre=7, out_bits=8)
+    wo = QuantLinearParams(
+        jnp.asarray(rng.integers(-127, 128, (h * d, n_out)), jnp.int8),
+        jnp.asarray(rng.integers(1000, 30000, (n_out,)), jnp.int32),
+        jnp.asarray(rng.integers(-500, 500, (n_out,)), jnp.int32))
+    folded = np.asarray(FUSED.int_decode_attention(
+        q8, kp, vp, plan, vl, wo=wo, wo_spec=spec, **kw))
+    monkeypatch.setattr(contracts, "can_fold_wo", lambda *a, **k: False)
+    unfolded = FUSED.int_decode_attention(q8, kp, vp, plan, vl, wo=wo,
+                                          wo_spec=spec, **kw)
+    want = np.asarray(resolve_ops("ref").int_decode_attention(
+        q8, kp, vp, plan, vl, wo=wo, wo_spec=spec, **kw))
+    assert unfolded.dtype == jnp.int8 and unfolded.shape == (b, 1, n_out)
+    assert np.array_equal(np.asarray(unfolded), want)
+    assert np.array_equal(folded, want)
+
+
 def test_wo_fold_rejects_non_int8_attention_epilogue(rng):
     plan = _plan(16)
     q8 = jnp.asarray(rng.integers(-127, 128, (1, 1, 2, 16)), jnp.int8)

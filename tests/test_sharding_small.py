@@ -19,7 +19,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.registry import get_config
 from repro.launch import shardings as shd
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import model as M, transformer as tf
 from repro.optim import adamw_init
 from repro.optim.adamw import AdamWConfig
@@ -39,14 +39,13 @@ if cfg.family == "vlm":
 if cfg.family == "encdec":
     batch["src_embeds"] = jax.random.normal(
         jax.random.key(3), (b, s, cfg.d_model))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     opt_cfg = AdamWConfig(lr=1e-3)
     p_sh = shd.param_pspecs(params, mesh)
     step = steps_mod.make_train_step(cfg, opt_cfg, param_specs=p_sh)
     opt = adamw_init(params, opt_cfg)
     b_sh = shd.batch_pspecs(batch, mesh)
-    fn = jax.jit(step, in_shardings=(shd.as_shardings(p_sh, mesh), None,
-                                     shd.as_shardings(b_sh, mesh)))
+    fn = jax.jit(step, in_shardings=(p_sh, None, b_sh))
     params2, opt2, metrics = fn(params, opt, batch)
     loss1 = float(metrics["loss"])
     _, _, metrics2 = fn(params2, opt2, batch)

@@ -169,6 +169,38 @@ def test_spec_streams_bit_exact_across_backend_and_cache_mode(setup):
             assert spec["wasted"] == spec["drafted"] - spec["accepted"]
 
 
+def _digests(setup, spec_k, ops, **kw):
+    cfg, qp, plans = setup
+    eng = ServingEngine(qp, plans, cfg, batch_size=2, cache_len=64,
+                        spec_k=spec_k, ops=ops, record_logits=True, **kw)
+    reqs = [Request(uid=i, prompt=list(p), max_new_tokens=12)
+            for i, p in enumerate(PROMPTS)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_done()
+    return [r.logits_sha.hexdigest() for r in reqs]
+
+
+def test_logit_digests_agree_across_backend_and_spec(setup):
+    """``record_logits``: the per-request digest of every logits row a
+    token came from is identical across backends and with speculation
+    on (verify rows are the sequential rows), and it sees the logits
+    themselves — a change to one weight that keeps every argmax still
+    changes it."""
+    base = _digests(setup, 0, "ref")
+    assert len(set(base)) == len(PROMPTS)
+    assert _digests(setup, 0, "pallas_fused") == base
+    assert _digests(setup, 2, "ref") == base
+    assert _digests(setup, 2, "pallas_fused",
+                    cache_mode="contiguous") == base
+    cfg, qp, plans = setup
+    # token 0's logit moves by a part in 2^20: no argmax changes
+    nudged = (cfg, dict(qp, head_scale=qp["head_scale"].at[0].multiply(
+        1 + 2 ** -20)), plans)
+    assert _drive(nudged, 0, ops="ref")[1] == _drive(setup, 0, ops="ref")[1]
+    assert all(a != b for a, b in zip(_digests(nudged, 0, "ref"), base))
+
+
 def test_spec_accepts_drafts_on_repeated_structure(setup):
     """Prompt-lookup must actually land drafts on repetitive traffic —
     accept-rate > 0, and accepted drafts shorten the step count."""
