@@ -1,0 +1,23 @@
+"""Published peaks of the chips the benchmark runs on, keyed by the
+``device_kind`` that JAX reports.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2 at 819 GB/s per chip.
+A device that is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+_V5E = {"int8_ops_per_s": 393e12, "bf16_flops_per_s": 197e12,
+        "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9,
+        "source": "Google Cloud TPU v5e documentation"}
+
+PEAKS = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise SystemExit(f"bench: no published peaks for device kind "
+                         f"{device_kind!r}; add it to bench/lib/peaks.py "
+                         "with its source") from None
