@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.dyadic import Dyadic
 from repro.kernels import resolve_interpret
@@ -161,5 +162,6 @@ def int8_matmul_pallas(x8, w8, bias32=None, dn: Dyadic = None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name=trace_names.kernel("int8_matmul"),
         interpret=resolve_interpret(interpret),
     )(*args)

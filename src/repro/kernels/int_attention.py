@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
 from repro.kernels import resolve_interpret
@@ -145,5 +146,6 @@ def int_attention_pallas(q8, k8, v8, plan: IAttnPlan, causal: bool = True,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, d), jnp.int32)],
+        name=trace_names.kernel("int_attention"),
         interpret=resolve_interpret(interpret),
     )(q8, k8, v8)

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.budgets import MAX_ROWSUM_LEN
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
@@ -403,6 +404,7 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
                                lambda bi, qi, ph, ki: (bi, 0, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), out_dtype),
         scratch_shapes=_attn_scratch(h, bq, d),
+        name=trace_names.kernel("int_attention_fused"),
         interpret=resolve_interpret(interpret),
     )(*args)
     return jnp.swapaxes(out, 1, 2)
@@ -657,6 +659,7 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=trace_names.kernel("int_paged_prefill_fused"),
         interpret=resolve_interpret(interpret),
     )(*scalar_args, *args)
     return out if fold else jnp.swapaxes(out, 1, 2)

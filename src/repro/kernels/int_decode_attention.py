@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.budgets import MAX_ROWSUM_LEN
 from repro.analysis.budgets import MAX_SQ as _MAX_SQ
 from repro.analysis.contracts import check_launch, require_launch
@@ -315,6 +316,7 @@ def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name=trace_names.kernel("int_decode_attention"),
         interpret=resolve_interpret(interpret),
     )(*scalar_args, *args)
     return out if fold else jnp.swapaxes(out, 1, 2)

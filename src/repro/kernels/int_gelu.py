@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.contracts import fit_block
 from repro.core.dyadic import Dyadic
 from repro.core.intmath import IGeluPlan
@@ -71,6 +72,7 @@ def int_gelu_pallas(q, plan: IGeluPlan, dn_out: Dyadic, out_bits: int = 8,
         in_specs=[pl.BlockSpec((br, lanes), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        name=trace_names.kernel("int_gelu"),
         interpret=resolve_interpret(interpret),
     )(x2)
     return out.reshape(-1)[:n].reshape(shape)

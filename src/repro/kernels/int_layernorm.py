@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.contracts import fit_block
 from repro.core.norms import INormPlan
 from repro.kernels import resolve_interpret
@@ -110,6 +111,7 @@ def int_layernorm_pallas(q, q_gamma, q_beta, plan: INormPlan,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, d), jnp.int32),
+        name=trace_names.kernel("int_norm"),
         interpret=resolve_interpret(interpret),
     )(*args)
     return out[:rows].reshape(shape)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import trace_names
 from repro.analysis.contracts import fit_block
 from repro.core.softmax import ISoftmaxPlan, PROB_SHIFT, RECIP_BITS
 from repro.kernels import resolve_interpret
@@ -94,6 +95,7 @@ def int_softmax_pallas(scores, plan: ISoftmaxPlan, valid_len: int = -1,
         in_specs=[pl.BlockSpec((br, row_len), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, row_len), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, row_len), jnp.int8),
+        name=trace_names.kernel("int_softmax"),
         interpret=resolve_interpret(interpret),
     )(x2)
     return out[:rows].reshape(shape)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace_names
 from repro.core import activations as iact
 from repro.core import attention as iattn
 from repro.core import intmath, norms
@@ -447,19 +448,24 @@ def int_ffn_fwd(qp, x8, plans: qplans.FfnPlan, cfg: ArchConfig,
                 ops=None):
     """x8 (B,S,D) int8 -> int32 at s_res."""
     ops = resolve_ops(ops, cfg)
-    h1 = int_linear(x8, qp["w1"], plans.up, ops)            # 10-bit int32
-    if cfg.activation == "swiglu":
-        h3 = int_linear(x8, qp["w3"], plans.up, ops)
-        a8 = iact.i_silu(h1, plans.act_silu, out_bits=8)
-        prod = a8 * h3                                      # s8 * s10
-        h = clip_to_bits(plans.dn_gate(prod), 8).astype(jnp.int8)
-    else:
-        a = ops.int_gelu(h1, plans.act_gelu.gelu, plans.act_gelu.dn_out,
-                         out_bits=8)
-        h = a.astype(jnp.int8)
-    h = shard(h, "batch", "seq", "ffn")
-    return shard(int_linear(h, qp["w2"], plans.down, ops),
-                 "batch", "seq", "embed")
+    swiglu = cfg.activation == "swiglu"
+    with trace_names.scope("ffn.up"):
+        h1 = int_linear(x8, qp["w1"], plans.up, ops)        # 10-bit int32
+        if swiglu:
+            h3 = int_linear(x8, qp["w3"], plans.up, ops)
+    with trace_names.scope("ffn.act"):
+        if swiglu:
+            a8 = iact.i_silu(h1, plans.act_silu, out_bits=8)
+            prod = a8 * h3                                  # s8 * s10
+            h = clip_to_bits(plans.dn_gate(prod), 8).astype(jnp.int8)
+        else:
+            a = ops.int_gelu(h1, plans.act_gelu.gelu,
+                             plans.act_gelu.dn_out, out_bits=8)
+            h = a.astype(jnp.int8)
+    with trace_names.scope("ffn.down"):
+        h = shard(h, "batch", "seq", "ffn")
+        return shard(int_linear(h, qp["w2"], plans.down, ops),
+                     "batch", "seq", "embed")
 
 
 # --------------------------------------------------------------- moe ------

@@ -17,6 +17,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from repro import trace_names
 from repro.distributed.sharding import shard
 from repro.models import intlayers as il
 from repro.models.common import ArchConfig
@@ -28,7 +29,14 @@ Pytree = Any
 
 
 def _residual_add(x32, delta32, cfg: ArchConfig):
-    return jnp.clip(x32 + delta32, -cfg.qmax_res, cfg.qmax_res)
+    with trace_names.scope("residual"):
+        return jnp.clip(x32 + delta32, -cfg.qmax_res, cfg.qmax_res)
+
+
+def _norm(qp, key: str, x32, plan, ops):
+    """The integer norm of parameters ``qp[key]``, scoped by ``key``."""
+    with trace_names.scope(key):
+        return il.int_norm(qp[key], x32, plan, ops)
 
 
 def _sub_plans(plans: qplans.LayerPlans, kind):
@@ -40,40 +48,52 @@ def _int_sublayer_fwd(qp, x32, plans: qplans.LayerPlans, cfg: ArchConfig,
                       kind, rope_tab, positions, causal, memory8, ops):
     """Pre-norm integer sublayer.  x32: (B,S,D) int32 at s_res."""
     mix, ff, has_cross = kind
-    h8 = il.int_norm(qp["norm1"], x32, plans.norm, ops)
+    h8 = _norm(qp, "norm1", x32, plans.norm, ops)
     if mix == "attn":
-        a32 = il.int_attn_fwd(qp["attn"], h8, plans.attn, cfg, rope_tab,
-                              positions, causal=causal, window=cfg.window,
-                              ops=ops)
+        with trace_names.scope("attn"):
+            a32 = il.int_attn_fwd(qp["attn"], h8, plans.attn, cfg,
+                                  rope_tab, positions, causal=causal,
+                                  window=cfg.window, ops=ops)
     elif mix == "cross":
-        a32 = il.int_attn_fwd(qp["attn"], h8, plans.cross, cfg, None,
-                              positions, causal=False, memory8=memory8,
-                              ops=ops)
+        with trace_names.scope("cross_attn"):
+            a32 = il.int_attn_fwd(qp["attn"], h8, plans.cross, cfg, None,
+                                  positions, causal=False, memory8=memory8,
+                                  ops=ops)
     else:
-        out, _ = il.int_mamba_prefill(qp["ssm"], h8, plans.mamba, cfg,
-                                      ops=ops)
-        a32 = out
+        with trace_names.scope("ssm"):
+            a32, _ = il.int_mamba_prefill(qp["ssm"], h8, plans.mamba, cfg,
+                                          ops=ops)
     x32 = _residual_add(x32, a32, cfg)
     if has_cross:
-        h8 = il.int_norm(qp["norm_cross"], x32, plans.norm, ops)
-        c32 = il.int_attn_fwd(qp["cross"], h8, plans.cross, cfg, None,
-                              positions, causal=False, memory8=memory8,
-                              ops=ops)
+        h8 = _norm(qp, "norm_cross", x32, plans.norm, ops)
+        with trace_names.scope("cross_attn"):
+            c32 = il.int_attn_fwd(qp["cross"], h8, plans.cross, cfg, None,
+                                  positions, causal=False, memory8=memory8,
+                                  ops=ops)
         x32 = _residual_add(x32, c32, cfg)
     if ff is not None:
-        h8 = il.int_norm(qp["norm2"], x32, plans.norm, ops)
-        if ff == "moe":
-            f32 = il.int_moe_fwd(qp["moe"], h8, plans.moe, cfg, ops)
-        else:
-            f32 = il.int_ffn_fwd(qp["ffn"], h8, plans.ffn, cfg, ops)
+        h8 = _norm(qp, "norm2", x32, plans.norm, ops)
+        f32 = _int_ff(qp, h8, plans, cfg, ff, ops)
         x32 = _residual_add(x32, f32, cfg)
     return x32
 
 
+def _int_ff(qp, h8, plans: qplans.LayerPlans, cfg: ArchConfig, ff, ops,
+            **moe_kw):
+    """The feed-forward sublayer: an MoE (scoped ``moe``) or a dense FFN
+    (scoped ``ffn.*`` inside ``int_ffn_fwd``)."""
+    if ff == "moe":
+        with trace_names.scope("moe"):
+            return il.int_moe_fwd(qp["moe"], h8, plans.moe, cfg, ops,
+                                  **moe_kw)
+    return il.int_ffn_fwd(qp["ffn"], h8, plans.ffn, cfg, ops)
+
+
 def embed_int(qparams, tokens, plans: qplans.LayerPlans, cfg: ArchConfig):
-    e8 = jnp.take(qparams["embed_w8"], tokens, axis=0).astype(jnp.int32)
-    x32 = plans.embed.dn_res(e8)
-    return shard(x32, "batch", "seq", "embed")
+    with trace_names.scope("embed"):
+        e8 = jnp.take(qparams["embed_w8"], tokens, axis=0).astype(jnp.int32)
+        x32 = plans.embed.dn_res(e8)
+        return shard(x32, "batch", "seq", "embed")
 
 
 def quantize_memory(mem_f, cfg: ArchConfig):
@@ -85,14 +105,16 @@ def quantize_memory(mem_f, cfg: ArchConfig):
 def logits_int(qparams, x32, plans: qplans.LayerPlans, cfg: ArchConfig,
                ops=None):
     ops = resolve_ops(ops, cfg)
-    h8 = il.int_norm(qparams["final_norm"], x32, plans.final_norm, ops)
+    h8 = _norm(qparams, "final_norm", x32, plans.final_norm, ops)
     head_plan = qplans.LinearPlan(cfg.s_act8, 0.0, 32, 0, 0, cfg.d_model)
-    acc = il.int_linear(h8, qparams["head"], head_plan, ops)
-    # host-side dequant boundary: float per-channel scales
-    return acc.astype(jnp.float32) * qparams["head_scale"][None] \
-        * cfg.s_act8
+    with trace_names.scope("head"):
+        acc = il.int_linear(h8, qparams["head"], head_plan, ops)
+        # host-side dequant boundary: float per-channel scales
+        return acc.astype(jnp.float32) * qparams["head_scale"][None] \
+            * cfg.s_act8
 
 
+@trace_names.entry_point
 def int_prefill(qparams, batch, plans: qplans.LayerPlans, cfg: ArchConfig,
                 ops=None, return_cache=False, cache_len: int = 0,
                 rope_tab=None):
@@ -152,7 +174,7 @@ def _int_encoder(qparams, src_embeds, plans, cfg: ArchConfig, ops):
     enc = qparams["enc_layers"]
     x32, _ = jax.lax.scan(body, x32, enc[0] if isinstance(enc, list)
                           else enc)
-    return il.int_norm(qparams["enc_final_norm"], x32, plans.norm, ops)
+    return _norm(qparams, "enc_final_norm", x32, plans.norm, ops)
 
 
 # ============================================================ decode =======
@@ -226,36 +248,37 @@ def _int_sublayer_decode(qp, cache, x32, plans, cfg: ArchConfig, kind,
                          fold_wo: bool = False, tp_axis=None):
     mix, ff, has_cross = kind
     new_cache = dict(cache)
-    h8 = il.int_norm(qp["norm1"], x32, plans.norm, ops)
+    h8 = _norm(qp, "norm1", x32, plans.norm, ops)
     if mix == "attn":
-        a32, kv = il.int_attn_decode(qp["attn"], h8, cache, pos,
-                                     plans.attn, cfg, rope_tab,
-                                     window=cfg.window, ops=ops,
-                                     pages=pages, page_size=page_size,
-                                     max_len=max_len, fold_wo=fold_wo,
-                                     tp_axis=tp_axis)
+        with trace_names.scope("attn"):
+            a32, kv = il.int_attn_decode(qp["attn"], h8, cache, pos,
+                                         plans.attn, cfg, rope_tab,
+                                         window=cfg.window, ops=ops,
+                                         pages=pages, page_size=page_size,
+                                         max_len=max_len, fold_wo=fold_wo,
+                                         tp_axis=tp_axis)
         new_cache.update(kv)
     elif mix == "cross":
-        a32 = _cross_decode(qp["attn"], h8, cache, plans, cfg, pos, ops)
+        with trace_names.scope("cross_attn"):
+            a32 = _cross_decode(qp["attn"], h8, cache, plans, cfg, pos,
+                                ops)
     else:
-        st = il.IntMambaState(cache["h"], cache["conv"])
-        a32_t, st = il.int_mamba_step(qp["ssm"], h8[:, 0], st, plans.mamba,
-                                      cfg, ops)
-        a32 = a32_t[:, None]
+        with trace_names.scope("ssm"):
+            st = il.IntMambaState(cache["h"], cache["conv"])
+            a32_t, st = il.int_mamba_step(qp["ssm"], h8[:, 0], st,
+                                          plans.mamba, cfg, ops)
+            a32 = a32_t[:, None]
         new_cache.update({"h": st.h, "conv": st.conv})
     x32 = _residual_add(x32, a32, cfg)
     if has_cross:
-        h8 = il.int_norm(qp["norm_cross"], x32, plans.norm, ops)
-        c32 = _cross_decode(qp["cross"], h8, cache, plans, cfg, pos,
-                            ops)
+        h8 = _norm(qp, "norm_cross", x32, plans.norm, ops)
+        with trace_names.scope("cross_attn"):
+            c32 = _cross_decode(qp["cross"], h8, cache, plans, cfg, pos,
+                                ops)
         x32 = _residual_add(x32, c32, cfg)
     if ff is not None:
-        h8 = il.int_norm(qp["norm2"], x32, plans.norm, ops)
-        if ff == "moe":
-            f32 = il.int_moe_fwd(qp["moe"], h8, plans.moe, cfg, ops,
-                                 group_size=1)
-        else:
-            f32 = il.int_ffn_fwd(qp["ffn"], h8, plans.ffn, cfg, ops)
+        h8 = _norm(qp, "norm2", x32, plans.norm, ops)
+        f32 = _int_ff(qp, h8, plans, cfg, ff, ops, group_size=1)
         x32 = _residual_add(x32, f32, cfg)
     return x32, new_cache
 
@@ -277,6 +300,7 @@ def _cross_decode(qp, h8, cache, plans, cfg, pos, ops):
                          plans.cross.out, ops)
 
 
+@trace_names.entry_point
 def int_decode_step(qparams, caches, tokens, pos, plans, cfg: ArchConfig,
                     rope_tab=None, ops=None, pages=None,
                     page_size: int = 0, max_len: int = 0,
@@ -334,6 +358,7 @@ def speculative_decode_supported(cfg: ArchConfig) -> bool:
                                    for (mix, ff, has_cross) in kinds)
 
 
+@trace_names.entry_point
 def int_verify_step(qparams, caches, tokens, pos, n_new, plans,
                     cfg: ArchConfig, rope_tab=None, ops=None, pages=None,
                     page_size: int = 0, max_len: int = 0,
@@ -377,24 +402,19 @@ def int_verify_step(qparams, caches, tokens, pos, n_new, plans,
         for j, kind in enumerate(kinds):
             qp, cache = qp_group[j], cache_group[j]
             new_cache = dict(cache)
-            h8 = il.int_norm(qp["norm1"], x32, plans.norm, ops)
-            a32, kv = il.int_attn_decode(qp["attn"], h8, cache, pos,
-                                         plans.attn, cfg, rope_tab,
-                                         window=0, ops=ops, pages=pages,
-                                         page_size=page_size,
-                                         max_len=max_len, fold_wo=fold_wo,
-                                         tp_axis=tp_axis, n_new=n_new)
+            h8 = _norm(qp, "norm1", x32, plans.norm, ops)
+            with trace_names.scope("attn"):
+                a32, kv = il.int_attn_decode(
+                    qp["attn"], h8, cache, pos, plans.attn, cfg, rope_tab,
+                    window=0, ops=ops, pages=pages, page_size=page_size,
+                    max_len=max_len, fold_wo=fold_wo, tp_axis=tp_axis,
+                    n_new=n_new)
             new_cache.update(kv)
             x32 = _residual_add(x32, a32, cfg)
             _, ff, _ = kind
             if ff is not None:
-                h8 = il.int_norm(qp["norm2"], x32, plans.norm, ops)
-                if ff == "moe":
-                    f32 = il.int_moe_fwd(qp["moe"], h8, plans.moe, cfg,
-                                         ops, group_size=1)
-                else:
-                    f32 = il.int_ffn_fwd(qp["ffn"], h8, plans.ffn, cfg,
-                                         ops)
+                h8 = _norm(qp, "norm2", x32, plans.norm, ops)
+                f32 = _int_ff(qp, h8, plans, cfg, ff, ops, group_size=1)
                 x32 = _residual_add(x32, f32, cfg)
             new_group.append(new_cache)
         return x32, tuple(new_group)
@@ -420,6 +440,7 @@ def chunked_prefill_supported(cfg: ArchConfig) -> bool:
                                    for kind in kinds)
 
 
+@trace_names.entry_point
 def int_prefill_chunk_step(qparams, caches, tokens, base_pos, plans,
                            cfg: ArchConfig, rope_tab=None, ops=None,
                            pages=None, page_size: int = 0,
@@ -456,14 +477,15 @@ def int_prefill_chunk_step(qparams, caches, tokens, base_pos, plans,
         for j in range(len(kinds)):
             qp, cache = qp_group[j], cache_group[j]
             new_cache = dict(cache)
-            h8 = il.int_norm(qp["norm1"], x32, plans.norm, ops)
-            a32, kv = il.int_attn_prefill_chunk(
-                qp["attn"], h8, cache, base_pos, plans.attn, cfg,
-                rope_tab, ops=ops, pages=pages, page_size=page_size,
-                fold_wo=fold_wo, tp_axis=tp_axis)
+            h8 = _norm(qp, "norm1", x32, plans.norm, ops)
+            with trace_names.scope("attn"):
+                a32, kv = il.int_attn_prefill_chunk(
+                    qp["attn"], h8, cache, base_pos, plans.attn, cfg,
+                    rope_tab, ops=ops, pages=pages, page_size=page_size,
+                    fold_wo=fold_wo, tp_axis=tp_axis)
             new_cache.update(kv)
             x32 = _residual_add(x32, a32, cfg)
-            h8 = il.int_norm(qp["norm2"], x32, plans.norm, ops)
+            h8 = _norm(qp, "norm2", x32, plans.norm, ops)
             f32 = il.int_ffn_fwd(qp["ffn"], h8, plans.ffn, cfg, ops)
             x32 = _residual_add(x32, f32, cfg)
             new_group.append(new_cache)

@@ -107,6 +107,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import trace_names
 from repro.analysis import contracts
 from repro.distributed import tp_serving
 from repro.models import intlayers as il
@@ -490,16 +491,17 @@ class ServingEngine:
             cfg = tp_serving.local_cfg(cfg, self.tp)
             tp_axis = tp_serving.TP_AXIS
 
-        def step(qparams, caches, tokens, pos, pages=None):
+        def decode_step(qparams, caches, tokens, pos, pages=None):
             return it.int_decode_step(
                 qparams, caches, tokens, pos, plans, cfg, rope_tab,
                 ops=ops, pages=pages, page_size=page_size,
                 max_len=max_len, fold_wo=fold_wo, tp_axis=tp_axis)
 
         if self.tp_sharded:
-            step = self._tp_wrap(step, n_host_args=3 if self.paged else 2)
+            decode_step = self._tp_wrap(
+                decode_step, n_host_args=3 if self.paged else 2)
         return _cached_step(self._step_key("decode"),
-                            lambda: jax.jit(step))
+                            lambda: jax.jit(decode_step))
 
     def _shared_prefill_step(self) -> Callable:
         """The jitted chunked-prefill step (tokens (B, C), base_pos (B,),
@@ -514,7 +516,7 @@ class ServingEngine:
             cfg = tp_serving.local_cfg(cfg, self.tp)
             tp_axis = tp_serving.TP_AXIS
 
-        def step(qparams, caches, tokens, base_pos, pages):
+        def prefill_chunk_step(qparams, caches, tokens, base_pos, pages):
             return it.int_prefill_chunk_step(qparams, caches, tokens,
                                              base_pos, plans, cfg,
                                              rope_tab, ops=ops,
@@ -524,9 +526,10 @@ class ServingEngine:
                                              tp_axis=tp_axis)
 
         if self.tp_sharded:
-            step = self._tp_wrap(step, n_host_args=3, caches_only=True)
+            prefill_chunk_step = self._tp_wrap(
+                prefill_chunk_step, n_host_args=3, caches_only=True)
         return _cached_step(self._step_key("prefill", self.prefill_chunk),
-                            lambda: jax.jit(step))
+                            lambda: jax.jit(prefill_chunk_step))
 
     def _shared_verify_step(self) -> Callable:
         """The jitted speculative verify step (tokens (B, S = spec_k+1)
@@ -545,16 +548,17 @@ class ServingEngine:
             cfg = tp_serving.local_cfg(cfg, self.tp)
             tp_axis = tp_serving.TP_AXIS
 
-        def step(qparams, caches, tokens, pos, n_new, pages=None):
+        def verify_step(qparams, caches, tokens, pos, n_new, pages=None):
             return it.int_verify_step(
                 qparams, caches, tokens, pos, n_new, plans, cfg,
                 rope_tab, ops=ops, pages=pages, page_size=page_size,
                 max_len=max_len, fold_wo=fold_wo, tp_axis=tp_axis)
 
         if self.tp_sharded:
-            step = self._tp_wrap(step, n_host_args=4 if self.paged else 3)
+            verify_step = self._tp_wrap(
+                verify_step, n_host_args=4 if self.paged else 3)
         return _cached_step(self._step_key("spec", self.spec_k + 1),
-                            lambda: jax.jit(step))
+                            lambda: jax.jit(verify_step))
 
     def _tp_wrap(self, step: Callable, n_host_args: int,
                  caches_only: bool = False) -> Callable:
@@ -1039,6 +1043,10 @@ class ServingEngine:
         ``evict`` / ``preempt`` / another dispatch raise
         :class:`StepInFlight` until the commit lands."""
         self._require_committed("dispatch_step")
+        with trace_names.host_span("engine.dispatch"):
+            return self._dispatch()
+
+    def _dispatch(self) -> PendingStep:
         self._admit()
         self._advance_prefill()
         occupied = sum(s is not None for s in self.slots)
@@ -1078,22 +1086,25 @@ class ServingEngine:
                 "flight: each dispatch_step() result is committed "
                 "exactly once, in order")
         self._inflight = None
-        if pending.kind == "verify":
-            self._commit_spec(pending)
-        else:
-            self._commit_decode(pending)
+        with trace_names.host_span("engine.commit"):
+            if pending.kind == "verify":
+                self._commit_spec(pending)
+            else:
+                self._commit_decode(pending)
         return pending.occupied
 
     def _commit_decode(self, pending: PendingStep):
-        logits = np.asarray(pending.logits)
+        with trace_names.host_span("engine.sample"):
+            logits = np.asarray(pending.logits)[:, :self.cfg.vocab]
+            picks = {i: self._sample(self.slots[i].request, logits[i])
+                     for i in pending.live}
         for i in pending.live:
             sess = self.slots[i]
             req = sess.request
             self.pos[i] += 1
             sess.pos = int(self.pos[i])
-            row = logits[i][:self.cfg.vocab]
-            self._record(req, row)
-            nxt = self._sample(req, row)
+            self._record(req, logits[i])
+            nxt = picks[i]
             req.out_tokens.append(nxt)
             sess.last_token = nxt
             if len(req.out_tokens) >= req.max_new_tokens \
@@ -1164,14 +1175,16 @@ class ServingEngine:
         pages only rejected drafts touched."""
         S = self.spec_k + 1
         live, n_new, drafts = pending.live, pending.n_new, pending.drafts
-        logits = np.asarray(pending.logits)
+        with trace_names.host_span("engine.sample"):
+            logits = np.asarray(pending.logits)[..., :self.cfg.vocab]
+            argmax = np.argmax(logits, axis=-1)
         for i in live:
             sess = self.slots[i]
             req = sess.request
             draft = drafts[i]
             n = int(n_new[i])
-            rows = logits[i, S - n:, :self.cfg.vocab]
-            preds = np.argmax(rows, axis=-1)
+            rows = logits[i, S - n:]
+            preds = argmax[i, S - n:]
             a = 0
             while a < len(draft) and int(preds[a]) == draft[a]:
                 a += 1
