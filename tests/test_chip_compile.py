@@ -248,13 +248,17 @@ def test_gelu_compiles(one_chip):
              ((BATCH * 256, R_FF), I32))
 
 
-@pytest.mark.parametrize("d,subtract_mean", [(R_MODEL, True),
-                                             (G_MODEL, False)])
-def test_layernorm_compiles(one_chip, d, subtract_mean):
+@pytest.mark.parametrize("lead,d,subtract_mean", [
+    ((BATCH, 256), R_MODEL, True), ((BATCH, 256), G_MODEL, False),
+    ((512, 64), R_MODEL, True),    # the encoder benchmark's batch
+    ((16,), G_MODEL, False),       # a Granite decode step: one block
+], ids=["768-True", "2048-False", "roberta-512x64", "granite-decode16"])
+def test_layernorm_compiles(one_chip, lead, d, subtract_mean):
+    """Under the shape-chosen row block (``norm_block_rows``)."""
     from repro.kernels.int_layernorm import int_layernorm_pallas
     plan = norms.make_inorm(d, 8 / 1024, 1024, 2 / 127, 8 / 127,
                             subtract_mean=subtract_mean)
-    shapes = [((BATCH, 256, d), I32), ((d,), I32)]
+    shapes = [(lead + (d,), I32), ((d,), I32)]
     if subtract_mean:
         shapes.append(((d,), I32))
 

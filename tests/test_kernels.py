@@ -1,4 +1,7 @@
 """Per-kernel shape/dtype sweeps: pallas (interpret) vs ref.py oracles."""
+import math
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -66,22 +69,93 @@ def test_int_gelu_kernel(rng, shape):
     assert np.array_equal(got, want)
 
 
-@pytest.mark.parametrize("d,subtract_mean", [(768, True), (512, False),
-                                             (384, True)])
-def test_int_layernorm_kernel(rng, d, subtract_mean):
-    s = 8 / 1024
-    plan = norms.make_inorm(d, s, 1024, 2 / 127, 8 / 127,
+def _norm_case(rng, d, subtract_mean, qmax=1024):
+    plan = norms.make_inorm(d, 8 / qmax, qmax, 2 / 127, 8 / 127,
                             subtract_mean=subtract_mean)
     gamma = rng.normal(1, 0.2, d).astype(np.float32)
     beta = rng.normal(0, 0.2, d).astype(np.float32) if subtract_mean \
         else None
-    qg, qb = norms.quantize_norm_weights(
+    return (plan,) + norms.quantize_norm_weights(
         jnp.asarray(gamma), jnp.asarray(beta) if beta is not None else
         None, plan)
-    q = rng.integers(-1024, 1025, (16, d)).astype(np.int32)
+
+
+@pytest.mark.parametrize("rows,d,subtract_mean", [
+    (16, 768, True), (16, 512, False), (16, 384, True),
+    # several shape-chosen row blocks, ragged tails zero-padded
+    (1000, 768, True), (4104, 768, True), (4096, 2048, False),
+], ids=["768-True", "512-False", "384-True", "1000x768", "4104x768",
+        "4096x2048-rms"])
+def test_int_layernorm_kernel(rng, rows, d, subtract_mean):
+    from repro.kernels.int_layernorm import norm_block_rows
+    if rows > 16:
+        assert norm_block_rows(-(-rows // 8) * 8, d) < rows
+    plan, qg, qb = _norm_case(rng, d, subtract_mean)
+    q = rng.integers(-1024, 1025, (rows, d)).astype(np.int32)
     got = np.asarray(PALLAS.int_layernorm(jnp.asarray(q), qg, qb, plan))
     want = np.asarray(REF.int_layernorm(jnp.asarray(q), qg, qb, plan))
     assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("d,subtract_mean", [(768, True), (2048, False)])
+def test_int_layernorm_kernel_edge_rows(rng, d, subtract_mean):
+    """Rows with sigma = 0, rows at +-qmax_in, and the largest variance a
+    row in range can have (alternating +-qmax_in: the squared sum's
+    largest reachable value), between ordinary rows, over several
+    blocks."""
+    qmax = 1 << 13      # the served residual stream's qmax_res
+    plan, qg, qb = _norm_case(rng, d, subtract_mean, qmax)
+    alt = np.where(np.arange(d) % 2, qmax, -qmax)
+    one_up = np.full(d, -qmax).astype(np.int64)
+    one_up[d // 3] = qmax
+    edge = np.stack([np.zeros(d), np.full(d, 37), np.full(d, qmax),
+                     np.full(d, -qmax), alt, -alt, one_up,
+                     np.full(d, 1)]).astype(np.int32)
+    q = rng.integers(-qmax, qmax + 1, (1024, d)).astype(np.int32)
+    q[::5][:len(edge) * 25] = np.tile(edge, (25, 1))
+    got = np.asarray(PALLAS.int_layernorm(jnp.asarray(q), qg, qb, plan))
+    want = np.asarray(REF.int_layernorm(jnp.asarray(q), qg, qb, plan))
+    assert np.array_equal(got, want)
+
+
+def _run_tile(fn, *xs):
+    """Apply an in-kernel helper to (8, n) int32 tiles in interpret mode."""
+    from jax.experimental import pallas as pl
+
+    def kernel(*refs):
+        refs[-1][...] = fn(*(r[...] for r in refs[:-1]))
+    n = -(-len(xs[0]) // 1024) * 1024
+    tiles = [jnp.asarray(np.pad(np.asarray(x, np.int32), (0, n - len(x)))
+                         .reshape(8, n // 8)) for x in xs]
+    out = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        tiles[0].shape, jnp.int32), interpret=True)(*tiles)
+    return np.asarray(out).reshape(-1)[:len(xs[0])]
+
+
+def test_int_layernorm_isqrt_exact(rng):
+    """The kernel's division-free square root is floor(sqrt(n)) on all of
+    int32's non-negative range, and 0 for n <= 0 (as core.intmath.i_sqrt)."""
+    from repro.kernels.int_layernorm import isqrt_tile
+    k = np.concatenate([np.arange(1, 300), rng.integers(300, 46341, 2000),
+                        [46339, 46340]]).astype(np.int64)
+    n = np.concatenate([[0, 1, 2, 3, 46340 ** 2, 46340 ** 2 - 1,
+                         2 ** 31 - 1, 2 ** 31 - 2, 2 ** 30, -1, -5,
+                         -2 ** 31], k * k - 1, k * k,
+                        np.minimum(k * k + 1, 2 ** 31 - 1),
+                        rng.integers(0, 2 ** 31, 4000)])
+    got = _run_tile(isqrt_tile, n)
+    want = [math.isqrt(int(v)) if v > 0 else 0 for v in n]
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("m", [8, 15, 16, 20, 24, 30])
+def test_int_layernorm_recip_exact(m):
+    """The kernel's reciprocal is the floor quotient 2^m // sigma for
+    every sigma an int32 square root can give (1..46340)."""
+    from repro.kernels.int_layernorm import recip_tile
+    sigma = np.arange(1, 46341)
+    got = _run_tile(lambda s: recip_tile(1 << m, s), sigma)
+    assert np.array_equal(got, (1 << m) // sigma)
 
 
 @pytest.mark.parametrize("h,hkv,window", [(4, 2, 0), (4, 4, 0), (2, 1, 96),
