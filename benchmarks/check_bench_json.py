@@ -105,6 +105,7 @@ SCHEMAS = {
         "budgets": {
             "INT32_MAX": int,
             "MAX_ROWSUM_LEN": int,
+            "MAX_PV_KEYS": int,
             "MAX_SQ": int,
         },
         "n_configs": int,

@@ -3,7 +3,8 @@
 Three tools, one goal — *prove* properties before anything runs:
 
   * :mod:`repro.analysis.budgets`    — the single home of the repo's bit
-    budgets (``INT32_MAX``, ``MAX_ROWSUM_LEN``, ``MAX_SQ``) and the typed
+    budgets (``INT32_MAX``, ``MAX_ROWSUM_LEN``, ``MAX_PV_KEYS``, ``MAX_SQ``)
+    and the typed
     :class:`BitBudgetError`;
   * :mod:`repro.analysis.ranges`     — the :class:`IntRange` abstract
     domain + sound transfer functions for the integer primitives
@@ -23,7 +24,8 @@ Three tools, one goal — *prove* properties before anything runs:
 See docs/ANALYSIS.md for the abstract-domain contract.
 """
 from repro.analysis.budgets import (BitBudgetError, INT32_MAX,
-                                    MAX_ROWSUM_LEN, MAX_SQ, static_check)
+                                    MAX_PV_KEYS, MAX_ROWSUM_LEN, MAX_SQ,
+                                    static_check)
 from repro.analysis.contracts import (KernelContractError, LaunchReport,
                                       can_tile, can_tile_decode,
                                       can_tile_prefill, check_launch,

@@ -13,8 +13,20 @@ Budgets:
     proves against;
   * ``MAX_ROWSUM_LEN``  — longest softmax row whose exact e16 sum stays
     int32: ``rowlen * 2^15 <= 2^30`` (``core.softmax`` requantizes exp
-    values to 2^-15 fractions).  Every exact (non-streaming-corrected)
-    attention kernel asserts this as its ``MAX_SKV``;
+    values to 2^-15 fractions) — the budget of the standalone
+    ``i_softmax`` op.  The fused attention kernels keep it as their
+    launch limit (``MAX_SKV``; longer rows take the chunked path), inside
+    their own arithmetic's ``MAX_PV_KEYS``;
+  * ``MAX_PV_KEYS``     — longest attention row whose unnormalised P·V
+    accumulator and final per-row division stay int32 in one pass:
+    the row sum of int8 weights is at most ``keys * 127``, the
+    accumulator at most ``keys * 127 * 127`` (``< 2^31`` at ``2^17``
+    keys) and the division's ``128 * rem + sum / 2`` at most ``128.5 *
+    keys * 127`` (also ``< 2^31``).  Longer rows stream in chunks of
+    at most ``2^15`` keys that halve the running pair whenever its sum
+    could pass ``STREAM_SUM_BUDGET``, half the one-pass sum, which
+    leaves the accumulator room for the halvings' rounding
+    (``core.attention.fold_pv``);
   * ``MAX_SQ``          — speculative query rows the decode kernel holds
     in VMEM scratch for a whole launch.
 
@@ -30,6 +42,14 @@ INT32_MAX = 2 ** 31 - 1
 # longest row whose e16 sum is int32-exact: rowlen * 2^15 <= 2^30 — the
 # budget every exact (non-streaming-corrected) attention kernel asserts
 MAX_ROWSUM_LEN = 1 << 15
+
+# longest attention row whose int8-weight sum (<= keys * 127), P·V
+# accumulator (<= keys * 127 * 127) and final per-row division all stay
+# int32 in a single pass; past it the streaming path keeps its running
+# sum under STREAM_SUM_BUDGET
+MAX_PV_KEYS = 1 << 17
+PV_SUM_BUDGET = MAX_PV_KEYS * 127
+STREAM_SUM_BUDGET = PV_SUM_BUDGET // 2
 
 # speculative query budget: decode-kernel scratch rows per head
 MAX_SQ = 8

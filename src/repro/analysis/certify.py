@@ -21,8 +21,8 @@ import json
 import os
 import sys
 
-from repro.analysis.budgets import (INT32_MAX, MAX_ROWSUM_LEN, MAX_SQ,
-                                    BitBudgetError)
+from repro.analysis.budgets import (INT32_MAX, MAX_PV_KEYS,
+                                    MAX_ROWSUM_LEN, MAX_SQ, BitBudgetError)
 from repro.analysis.interpret import certify_config
 
 SCHEMA = "repro/certify-v1"
@@ -84,6 +84,7 @@ def certify_all(seq_len: int, cache_len: int, names=None):
         "budgets": {
             "INT32_MAX": INT32_MAX,
             "MAX_ROWSUM_LEN": MAX_ROWSUM_LEN,
+            "MAX_PV_KEYS": MAX_PV_KEYS,
             "MAX_SQ": MAX_SQ,
         },
         "n_configs": len(configs),

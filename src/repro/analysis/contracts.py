@@ -337,7 +337,7 @@ def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
     elif online:
         grid = (b, h, sq // bq, skv // bkv)
     else:
-        grid = (b, sq // bq, 3, skv // bkv)
+        grid = (b, sq // bq, 2, skv // bkv)
     return LaunchReport(
         op=op, ok=not reasons, fused=not (reasons or policy),
         reasons=tuple(reasons + policy), grid=grid,
@@ -397,7 +397,7 @@ def _check_int_decode_attention(b, sq, h, hkv, d, L=None, bkv=128,
     vmem = _attn_vmem(sq, h, hkv, d, bkv, kv_d,
                       1 if out_bits <= 8 else 4, per_channel, fold, n_out)
     reasons += vmem_violations("int_decode_attention", vmem)
-    grid = (b, 3, L // bkv) if not (L % bkv if not paged
+    grid = (b, 2, L // bkv) if not (L % bkv if not paged
                                     else page_size % bkv) else ()
     return LaunchReport(
         op="int_decode_attention", ok=not reasons,
@@ -444,7 +444,7 @@ def _check_int_paged_prefill(b, c, h, hkv, d, max_pages, page_size,
     if kv_pack:
         prefetch.append(("k_shift", (num_pages,)))
         prefetch.append(("v_shift", (num_pages,)))
-    grid = (b, c // bq, 3, L // bkv) \
+    grid = (b, c // bq, 2, L // bkv) \
         if not (c % bq or page_size % bkv) else ()
     return LaunchReport(
         op="int_paged_prefill", ok=not reasons,

@@ -33,8 +33,9 @@ from repro.analysis import contracts
 from repro.analysis.budgets import (MAX_ROWSUM_LEN, MAX_SQ, bits_for,
                                     static_check)
 from repro.analysis.ranges import (INT4, INT4_KV, INT8, MSR4_DELTA_MAX,
-                                   IntRange, audit_dyadics,
-                                   t_attention_acc, t_clip,
+                                   IntRange, audit_dyadics, pv_bounds,
+                                   t_attention_acc, t_attention_weights,
+                                   t_clip,
                                    t_dyadic, t_dyadic_perchannel, t_gelu,
                                    t_layernorm, t_matmul_acc,
                                    t_requant_spec, t_silu, t_softmax)
@@ -211,7 +212,8 @@ def check_int_layernorm(plan, layer: str, x: IntRange = None,
 
 def _attention_core(ia, rowlen: int, layer: str, op: str, t: _Track,
                     kv_qmax: int = 127):
-    """Shared Q·Kᵀ → Shiftmax → P·V → dn_out epilogue range walk.
+    """Shared Q·Kᵀ → Shiftmax weights → P·V → row division → dn_out
+    epilogue range walk.
 
     ``kv_qmax`` is the K/V operand magnitude: 127 on the int8 grid, or
     ``INT4_KV.qmax`` (7 << KV4_SHIFT = 112) when the pages store packed
@@ -221,9 +223,10 @@ def _attention_core(ia, rowlen: int, layer: str, op: str, t: _Track,
         ia.head_dim, w_qmax=kv_qmax,
         what="attention score accumulator", op=op, layer=layer))
     exact = rowlen <= MAX_ROWSUM_LEN
-    t_softmax(ia.sm, score, rowlen, exact_rowsum=exact, op=op, layer=layer)
-    acc = t("P*V accumulator", t_attention_acc(rowlen, v_qmax=kv_qmax,
-                                               op=op, layer=layer))
+    u = t_attention_weights(ia.sm, score, op=op, layer=layer)
+    t("P*V accumulator", pv_bounds(rowlen, kv_qmax, u.hi)[1])
+    acc = t("normalised accumulator", t_attention_acc(
+        rowlen, v_qmax=kv_qmax, u_max=u.hi, op=op, layer=layer))
     out = t_clip(t("epilogue staging", t_dyadic(
         acc, ia.dn_out, what="attention epilogue dyadic",
         op=op, layer=layer)), 8)
@@ -362,6 +365,18 @@ def _check_mamba(m, cfg, ops, assumptions):
         "(runtime clip in the SSD scan)")
 
 
+def longest_key_count(cfg, cache_len: int) -> int:
+    """The most keys one attention row of ``cfg`` sees where it runs:
+    the sweep's ``cache_len``, or the registry's long-context shape
+    (``models.common.SHAPES["long_500k"]``) for the configs that run it
+    (``configs.registry.LONG_OK``), capped by a sliding window."""
+    from repro.configs.registry import LONG_OK
+    from repro.models.common import SHAPES
+    n = max(cache_len, SHAPES["long_500k"].seq_len
+            if cfg.name in LONG_OK else 0)
+    return min(n, cfg.window) if cfg.window else n
+
+
 def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
                    calib: dict = None, page_size: int = 64,
                    chunk: int = 256) -> ConfigReport:
@@ -420,6 +435,14 @@ def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
                 plans.attn.attn, cache_len, "attn.decode[kv4]",
                 kv_pack=True, page_size=page_size)
             ops.append(rep)
+            longest = longest_key_count(cfg, cache_len)
+            if longest > cache_len:
+                # the long-context shape: rows past MAX_PV_KEYS stream
+                # their P·V pair through core.attention.fold_pv
+                _, rep = check_int_decode_attention(
+                    plans.attn.attn, longest, f"attn.decode[{longest}]",
+                    page_size=page_size)
+                ops.append(rep)
             _, rep = check_int_paged_prefill(
                 plans.attn.attn, cache_len, "attn.prefill",
                 chunk=chunk, page_size=page_size,
@@ -476,6 +499,7 @@ def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
 
 __all__ = [
     "BIAS_QMAX", "ConfigReport", "OpReport", "certify_config",
+    "longest_key_count",
     "check_int8_matmul", "check_int8_matmul_packed", "check_int_attention",
     "check_int_decode_attention", "check_int_gelu",
     "check_int_layernorm", "check_int_paged_prefill",

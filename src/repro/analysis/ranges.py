@@ -28,7 +28,9 @@ from __future__ import annotations
 import dataclasses
 
 from repro.analysis.budgets import (BitBudgetError, INT32_MAX,
-                                    MAX_ROWSUM_LEN, bits_for, static_check)
+                                    MAX_PV_KEYS, MAX_ROWSUM_LEN,
+                                    STREAM_SUM_BUDGET, bits_for,
+                                    static_check)
 
 # per-channel multipliers are bounded by the fit's mult_bits=15 contract:
 # fit_dyadic folds any rounding spill, so b <= 2^15 - 1, and
@@ -279,20 +281,77 @@ def t_softmax(sm, score: IntRange, rowlen: int, exact_rowsum: bool = True,
     return IntRange(0, 127)
 
 
-def prob_rowsum_max(rowlen: int) -> int:
-    """Worst-case Σ p8 over a row: the probabilities sum to ≤ 2^7 before
-    rounding, and each of the ``rowlen`` round-half-up requants adds at
-    most 1/2 — the P·V accumulator bound ``(2^7 + rowlen/2)·127``."""
-    from repro.core.softmax import PROB_SHIFT
-    return (1 << PROB_SHIFT) + (rowlen + 1) // 2
+def t_attention_weights(sm, score: IntRange, op=None,
+                        layer=None) -> IntRange:
+    """The attention half of Shiftmax (``core.softmax.attn_weights``):
+    the exact max-subtract, the i-exp band and its e16 requant, then the
+    weight ``min(rshift_round(e16, 8), 127)``.  No row sum of e16 and no
+    probability product: the row's only division is after P·V
+    (:func:`t_attention_acc`).  Returns the weight range [0, 127]."""
+    from repro.core.softmax import U_MAX, U_SHIFT
+    static_check(2 * score.qmax, "softmax max-subtract headroom",
+                 op=op, layer=layer)
+    sub = IntRange(-sm.q_band, 0)
+    q_sm = t_dyadic(sub, sm.dn_in, what="softmax score dyadic",
+                    op=op, layer=layer)
+    assert q_sm.hi <= 0, q_sm
+    e_raw = t_iexp(sm.iexp, what="softmax i-exp", op=op, layer=layer)
+    e16 = t_dyadic(e_raw, sm.dn_e16, what="softmax e16 dyadic",
+                   op=op, layer=layer)
+    static_check(e16.hi + (1 << (U_SHIFT - 1)), "attention weight rounding",
+                 op=op, layer=layer)
+    return IntRange(0, min(rshift_round_int(e16.hi, U_SHIFT), U_MAX))
 
 
-def t_attention_acc(rowlen: int, v_qmax: int = 127,
+def pv_streamed(rowlen: int) -> bool:
+    """Whether a row of ``rowlen`` keys passes the one-pass budget, so
+    its (accumulator, sum) pair streams through ``core.attention.
+    fold_pv``."""
+    return rowlen > MAX_PV_KEYS
+
+
+def pv_bounds(rowlen: int, v_qmax: int = 127, u_max: int = 127):
+    """``(weight sum, |P·V accumulator|, extra V steps of the quotient)``
+    worst cases over rows of ``rowlen`` keys (see
+    :func:`t_attention_acc`)."""
+    if not pv_streamed(rowlen):
+        s_max = rowlen * u_max
+        return s_max, s_max * v_qmax, 0
+    drift = (v_qmax + 1) * rowlen
+    return (STREAM_SUM_BUDGET, STREAM_SUM_BUDGET * v_qmax + drift,
+            -(-drift * 4 // STREAM_SUM_BUDGET))
+
+
+def t_attention_acc(rowlen: int, v_qmax: int = 127, u_max: int = 127,
                     op=None, layer=None) -> IntRange:
-    """The int32 P·V accumulator range (scale ``2^-7 · s_v``)."""
-    return IntRange.symmetric(
-        static_check(prob_rowsum_max(rowlen) * v_qmax,
-                     "attention P*V accumulator", op=op, layer=layer))
+    """The P·V accumulator and the row's division
+    (``core.softmax.normalize_rows``), over rows of ``rowlen`` keys.
+
+    One pass (``rowlen <= MAX_PV_KEYS``): the weight sum is at most
+    ``rowlen * u_max``, the accumulator ``rowlen * u_max * v_qmax``, and
+    the quotient, a weighted mean of V at 7 fraction bits, at most
+    ``128 * v_qmax``.
+
+    Streamed (longer rows, ``core.attention.fold_pv``): the sum stays
+    within ``STREAM_SUM_BUDGET`` by the fold's rule.  Rounding enters only
+    once the pair has been halved, at most half an LSB of sum and of
+    accumulator per fold (bounded here by one per key), and from then on
+    the sum is at least a quarter of the budget; so the accumulator is
+    within ``v_qmax`` times the sum plus ``(v_qmax + 1) * rowlen``, and
+    the quotient within ``ceil((v_qmax + 1) * rowlen / (budget / 4))``
+    V steps of the one-pass bound.
+
+    Either way the division's remainder product ``2^7 * (s - 1) + s //
+    2`` must fit.  Returns the normalised accumulator's range (scale
+    ``2^-7 * s_v``)."""
+    from repro.core.softmax import PROB_SHIFT
+    s_max, acc_max, extra = pv_bounds(rowlen, v_qmax, u_max)
+    static_check(s_max, "attention weight sum", op=op, layer=layer)
+    static_check(acc_max, "attention P*V accumulator", op=op, layer=layer)
+    static_check(((s_max - 1) << PROB_SHIFT) + s_max // 2,
+                 "attention row division remainder", op=op, layer=layer)
+    return IntRange.symmetric(((v_qmax + extra) << PROB_SHIFT)
+                              + (1 if extra else 0))
 
 
 def t_gelu(plan, r: IntRange, op=None, layer=None) -> IntRange:
@@ -340,12 +399,25 @@ def t_layernorm(plan, r: IntRange, out_bits: int = 8, beta_abs: float = 2.0,
         y_max = q + mu.qmax                      # centred values
     else:
         y_max = q                                # RMSNorm: y = q
-    static_check(d * ((y_max >> s) ** 2), "i-norm variance sum",
-                 op=op, layer=layer)
-    t_dyadic(IntRange(0, d * ((y_max >> s) ** 2)), plan.dn_var,
+    # each row is shifted before squaring (core.norms.row_shift): right
+    # by t < s where its max |y| is within down[t], left by u <= s where
+    # it is within up[u - 1]; either way its shifted values are at most
+    # y_cap = (2 qmax_in) >> s, the cap dn_var is sized at; a row past
+    # every limit takes the design-time shift s
+    from repro.core.norms import row_shift_limits
+    y_cap = (2 * plan.qmax_in) >> s
+    up, down = row_shift_limits(plan)
+    for t, lim in enumerate(down):
+        assert ((lim + ((1 << t) >> 1)) >> t) <= y_cap, (t, lim, y_cap)
+    for u, lim in enumerate(up, 1):
+        assert lim << u <= y_cap, (u, lim, y_cap)
+    y_sq = max(y_max >> s, y_cap) if s else y_max
+    static_check(d * y_sq ** 2, "i-norm variance sum", op=op, layer=layer)
+    t_dyadic(IntRange(0, d * y_sq ** 2), plan.dn_var,
              what="i-norm variance dyadic", op=op, layer=layer)
     # r = 2^(k+s) // sigma_s with sigma_s >= 1 -> r <= 2^(k+s); the
-    # normalisation product y*r plus its 2s rounding addend must fit
+    # normalisation product y*r plus its rounding addend (a shift of
+    # s + t, 0 <= s + t <= 2s, for the row's own shift t) must fit
     static_check((y_max << (k + s)) + (1 << max(0, 2 * s - 1)),
                  "i-norm normalisation product", op=op, layer=layer)
     # |n| <= sqrt(d) mathematically (sigma^2 >= y_i^2/d); make_inorm
@@ -402,8 +474,10 @@ def audit_dyadics(obj, prefix: str = "", op=None, layer=None) -> int:
 __all__ = [
     "INT4", "INT4_KV", "INT8", "IntRange", "KV4_SHIFT",
     "MSR4_DELTA_MAX", "PER_CHANNEL_B_MAX", "BitBudgetError",
-    "INT32_MAX", "audit_dyadics", "iter_dyadics", "prob_rowsum_max",
-    "rshift_round_int", "t_attention_acc", "t_clip", "t_dyadic",
+    "INT32_MAX", "audit_dyadics", "iter_dyadics", "pv_bounds",
+    "pv_streamed",
+    "rshift_round_int", "t_attention_acc", "t_attention_weights",
+    "t_clip", "t_dyadic",
     "t_dyadic_perchannel", "t_gelu", "t_iexp", "t_layernorm",
     "t_matmul_acc", "t_requant_spec", "t_rshift_round", "t_silu",
     "t_softmax",

@@ -2,8 +2,10 @@
 
 Three phases, matching the ASIC pipeline:
   1. mean      — integer sum, dyadic multiply by 1/d
-  2. std       — centred squares (with a design-time pre-shift so the INT32
-                 accumulator cannot overflow), dyadic 1/d, iterative i-sqrt
+  2. std       — centred squares (each row shifted, by at most the
+                 design-time pre-shift either way, so that its largest
+                 value is as large as the INT32 accumulator allows),
+                 dyadic 1/d, iterative i-sqrt
   3. output    — one reciprocal per row (2^k // sigma), per-channel gamma,
                  folded beta, dyadic requant to the int8 output scale
 
@@ -28,7 +30,7 @@ class INormPlan(NamedTuple):
     qmax_in: int
     dn_mean: Dyadic         # 1/d on the sum
     dn_var: Dyadic          # 1/d on the squared sum
-    pre_shift: int          # s: y >> s before squaring
+    pre_shift: int          # s: the largest row shift (either way)
     recip_bits: int         # k: reciprocal precision (n at scale 2^-k)
     s_gamma: float
     s_out: float
@@ -72,6 +74,51 @@ def quantize_norm_weights(gamma, beta, plan: INormPlan):
     return q_gamma, q_beta
 
 
+def row_shift_limits(plan: INormPlan) -> tuple:
+    """Design-time thresholds of the per-row shift before squaring,
+    ``(up, down)``.  The shift makes a row's largest value as large as
+    it can be while the squared sum stays within the ``d Y^2`` that
+    ``dn_var`` was sized for, ``Y = (2 qmax_in) >> pre_shift``, and
+    moves by at most ``pre_shift`` bits either way:
+
+      * ``down[t]`` (``t < pre_shift``) is the largest row max
+        ``m = max|y|`` with ``rshift_round(m, t) <= Y``: a row past
+        ``down[t - 1]`` is shifted right by ``t`` or more;
+      * ``up[u - 1]`` (``u = 1 .. pre_shift``) is ``Y >> u``: a row
+        within it is shifted left by ``u`` or more, exactly.
+
+    The design-time shift alone squared a small residual stream at a
+    few LSB (Granite's embedding, 51 LSB under 5 bits), where the floor
+    of the square root read its sigma far low (1 for 1.6)."""
+    y_cap = (2 * plan.qmax_in) >> plan.pre_shift
+    up = tuple(y_cap >> u for u in range(1, plan.pre_shift + 1))
+    down = tuple((y_cap + 1) * (1 << t) - ((1 << t) >> 1) - 1
+                 for t in range(plan.pre_shift))
+    return up, down
+
+
+def row_shift(y, plan: INormPlan):
+    """Per-row shift (int32, ``y``'s shape with a last axis of 1) of
+    :func:`row_shift_limits`: positive right, negative left."""
+    up, down = row_shift_limits(plan)
+    m = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
+    sh = jnp.zeros_like(m)
+    for lim in down:
+        sh = sh + (m > lim).astype(jnp.int32)
+    for lim in up:
+        sh = sh - (m <= lim).astype(jnp.int32)
+    return sh
+
+
+def shift_by(x, sh):
+    """``x`` shifted by an int32 array ``sh`` (broadcast against ``x``):
+    right with round-half-up, ``(x + 2^(sh-1)) >> sh``, where ``sh >
+    0``; left, exactly, where ``sh < 0``."""
+    right = jnp.maximum(sh, 0)
+    down = (x + (jnp.left_shift(jnp.int32(1), right) >> 1)) >> right
+    return jnp.where(sh < 0, jnp.left_shift(x, jnp.maximum(-sh, 0)), down)
+
+
 def i_norm(q, q_gamma, q_beta, plan: INormPlan, out_bits: int = 8):
     """LayerNorm/RMSNorm over the last axis. q: int32 at plan.s_in.
 
@@ -83,15 +130,17 @@ def i_norm(q, q_gamma, q_beta, plan: INormPlan, out_bits: int = 8):
         y = q - mu
     else:
         y = q
-    ys = rshift_round(y, plan.pre_shift)
+    sh = row_shift(y, plan)                      # per row, |sh| <= pre
+    ys = shift_by(y, sh)
     var = plan.dn_var(jnp.sum(ys * ys, axis=-1, keepdims=True))
-    sigma_s = intmath.i_sqrt(var)               # scale s_in * 2^pre_shift
-    # n = y / (sigma_s * 2^pre) at scale 2^-k:
+    sigma_s = intmath.i_sqrt(var)               # scale s_in * 2^sh
+    # n = y / (sigma_s * 2^sh) at scale 2^-k:
     #   r   = 2^(k+pre) / sigma_s
-    #   y*r = n * 2^(k + 2*pre)  ->  shift back by 2*pre
+    #   y*r = n * 2^(k + pre + sh)  ->  shift back by pre + sh >= 0
+    # (r's numerator is the design-time one, so |y*r| keeps its bound)
     r = jnp.int32(1 << (plan.recip_bits + plan.pre_shift)) \
         // jnp.maximum(sigma_s, 1)
-    n_q = rshift_round(y * r, 2 * plan.pre_shift)
+    n_q = shift_by(y * r, plan.pre_shift + sh)
     n_q = jnp.where(sigma_s == 0, 0, n_q)        # all-equal row -> 0
     out = n_q * q_gamma                          # scale 2^-k * s_gamma
     if q_beta is not None:

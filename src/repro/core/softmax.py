@@ -18,6 +18,15 @@ Scale plan (all frozen at design time):
     elements fits int32,
   * probabilities leave as int8 at scale ``2^-7`` (ready for the P*V INT8
     matmul, Fig. 10's Requantization block).
+
+Attention does not take ``i_softmax``'s probabilities (the MoE gate and
+the standalone ``int_softmax`` op do).  A probability rounded to 2^-7
+before P·V is ``128 / L`` steps over ``L`` comparable keys: a 25% error
+per weight at 64 keys, and a row of zeros past 256.  Attention instead
+weights V by the exponentials themselves, rounded to 2^-7 *of the row
+max* (:func:`attn_weights`, at most 127, never all zero), sums those
+weights, and divides once per row after P·V (:func:`normalize_rows`) —
+see ``core.attention``.
 """
 from __future__ import annotations
 
@@ -38,6 +47,8 @@ S_PROB = 2.0 ** -7       # int8 probability scale
 PROB_SHIFT = 7
 RECIP_BITS = 30
 Z_MAX = 30               # exp(-z_max*ln2) == 2^-30 ~ 0
+U_SHIFT = 8              # e16 (2^-15) -> attention weight at 2^-7 of the max
+U_MAX = 127              # int8 attention weight ceiling
 
 
 class ISoftmaxPlan(NamedTuple):
@@ -94,32 +105,6 @@ def i_softmax(q_scores, plan: ISoftmaxPlan, axis: int = -1, where=None):
     return jnp.clip(p, 0, 127).astype(jnp.int8)
 
 
-def i_softmax_stats(q_scores, plan: ISoftmaxPlan, axis: int = -1,
-                    where=None):
-    """Chunk-local stats for two-pass / online attention.
-
-    Returns (e16, chunk_max_raw, chunk_sum).  ``chunk_max_raw`` stays in the
-    exact raw score scale so running maxima combine losslessly; sums are
-    rescaled across chunks with ``combine_correction`` (an i-exp multiply).
-    """
-    q = q_scores.astype(jnp.int32)
-    neg = jnp.int32(-(2 ** 30))
-    if where is not None:
-        q = jnp.where(where, q, neg)
-    q_max = jnp.max(q, axis=axis, keepdims=True)
-    e16 = _exp16(q - q_max, plan)
-    if where is not None:
-        e16 = jnp.where(where, e16, 0)
-    s = jnp.sum(e16, axis=axis, keepdims=True)
-    return e16, q_max, s
-
-
-def combine_correction(old_max_raw, new_max_raw, plan: ISoftmaxPlan):
-    """int32 multiplier (scale 2^-15) rescaling old-chunk stats to the new
-    running max: exp(old_max - new_max), maxes in the raw score scale."""
-    return _exp16(old_max_raw - new_max_raw, plan)
-
-
 def rescale_sum(s, corr16):
     """(s * corr16) >> 15 via a hi/lo split so the int32 product never
     overflows even for s up to 2^30 (split 32x16 multiply, as the ASIC's
@@ -129,9 +114,29 @@ def rescale_sum(s, corr16):
     return s_hi * corr16 + rshift_round(s_lo * corr16, 15)
 
 
-def finalize_probs(e16, s):
-    """Normalise e16 values (computed against the global max) by the global
-    sum -> int8 probs."""
-    r = jnp.int32(1 << RECIP_BITS) // jnp.maximum(s, 1)
-    p = rshift_round(e16 * r, RECIP_BITS - PROB_SHIFT)
-    return jnp.clip(p, 0, 127).astype(jnp.int8)
+def attn_weights(e16):
+    """Unnormalised int8 attention weights: ``e16`` (exp of score minus
+    the row max, at 2^-15) rounded half up to 2^-7 of the row max.  e16
+    peaks at 32755 (``i_exp(0)``), which rounds to 128, so the weight is
+    capped at 127; the row max itself always weighs 127, so a live row's
+    weights never all vanish.  int32 in, int32 values in [0, 127] out."""
+    return jnp.minimum(rshift_round(e16, U_SHIFT), U_MAX)
+
+
+def normalize_rows(acc, s):
+    """The row's one division, after P·V: ``round_half_up(acc * 2^7 /
+    s)``, exactly, in int32.
+
+    ``acc`` (..., D): the P·V accumulator ``sum_k u_k * v_k`` over int8
+    weights ``u`` (:func:`attn_weights`); ``s`` (..., 1): ``sum_k u_k``.
+    The result is the normalised accumulator at scale ``2^-7 * s_v`` —
+    what every attention requant epilogue takes (``|out| <= 128 * |v|``
+    max, as ``|acc| <= s * |v|`` max).  Floor division into a whole part
+    and a remainder ``0 <= rem < s`` keeps ``128 * rem + s // 2`` int32
+    while ``s <= PV_SUM_BUDGET``.  A fully masked row (``s = 0``,
+    ``acc = 0``) gives 0."""
+    s = jnp.maximum(s, 1)
+    whole = jnp.floor_divide(acc, s)
+    rem = acc - whole * s
+    frac = jnp.floor_divide((rem << PROB_SHIFT) + (s >> 1), s)
+    return (whole << PROB_SHIFT) + frac

@@ -10,16 +10,23 @@ softmax**:
   * when the max moves, previous partial sums and the int32 P*V accumulator
     are rescaled by ``exp16(m_old - m_new)`` — an i-exp evaluation plus a
     split 32x16 multiply (all int32-safe),
-  * probabilities enter the MXU as unnormalised int8 weights (e16 >> 8) and
-    the output is normalised once at the end by the accumulated sum using
-    an exact two-step integer division (quotient + 7 fraction bits).
+  * the int8 weights that enter the MXU are the fused kernel's
+    (``core.softmax.attn_weights``: e16 rounded to 2^-7 of the running
+    max, at most 127), their sum is kept beside the accumulator, and the
+    output is normalised once at the end by that sum with the fused
+    kernel's exact per-row division (``normalize_tile``).
+
+Only the running rescale (``exp16(m_old - m_new)`` on the sum and the
+accumulator) separates it from the bit-exact kernels: it rounds, so the
+result is within a few LSB of the oracle.
 
 A nice inversion of the paper's cost model: the ASIC normalises all m
-probabilities per row (m divider uses); the fused kernel normalises the
+probabilities per row (m divider uses); this kernel normalises the
 d-dimensional *output* instead — head_dim << seq_len divider uses per row.
 
-Bit budget: acc <= (sum_e16 >> 8) * 127 <= L * 2^14, int32-safe for rows up
-to 2^16; the wrapper asserts L <= 65536.
+Bit budget: the weight sum stays at most ``L * 127`` and the accumulator
+``L * 127 * 127`` (rescales only shrink them), int32-safe for rows up to
+2^16 (the wrapper asserts L <= 65536).
 """
 from __future__ import annotations
 
@@ -34,7 +41,8 @@ from repro import trace_names
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
 from repro.kernels import resolve_interpret
-from repro.kernels.int_softmax import _exp16_tile, _rshift_round
+from repro.kernels.int_softmax import (_exp16_tile, _rshift_round,
+                                       attn_weights_tile, normalize_tile)
 
 NEG = -(2 ** 30)
 
@@ -77,12 +85,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref, *,
         m_new = jnp.maximum(m_ref[...], m_c)
         corr16 = _exp16_tile(m_ref[...] - m_new, plan.sm)
         e16 = _exp16_tile(scores - m_new, plan.sm)
-        e16 = jnp.where(live, e16, 0)
-        u8 = (e16 >> 8).astype(jnp.int8)            # unnormalised weights
+        u = jnp.where(live, attn_weights_tile(e16), 0)   # unnormalised
         s_ref[...] = _rescale32(s_ref[...], corr16) \
-            + jnp.sum(e16, axis=-1, keepdims=True)
+            + jnp.sum(u, axis=-1, keepdims=True)
         acc_ref[...] = _rescale32(acc_ref[...], corr16) + \
-            jax.lax.dot_general(u8, v8, (((1,), (0,)), ((), ())),
+            jax.lax.dot_general(u.astype(jnp.int8), v8,
+                                (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.int32)
         m_ref[...] = m_new
 
@@ -94,12 +102,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref, *,
 
     @pl.when(kv_step == n_kv - 1)
     def _finalize():
-        acc = acc_ref[...]
-        s8 = jnp.maximum(s_ref[...] >> 8, 1)        # sums in u8 units
-        whole = acc // s8                           # <= 127 in v units
-        rem = acc - whole * s8
-        frac7 = (rem << 7) // s8                    # exact 7 fraction bits
-        out7 = whole * 128 + frac7                  # scale s_v * 2^-7
+        out7 = normalize_tile(acc_ref[...], s_ref[...])  # s_v * 2^-7
         dn = plan.dn_out
         out = _rshift_round(_rshift_round(out7, dn.pre) * jnp.int32(dn.b),
                             dn.c - dn.pre)

@@ -8,26 +8,31 @@ matrix never exists in HBM.
 
 Relation to ``int_attention.py`` (the ``pallas`` backend's kernel): that
 kernel keeps a one-pass *online* softmax whose running rescales round
-(±LSB vs the oracle).  This kernel instead makes **three streaming
+(±LSB vs the oracle).  This kernel instead makes **two streaming
 sweeps** over the KV blocks per query block and is *bit-exact* against
-the two-pass reference (``kernels.ref.ref_int_attention``):
+the reference (``kernels.ref.ref_int_attention``):
 
-  sweep 0  row max        m = max_k(scores)          (int32 compare — exact)
-  sweep 1  row sum        s = Σ_k e16(scores - m)    (int32 add — exact)
-  sweep 2  normalise+AV   p8 = ⌊e16·(2³⁰//s) + h⌋»23; acc += p8·v8 (MXU)
+  sweep 0  row max       m = max_k(scores)                 (int32 compare)
+  sweep 1  weights + AV  u8 = min(⌊e16(scores - m) + 2⁷⌋»8, 127);
+                         s += Σ_k u8;  acc += u8·v8 (MXU)   (int32 adds)
+  epilogue divide        acc7 = ⌊(2⁷·acc + ⌊s/2⌋) / s⌋  per row
 
 Each sweep recomputes the int8 Q·Kᵀ block product instead of storing it —
-the FlashAttention recompute-over-store trade, paid twice more here to
+the FlashAttention recompute-over-store trade, paid once more here to
 buy exactness (integer maxima and sums are associative; the online
-rescale of ``int_attention.py`` is not).
+rescale of ``int_attention.py`` is not).  Normalising after P·V
+(``core.softmax.normalize_rows``) instead of before it is what lets the
+weight sum and P·V share one sweep; the division is exact integer
+floor division, done here from a float32 estimate that one exact int32
+remainder corrects (``int_softmax.normalize_tile``).
 
-Epilogue: the int32 accumulator (scale ``2⁻⁷·s_v``) takes any of the
-three :class:`repro.ops.RequantSpec` forms —
+Epilogue: the normalised accumulator (scale ``2⁻⁷·s_v``) takes any of
+the three :class:`repro.ops.RequantSpec` forms —
 
   * per-tensor  — ``clip(rshift_round(rshift_round(acc, pre)·b, c-pre))``
   * per-channel — same staging with an int32 multiplier vector over the
     flattened (head, head_dim) output channels
-  * raw         — the int32 accumulator is written untouched
+  * raw         — the normalised accumulator is written untouched
 
 Tiling: the caches keep their public ``(..., L, Hkv, D)`` layout, and
 every block takes *all* heads — queries ``(1, bq, H, D)``, K/V ``(1, bkv,
@@ -38,12 +43,11 @@ one-head ``(1, bkv, 1, D)`` block is refused; see
 the heads, loading each KV head's tile once per block for its whole GQA
 group.  ``bq`` / ``bkv`` are free of the (8, 128) tile rule.
 
-Bit budgets (mirroring ``core.softmax``): row sums need Skv ≤ 2¹⁵ so
-``Σ e16 ≤ 2³⁰`` stays int32-exact; the P·V accumulator is bounded by
-``(2⁷ + Skv/2)·127`` (normalised probabilities + rounding), int32-safe at
-every supported length.  The wrapper asserts the sum budget; backends
-fall back to the two-pass path beyond it (see
-``ops.backends.pallas_fused``).
+Bit budgets (``analysis.budgets``): the weight sum is at most
+``Skv·127`` and the accumulator ``Skv·127·127``, int32 up to
+``MAX_PV_KEYS = 2¹⁷`` keys.  The launch itself is held to ``MAX_SKV =
+2¹⁵`` keys (the lengths its tiling was checked at); backends take the
+chunked path beyond it (see ``ops.backends.pallas_fused``).
 """
 from __future__ import annotations
 
@@ -58,14 +62,14 @@ from repro import trace_names
 from repro.analysis.budgets import MAX_ROWSUM_LEN
 from repro.analysis.contracts import check_launch, require_launch
 from repro.core.attention import IAttnPlan
-from repro.core.softmax import PROB_SHIFT, RECIP_BITS
 from repro.kernels import resolve_interpret
-from repro.kernels.int_softmax import _exp16_tile, _rshift_round
+from repro.kernels.int_softmax import (_exp16_tile, _rshift_round,
+                                       attn_weights_tile, normalize_tile)
 from repro.ops.spec import PER_CHANNEL, PER_TENSOR, RequantSpec
 
 NEG = -(2 ** 30)
 
-# the row-sum budget is owned by repro.analysis.budgets (one source of
+# the launch limit is owned by repro.analysis.budgets (one source of
 # truth shared with the decode kernel and the tiling policy)
 MAX_SKV = MAX_ROWSUM_LEN
 
@@ -74,7 +78,7 @@ def _streaming_attn_body(phase, kv_step, n_kv, live, blk_live, m_ref,
                          s_ref, acc_ref, b_ref, *, n_heads: int, group: int,
                          q_of, k_of, v_of, emit, plan: IAttnPlan,
                          requant: RequantSpec, finish=None):
-    """The shared three-sweep streaming datapath + requant epilogue, over
+    """The shared two-sweep streaming datapath + requant epilogue, over
     every head of one query block.
 
     The launches block K/V as ``(1, bkv, Hkv, D)`` and queries as
@@ -100,21 +104,14 @@ def _streaming_attn_body(phase, kv_step, n_kv, live, blk_live, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG)
 
     @pl.when((phase == 1) & (kv_step == 0))
-    def _init_sum():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    @pl.when((phase == 2) & (kv_step == 0))
     def _init_acc():
+        s_ref[...] = jnp.zeros_like(s_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _scores(h, k8):
         s = jax.lax.dot_general(q_of(h), k8, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.int32)
         return jnp.where(live, s, jnp.int32(NEG))
-
-    def _e16(h, k8):
-        e16 = _exp16_tile(_scores(h, k8) - m_ref[h], plan.sm)
-        return jnp.where(live, e16, 0)
 
     def _heads(g):
         return range(g * group, (g + 1) * group)
@@ -129,29 +126,21 @@ def _streaming_attn_body(phase, kv_step, n_kv, live, blk_live, m_ref,
                                       keepdims=True))
 
     @pl.when((phase == 1) & blk_live)
-    def _sweep_sum():
-        for g in range(n_kv_heads):
-            k8 = k_of(g)
-            for h in _heads(g):
-                s_ref[h] = s_ref[h] + jnp.sum(_e16(h, k8), axis=-1,
-                                              keepdims=True)
-
-    @pl.when((phase == 2) & blk_live)
     def _sweep_av():
         for g in range(n_kv_heads):
             k8, v8 = k_of(g), v_of(g)
             for h in _heads(g):
-                r = jnp.int32(1 << RECIP_BITS) // jnp.maximum(s_ref[h], 1)
-                p = _rshift_round(_e16(h, k8) * r, RECIP_BITS - PROB_SHIFT)
-                p8 = jnp.clip(p, 0, 127).astype(jnp.int8)
+                e16 = _exp16_tile(_scores(h, k8) - m_ref[h], plan.sm)
+                u = jnp.where(live, attn_weights_tile(e16), 0)
+                s_ref[h] = s_ref[h] + jnp.sum(u, axis=-1, keepdims=True)
                 acc_ref[h] = acc_ref[h] + jax.lax.dot_general(
-                    p8, v8, (((1,), (0,)), ((), ())),
+                    u.astype(jnp.int8), v8, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.int32)
 
-    @pl.when((phase == 2) & (kv_step == n_kv - 1))
+    @pl.when((phase == 1) & (kv_step == n_kv - 1))
     def _epilogue():
         for h in range(n_heads):
-            acc = acc_ref[h]                    # int32 at 2^-7 * s_v
+            acc = normalize_tile(acc_ref[h], s_ref[h])   # at 2^-7 * s_v
             if requant.is_raw:
                 emit(h, acc)
                 continue
@@ -300,7 +289,7 @@ def _wo_fold_setup(requant: RequantSpec, wo_w8, wo_bias32, wo_b_vec,
 def _attn_scratch(h: int, rows: int, d: int):
     from jax.experimental.pallas import tpu as pltpu
     return [pltpu.VMEM((h, rows, 1), jnp.int32),     # row max, per head
-            pltpu.VMEM((h, rows, 1), jnp.int32),     # row sum
+            pltpu.VMEM((h, rows, 1), jnp.int32),     # row weight sum
             pltpu.VMEM((h, rows, d), jnp.int32)]     # P·V accumulator
 
 
@@ -352,7 +341,7 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
     plan's per-tensor ``dn_out``); ``b_vec``: int32 per-channel
     multipliers, shape (H*D,) or (H, D), required iff per-channel.
 
-    Grid ``(B, Sq/bq, 3, Skv/bkv)``: every step takes all heads of a
+    Grid ``(B, Sq/bq, 2, Skv/bkv)``: every step takes all heads of a
     ``(bq, H, D)`` query block and a ``(bkv, Hkv, D)`` KV block and
     loops over the heads in-kernel (``_streaming_attn_body``).
 
@@ -398,7 +387,7 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
 
     out = pl.pallas_call(
         kernel,
-        grid=(b, sq // bq, 3, n_kv),
+        grid=(b, sq // bq, 2, n_kv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, bq, d),
                                lambda bi, qi, ph, ki: (bi, 0, qi, 0)),
@@ -543,7 +532,7 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
     exactly as the decode kernel — the attention epilogue must clip to
     ≤ 8 bits, and the return becomes ``(B, C, N)``.
 
-    Grid ``(B, C/bq, 3, L/bkv)``; each step takes all heads of a query
+    Grid ``(B, C/bq, 2, L/bkv)``; each step takes all heads of a query
     block and of a KV block (``_streaming_attn_body``), so the folded
     projection sums a query block's heads within its last step.
 
@@ -650,7 +639,7 @@ def int_paged_prefill_fused(q8, k_pool, v_pool, plan: IAttnPlan, pos_end,
         else (pos_end, pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
-        grid=(b, c // bq, 3, n_kv),
+        grid=(b, c // bq, 2, n_kv),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,

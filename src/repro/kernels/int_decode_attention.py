@@ -43,9 +43,10 @@ returns the ``(B, Sq, N)`` projected output directly — one kernel for
 attention *and* o-projection, bit-exact against the unfolded
 attention-then-``int8_matmul`` composition.
 
-Like ``int_attention_fused`` this buys bit-exactness with three
-streaming sweeps over the live KV blocks (max → sum → normalise+AV) —
-integer maxima and sums are associative, so the result is bit-identical
+Like ``int_attention_fused`` this buys bit-exactness with two
+streaming sweeps over the live KV blocks (max → weights, their sum and
+AV; one division per row at the end) — integer maxima and sums are
+associative, so the result is bit-identical
 to the full-matrix decode oracle ``kernels.ref.ref_int_decode_attention``
 for every RequantSpec epilogue form.
 
@@ -56,9 +57,10 @@ stepped causal mask of draft verification).  ``Sq = 1`` reduces to the
 plain ``pos < valid_len`` occupancy mask.
 
 Accumulator budget (Sq ≤ 8 rows live in VMEM scratch the whole launch):
-row sums need ``valid_len ≤ 2¹⁵`` so ``Σ e16 ≤ 2³⁰`` stays int32-exact —
-the same ``MAX_SKV`` budget as the prefill kernel, asserted on the
-*logical cache length* here because ``valid_len ≤ L`` by construction.
+the weight sum is at most ``L·127`` and the P·V accumulator
+``L·127·127``, int32 up to ``MAX_PV_KEYS = 2¹⁷``; the launch is held to
+the prefill kernel's ``MAX_SKV = 2¹⁵``, asserted on the *logical cache
+length* here because ``valid_len ≤ L`` by construction.
 The folded-wo scratch adds ``(Sq, N)`` int32 (N = H·D out channels).
 """
 from __future__ import annotations
@@ -86,7 +88,7 @@ from repro.ops.spec import PER_CHANNEL, RequantSpec
 # both budgets are owned by repro.analysis.budgets; re-exported here
 # because callers (and tests) import them from the kernel module
 MAX_SQ = _MAX_SQ            # speculative query budget (scratch rows/head)
-MAX_SKV = MAX_ROWSUM_LEN    # row-sum int32 budget: L * 2^15 <= 2^30
+MAX_SKV = MAX_ROWSUM_LEN    # launch limit (inside MAX_PV_KEYS)
 
 
 def _decode_kernel(*refs, plan: IAttnPlan, requant: RequantSpec,
@@ -307,7 +309,7 @@ def int_decode_attention_fused(q8, k8_cache, v8_cache, plan: IAttnPlan,
         scalar_args = (valid_len,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
-        grid=(b, 3, n_kv),
+        grid=(b, 2, n_kv),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,

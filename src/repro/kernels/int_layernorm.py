@@ -3,7 +3,7 @@
 Each grid step takes a ``(br, d)`` block of rows, ``br`` chosen from the
 shape (:func:`norm_block_rows`, about 2 MiB of int32 input), and runs the
 ASIC's three phases on the whole block: integer mean (dyadic 1/d),
-variance with the design-time pre-shift, then per row the integer
+variance with each row's own shift (``core.norms.row_shift``), then per row the integer
 square root (:func:`isqrt_tile`: digit by digit, no division) and the
 reciprocal ``2^(k+pre) // sigma`` (:func:`recip_tile`: a float32
 estimate corrected to the exact floor quotient), and the per-channel
@@ -24,7 +24,7 @@ from jax.experimental import pallas as pl
 
 from repro import trace_names
 from repro.analysis.contracts import fit_block
-from repro.core.norms import INormPlan
+from repro.core.norms import INormPlan, row_shift, shift_by
 from repro.kernels import resolve_interpret
 
 #: int32 input bytes a grid step aims for: 512 rows at d = 768, 256 at
@@ -92,13 +92,14 @@ def _ln_kernel(x_ref, g_ref, b_ref, o_ref, *, plan: INormPlan,
         y = q - mu
     else:
         y = q
-    ys = _rshift_round(y, plan.pre_shift)
+    sh = row_shift(y, plan)
+    ys = shift_by(y, sh)
     var = _apply_dn(jnp.sum(ys * ys, axis=-1, keepdims=True), plan.dn_var)
     sigma_s = isqrt_tile(var)
     # an all-equal row (sigma 0) normalizes to 0: r = 0 makes y * r = 0
     r = jnp.where(sigma_s == 0, 0, recip_tile(
         1 << (plan.recip_bits + plan.pre_shift), sigma_s))
-    out = _rshift_round(y * r, 2 * plan.pre_shift) \
+    out = shift_by(y * r, plan.pre_shift + sh) \
         * g_ref[...].astype(jnp.int32)[None, :]
     if b_ref is not None:
         out = out + b_ref[...].astype(jnp.int32)[None, :]

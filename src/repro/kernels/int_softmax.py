@@ -8,6 +8,10 @@ scores are read from HBM exactly once.
 
 Rows are assumed int32 at the plan's score scale; output is int8
 probabilities at 2^-7 (see core.softmax for the scale plan).
+
+Also here, shared by every attention kernel: the Shiftmax tile helpers —
+i-exp on a tile, the int8 attention weights, and the per-row division
+after P·V that ``core.softmax.normalize_rows`` defines.
 """
 from __future__ import annotations
 
@@ -20,7 +24,8 @@ from jax.experimental import pallas as pl
 
 from repro import trace_names
 from repro.analysis.contracts import fit_block
-from repro.core.softmax import ISoftmaxPlan, PROB_SHIFT, RECIP_BITS
+from repro.core.softmax import (ISoftmaxPlan, PROB_SHIFT, RECIP_BITS,
+                                U_MAX, U_SHIFT)
 from repro.kernels import resolve_interpret
 
 
@@ -48,6 +53,32 @@ def _exp16_tile(q_sub, plan: ISoftmaxPlan):
     d = plan.dn_e16
     return _rshift_round(_rshift_round(e, d.pre) * jnp.int32(d.b),
                          d.c - d.pre)
+
+
+def normalize_tile(acc, s):
+    """``core.softmax.normalize_rows`` in a kernel, without an integer
+    division: ``round_half_up(acc * 2^7 / s)`` per row.
+
+    A float32 estimate of the quotient lies within 0.01 of the true one
+    (``|quotient| <= 128 * 127 + 1``; the operands' and the reciprocal's
+    rounding are a few parts in 2^24 of it), so its floor is off by at
+    most one.  The remainder ``2^7 * acc + s // 2 - q * s`` of the
+    estimate is exact in int32 even where the products wrap (the true
+    remainder lies within ``(-s, 2s)``), and one compare each way
+    settles the floor."""
+    s = jnp.maximum(s, 1)
+    half = s >> 1
+    inv = 1.0 / s.astype(jnp.float32)
+    est = (acc.astype(jnp.float32) * float(1 << PROB_SHIFT)
+           + half.astype(jnp.float32)) * inv
+    q = jnp.floor(est).astype(jnp.int32)
+    rem = (acc << PROB_SHIFT) + half - q * s
+    return q + (rem >= s).astype(jnp.int32) - (rem < 0).astype(jnp.int32)
+
+
+def attn_weights_tile(e16):
+    """``core.softmax.attn_weights`` on a tile."""
+    return jnp.minimum(_rshift_round(e16, U_SHIFT), U_MAX)
 
 
 def _softmax_kernel(x_ref, o_ref, *, plan: ISoftmaxPlan, masked: bool,

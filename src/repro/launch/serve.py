@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import time
 
 import jax
@@ -82,14 +83,28 @@ async def _serve(fe: ServingFrontend, prompts, args) -> list:
     return handles
 
 
+def served_config(arch: str, reduced: bool = False, ckpt_dir=None):
+    """The config ``--arch`` / ``--reduced`` serve.  Random weights (no
+    ``ckpt_dir``) of a decoder draw its head apart from the embedding: a
+    tied random head predicts the current token, so greedy streams fall
+    into one repeated token (Granite-3-2B's reduced float graph does so
+    in some stream of the smoke's prompts at each of 8 weight seeds),
+    and a stream of one token could not show a misread cache.  A checkpoint keeps the config it was
+    trained at."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = M.reduce_config(cfg, dtype="float32", vocab=1024)
+    if not ckpt_dir and cfg.tie_embeddings and cfg.family != "encoder":
+        cfg = dataclasses.replace(cfg, tie_embeddings=False)
+    return cfg
+
+
 def load_quantized(arch: str, reduced: bool = False, ckpt_dir=None):
     """``(cfg, qparams, plans)`` for one model: the checkpoint in
     ``ckpt_dir`` or, without one, seeded random weights drawn at the
     served scale (``init_params(..., served=True)``), quantized to the
     integer datapath."""
-    cfg = get_config(arch)
-    if reduced:
-        cfg = M.reduce_config(cfg, dtype="float32", vocab=1024)
+    cfg = served_config(arch, reduced, ckpt_dir)
     params = tf.init_params(jax.random.key(0), cfg, served=not ckpt_dir)
     if ckpt_dir:
         params, meta = load_checkpoint(ckpt_dir, (params, None))
@@ -224,8 +239,7 @@ def main(argv=None, model=None) -> dict:
         ap.error("--arrival-rate must be >= 0 requests/s")
     if not 0 <= args.shared_prefix <= args.prompt_len:
         ap.error("--shared-prefix must be within [0, --prompt-len]")
-    if args.reduced:
-        cfg = M.reduce_config(cfg, dtype="float32", vocab=1024)
+    cfg = served_config(args.arch, args.reduced, args.ckpt_dir)
     # --tp validates against the FINAL config (--reduced shrinks the
     # head counts), same early-typed-error policy as the flags above
     try:

@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,17 @@ class ArchConfig:
     # audio frontend stub
     n_audio_frames: int = 0
 
+    # scalar multipliers of the Granite 3.x block (neutral defaults): the
+    # embedding is scaled by ``embedding_multiplier``, attention scores
+    # by ``attention_multiplier`` (None: 1/sqrt(head_dim)), every
+    # residual branch by ``residual_multiplier``, and the logits divided
+    # by ``logits_scaling``.  The integer path folds each into a dyadic
+    # constant or the head's dequant scale (quant.plans).
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
     # numerics / execution
     dtype: str = "bfloat16"
     kernel_backend: str = "ref"  # ref | pallas
@@ -80,6 +92,20 @@ class ArchConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def score_scale(self) -> float:
+        """The real factor on Q·Kᵀ before the softmax."""
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return 1.0 / math.sqrt(self.hd)
+
+    @property
+    def s_branch(self) -> float:
+        """The scale a residual branch's output projection requantizes
+        to: the residual grid over ``residual_multiplier``, so the
+        branch lands on the stream already multiplied."""
+        return self.s_res / self.residual_multiplier
 
     @property
     def q_group(self) -> int:

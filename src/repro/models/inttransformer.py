@@ -111,7 +111,7 @@ def logits_int(qparams, x32, plans: qplans.LayerPlans, cfg: ArchConfig,
         acc = il.int_linear(h8, qparams["head"], head_plan, ops)
         # host-side dequant boundary: float per-channel scales
         return acc.astype(jnp.float32) * qparams["head_scale"][None] \
-            * cfg.s_act8
+            * plans.head.s_in
 
 
 @trace_names.entry_point

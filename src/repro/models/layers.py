@@ -146,7 +146,7 @@ def attn_fwd(p, x, cfg: ArchConfig, positions=None, causal=True,
     k = _repeat_kv(k, cfg.q_group)
     v = _repeat_kv(v, cfg.q_group)
 
-    scale = 1.0 / math.sqrt(cfg.hd)
+    scale = cfg.score_scale
     qc = min(q_chunk, s)
     while s % qc:
         qc -= 1

@@ -93,17 +93,28 @@ def init_params(key, cfg: ArchConfig, served: bool = False) -> Pytree:
     ``chip_smoke.py``), so the integer datapath computes on a living
     stream at published widths and depths:
 
-      * the embedding at std ``SERVED_EMBED_STD`` — the fan-in
+      * the embedding at std ``SERVED_EMBED_STD`` once multiplied by
+        the architecture's ``embedding_multiplier`` — the fan-in
         1/sqrt(vocab), about 0.004 at a published vocab, sits under the
         integer norms' pre-shift on the residual grid, so every integer
-        activation is 0 and all logits are equal;
+        activation is 0 and all logits are equal; drawn at 0.1 under
+        Granite's x12, the embedding outweighs 40 layers' branches and
+        with tied embeddings the stream repeats its input token;
       * the residual branches' output projections (``wo`` at its whole
-        H·hd fan-in, ``w2``) at gain ``SERVED_BRANCH_GAIN`` — at 40
+        H·hd fan-in, ``w2``) at gain ``SERVED_BRANCH_GAIN`` once
+        multiplied by the ``residual_multiplier`` — at 40
         layers the fan-in draw (``wo``'s fan-in is taken over heads
         alone) saturates the residual bus at ±16, and with tied
         embeddings a stream that the current token's embedding
         dominates repeats one token; at this scale the layers pick
         the next token and nothing clips.
+
+    So the stream the layers see is the same whatever an
+    architecture's embedding and residual multipliers are: the integer
+    path folds them into its constants, and the float graph applies
+    them.  Queries and keys keep their fan-in draw, so an
+    ``attention_multiplier`` below 1/sqrt(head_dim) (Granite's 1/64)
+    leaves served attention nearly flat, as it is at that scale.
     """
     dtype = jnp.dtype(cfg.dtype)
     gl, ng, kinds = layer_group_spec(cfg)
@@ -135,14 +146,15 @@ def init_params(key, cfg: ArchConfig, served: bool = False) -> Pytree:
 def _served_scale(params, cfg: ArchConfig):
     """The ``served=True`` rescaling of :func:`init_params`."""
     v = cfg.padded_vocab()
-    gains = {"wo": SERVED_BRANCH_GAIN / math.sqrt(cfg.hd or 1),
-             "w2": SERVED_BRANCH_GAIN}
+    gain = SERVED_BRANCH_GAIN / cfg.residual_multiplier
+    gains = {"wo": gain / math.sqrt(cfg.hd or 1), "w2": gain}
 
     def scale(path, leaf):
         g = gains.get(getattr(path[-1], "key", None), 1.0)
         return leaf if g == 1.0 else (leaf * g).astype(leaf.dtype)
     out = dict(params)
-    out["embed"] = (params["embed"] * (SERVED_EMBED_STD * math.sqrt(v))
+    std = SERVED_EMBED_STD / cfg.embedding_multiplier
+    out["embed"] = (params["embed"] * (std * math.sqrt(v))
                     ).astype(params["embed"].dtype)
     for name in ("layers", "enc_layers"):
         if name in params:
@@ -172,26 +184,30 @@ def _sublayer_fwd_float(p, x, cfg: ArchConfig, kind, positions, qat,
             return fl.moe_fwd(p["moe"], h, cfg, qat=qat)
         return fl.ffn_fwd(p["ffn"], h, cfg, qat=qat), None
 
+    def branch(y):
+        m = cfg.residual_multiplier
+        return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
+
     if cfg.post_norm:
-        x = fl.norm_fwd(p["norm1"], x + mixer(x), cfg)
+        x = fl.norm_fwd(p["norm1"], x + branch(mixer(x)), cfg)
         if has_cross:
             c = fl.attn_fwd(p["cross"], x, cfg, positions, causal=False,
                             memory=memory, qat=qat)
-            x = fl.norm_fwd(p["norm_cross"], x + c, cfg)
+            x = fl.norm_fwd(p["norm_cross"], x + branch(c), cfg)
         if ff is not None:
             f, a = ffn(x)
-            x = fl.norm_fwd(p["norm2"], x + f, cfg)
+            x = fl.norm_fwd(p["norm2"], x + branch(f), cfg)
             if a is not None:
                 aux = aux + a
         return x, aux
-    x = x + mixer(fl.norm_fwd(p["norm1"], x, cfg))
+    x = x + branch(mixer(fl.norm_fwd(p["norm1"], x, cfg)))
     if has_cross:
         h = fl.norm_fwd(p["norm_cross"], x, cfg)
-        x = x + fl.attn_fwd(p["cross"], h, cfg, positions, causal=False,
-                            memory=memory, qat=qat)
+        x = x + branch(fl.attn_fwd(p["cross"], h, cfg, positions,
+                                   causal=False, memory=memory, qat=qat))
     if ff is not None:
         f, a = ffn(fl.norm_fwd(p["norm2"], x, cfg))
-        x = x + f
+        x = x + branch(f)
         if a is not None:
             aux = aux + a
     return x, aux
@@ -228,6 +244,8 @@ def _run_stack_float(layer_params: List, x, cfg: ArchConfig, kinds,
 
 def embed_tokens(params, tokens, cfg: ArchConfig):
     x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.pos == "learned":
         s = tokens.shape[1]
         x = x + params["pos_embed"][:s][None]
@@ -242,6 +260,8 @@ def logits_fwd(params, x, cfg: ArchConfig, qat=False):
     # tied, or an encoder (its MLM head shares the word embedding)
     w = params["lm_head"] if "lm_head" in params else params["embed"].T
     logits = jnp.einsum("bsd,dv->bsv", x, fl.fq_weight(w, 1, qat))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return shard(logits, "batch", "seq", "vocab")
 
 

@@ -10,6 +10,7 @@ frozen static plan set.
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Tuple
 
 import jax
@@ -23,6 +24,11 @@ from repro.ops import QuantLinearParams
 from repro.quant import plans as qplans
 
 Pytree = Any
+
+#: layers of a stack quantized at once on host threads (numpy releases
+#: the interpreter lock in the float64 work; each thread holds one
+#: layer's float64 working copy)
+QUANT_WORKERS = 4
 
 
 def _pc_scales(w: np.ndarray, out_axis: int) -> np.ndarray:
@@ -193,12 +199,18 @@ def _q_stacked(p_stack, plans: qplans.LayerPlans, cfg: ArchConfig, kind):
     first — but the float64 working copy is one layer's, not the
     stack's: at published widths a stacked FFN matrix alone is
     gigabytes of float64 (40 x 2048 x 8192 x 8 bytes for Granite-3-2B).
+    The layers run :data:`QUANT_WORKERS` at a time on host threads: the
+    same functions on the same inputs, each layer's result its own.
     """
     n = jax.tree.leaves(p_stack)[0].shape[0]
     scales = _stack_scales(p_stack, cfg, kind)
-    slices = [jax.tree.map(np.asarray, _q_sublayer(
-        jax.tree.map(lambda t: t[i], p_stack), plans, cfg, kind, {},
-        scales)) for i in range(n)]
+
+    def one(i):
+        return jax.tree.map(np.asarray, _q_sublayer(
+            jax.tree.map(lambda t: t[i], p_stack), plans, cfg, kind, {},
+            scales))
+    with ThreadPoolExecutor(min(QUANT_WORKERS, n)) as pool:
+        slices = list(pool.map(one, range(n)))
     return jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *slices)
 
 

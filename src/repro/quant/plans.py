@@ -86,11 +86,12 @@ class NormPlan(NamedTuple):
 
 class EmbedPlan(NamedTuple):
     s_emb: float             # int8 embedding table scale
-    dn_res: Dyadic           # s_emb -> s_res
+    dn_res: Dyadic           # s_emb * embedding_multiplier -> s_res
 
 
 class HeadPlan(NamedTuple):
-    s_in: float              # logits stay int32 at s_in * s_w (dequant host-side)
+    s_in: float              # logits stay int32 at s_in * s_w (dequant
+    #                          host-side); s_act8 / logits_scaling
 
 
 class MambaPlan(NamedTuple):
@@ -147,7 +148,7 @@ def _ffn_plan(cfg: ArchConfig, d_in: int, d_ff: int) -> FfnPlan:
     else:
         gelu = iact.make_igelu_act(s10, 1024, s_out=s8)
         silu, dn_gate = None, None
-    down = make_linear_plan(s8, S_W8, cfg.s_res, d_ff, out_bits=14)
+    down = make_linear_plan(s8, S_W8, cfg.s_branch, d_ff, out_bits=14)
     return FfnPlan(up, gelu, silu, dn_gate, down)
 
 
@@ -162,13 +163,15 @@ def build_layer_plans(cfg: ArchConfig, calib: Optional[dict] = None
                                  s_gamma=2.0 / 127.0, s_out=s8,
                                  subtract_mean=(cfg.norm == "layernorm"))
     s_emb = calib.get("s_emb", s8)
-    embed = EmbedPlan(s_emb, fit_dyadic(s_emb / cfg.s_res, 127))
+    embed = EmbedPlan(s_emb, fit_dyadic(
+        s_emb * cfg.embedding_multiplier / cfg.s_res, 127))
 
     attn = cross = None
     if cfg.family in ("dense", "encdec", "vlm", "moe", "hybrid", "encoder"):
         qkv = make_linear_plan(s8, S_W8, s8, d)
-        ia = iattn.make_iattention(cfg.hd, s8, s8, s8, s8)
-        out = make_linear_plan(s8, S_W8, cfg.s_res,
+        ia = iattn.make_iattention(cfg.hd, s8, s8, s8, s8,
+                                   score_scale=cfg.attention_multiplier)
+        out = make_linear_plan(s8, S_W8, cfg.s_branch,
                                cfg.n_heads * cfg.hd, out_bits=14)
         attn = AttnPlan(qkv, ia, out)
         if cfg.family in ("encdec", "vlm"):
@@ -182,7 +185,7 @@ def build_layer_plans(cfg: ArchConfig, calib: Optional[dict] = None
         gate_sm = ism.make_isoftmax(s8 * s_router, router.acc_qmax)
         f = cfg.moe_d_ff or cfg.d_ff
         expert = _ffn_plan(cfg, d, f)
-        dn_combine = fit_dyadic(s8 * ism.S_PROB / cfg.s_res,
+        dn_combine = fit_dyadic(s8 * ism.S_PROB / cfg.s_branch,
                                 cfg.top_k * 127 * 127)
         shared = _ffn_plan(cfg, d, f * cfg.n_shared_experts) \
             if cfg.n_shared_experts else None
@@ -194,7 +197,7 @@ def build_layer_plans(cfg: ArchConfig, calib: Optional[dict] = None
     if cfg.family in ("ssm", "hybrid"):
         mamba = _mamba_plan(cfg, calib)
 
-    head = HeadPlan(s8)
+    head = HeadPlan(s8 / cfg.logits_scaling)
     return LayerPlans(cfg.name, embed, norm_plan, attn, ffn, moe, mamba,
                       cross, head, norm_plan)
 
@@ -240,7 +243,7 @@ def _mamba_plan(cfg: ArchConfig, calib: Optional[dict] = None) -> MambaPlan:
     norm = norms.make_inorm(cfg.ssm_d_inner, 1.0, 1 << 11,
                             s_gamma=2.0 / 127.0, s_out=s8,
                             subtract_mean=False)
-    out_proj = make_linear_plan(s8, S_W8, cfg.s_res, cfg.ssm_d_inner,
+    out_proj = make_linear_plan(s8, S_W8, cfg.s_branch, cfg.ssm_d_inner,
                                 out_bits=14)
     s_conv = calib.get("s_conv", S_W8)
     # conv+silu outputs (x/B/C) have a wider dynamic range than the s8

@@ -187,7 +187,7 @@ def test_tpu_block_rule_refuses_one_head_kv_layout():
     rep = check_launch("int_decode_attention", b=8, sq=1, h=32, hkv=8,
                        d=64, max_pages=8, page_size=128, num_pages=65,
                        per_channel=True, fold=True, n_out=2048)
-    assert rep.ok and rep.fused and rep.grid == (8, 3, 8)
+    assert rep.ok and rep.fused and rep.grid == (8, 2, 8)
 
 
 def test_vmem_budget_refuses_a_fold_too_wide_for_the_chip():

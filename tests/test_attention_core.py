@@ -59,7 +59,8 @@ def test_chunked_matches_full(rng, window):
     chk = np.asarray(iattn.i_attention_chunked(
         jnp.asarray(q8), jnp.asarray(k8), jnp.asarray(v8), plan,
         chunk=64, causal=True, window=window))
-    assert np.abs(chk.astype(int) - full.astype(int)).max() <= 2
+    # the weights' sum and P·V add the same integers in another order
+    assert np.array_equal(chk, full)
 
 
 def test_decode_matches_full_last_row(rng):
@@ -73,4 +74,4 @@ def test_decode_matches_full_last_row(rng):
     dec = np.asarray(iattn.i_attention_decode(
         jnp.asarray(q8[:, -1:]), jnp.asarray(k8), jnp.asarray(v8), plan,
         valid_len=jnp.full((b,), s, jnp.int32)))
-    assert np.abs(dec[:, 0].astype(int) - full[:, -1].astype(int)).max() <= 1
+    assert np.array_equal(dec[:, 0], full[:, -1])

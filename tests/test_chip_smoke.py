@@ -85,7 +85,8 @@ def test_served_weights_keep_the_residual_stream_off_the_rails():
     weights neither saturate the residual bus at ±16 (a unit embedding
     over the fan-in layers clipped about 14% of it) nor leave it to the
     current token's embedding: the layers add several times the
-    embedding's scale."""
+    embedding's scale.  The embedding is drawn at the served std once
+    Granite's ``embedding_multiplier`` has scaled it."""
     import jax.numpy as jnp
     import numpy as np
     from repro.models import inttransformer as it
@@ -97,7 +98,8 @@ def test_served_weights_keep_the_residual_stream_off_the_rails():
                           head_dim=64, n_heads=2, n_kv_heads=1)
     params = tf.init_params(jax.random.key(0), cfg, served=True)
     emb = np.asarray(params["embed"])
-    assert abs(emb.std() - tf.SERVED_EMBED_STD) < 0.01
+    assert abs(emb.std() * cfg.embedding_multiplier
+               - tf.SERVED_EMBED_STD) < 0.01
     qp, plans = convert.quantize_params(params, cfg)
     _, ng, kinds = layer_group_spec(cfg)
     tokens = jax.random.randint(jax.random.key(1), (2, 16), 1, cfg.vocab)

@@ -84,10 +84,11 @@ def test_integer_path_preserves_accuracy(trained):
 @pytest.mark.parametrize("arch", ["granite-3-2b", "roberta-base",
                                   "jamba-v0.1-52b"])
 def test_layerwise_quantization_matches_stacked(arch):
-    """``quantize_params`` quantizes a layer stack one layer at a time
-    (bounded host memory at published depth); the integers must equal
-    quantizing the whole stack at once — scales are per layer along the
-    stack axis, so slicing cannot change a single value."""
+    """``quantize_params`` quantizes a layer stack one layer at a time,
+    several layers at once on host threads (bounded host memory at
+    published depth); the integers must equal quantizing the whole stack
+    at once — scales are per layer along the stack axis, so slicing
+    cannot change a single value, nor can the order the threads take."""
     from repro.models.transformer import layer_group_spec
     cfg = M.reduce_config(get_config(arch), dtype="float32", vocab=256)
     params = tf.init_params(jax.random.key(1), cfg)

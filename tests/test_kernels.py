@@ -118,6 +118,25 @@ def test_int_layernorm_kernel_edge_rows(rng, d, subtract_mean):
     assert np.array_equal(got, want)
 
 
+@pytest.mark.parametrize("d,subtract_mean", [(768, True), (2048, False)])
+def test_int_layernorm_kernel_row_shifts(rng, d, subtract_mean):
+    """Rows from a few LSB to the bus's edge take the per-row shifts
+    before squaring, left and right (``core.norms.row_shift``), and the
+    kernel stays bit-identical to ``i_norm`` at each."""
+    qmax = 1 << 13
+    plan, qg, qb = _norm_case(rng, d, subtract_mean, qmax)
+    scale = 2.0 ** rng.uniform(-1, 14, (512, 1))
+    q = np.clip(np.round(rng.normal(0, 1, (512, d)) * scale),
+                -qmax, qmax).astype(np.int32)
+    sh = np.asarray(norms.row_shift(jnp.asarray(q), plan)).ravel()
+    # every shift a row within the bus reaches (a shift of pre_shift
+    # itself needs a centred value past qmax_in)
+    assert set(sh) == set(range(-plan.pre_shift, plan.pre_shift))
+    got = np.asarray(PALLAS.int_layernorm(jnp.asarray(q), qg, qb, plan))
+    want = np.asarray(REF.int_layernorm(jnp.asarray(q), qg, qb, plan))
+    assert np.array_equal(got, want)
+
+
 def _run_tile(fn, *xs):
     """Apply an in-kernel helper to (8, n) int32 tiles in interpret mode."""
     from jax.experimental import pallas as pl

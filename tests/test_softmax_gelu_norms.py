@@ -90,6 +90,49 @@ def test_inorm_rmsnorm(rng):
     assert np.abs(got - ref).max() < 0.1
 
 
+def _old_rmsnorm(q, plan):
+    """The norm as it squared every row after the design-time shift."""
+    y = q.astype(np.int64)
+    s = plan.pre_shift
+    ys = (y + (1 << (s - 1))) >> s
+    var = np.asarray(plan.dn_var(jnp.asarray(
+        (ys * ys).sum(-1, keepdims=True).astype(np.int32))))
+    sigma = np.floor(np.sqrt(var))
+    return np.where(sigma == 0, 0, y / (np.maximum(sigma, 1) * (1 << s)))
+
+
+@pytest.mark.parametrize("std", [12, 51, 400, 3000])
+def test_inorm_small_stream(rng, std):
+    """A residual stream a few LSB wide under the design-time pre-shift
+    (Granite-3-2B's RMSNorm: d 2048, qmax 8192, a 5-bit shift; its
+    embedding reaches the first norm at 51 LSB) is normalised to within
+    2.5% of its float RMSNorm in norm.  Each row is shifted so its
+    largest value is as large as the squared sum allows; squared after
+    the fixed shift, the floor of the square root of a few LSB read sigma
+    0 at 12 LSB (the row normalised to 0) and 1 for 1.6 at 51 (the old
+    arithmetic is checked to miss by more than 30% at both).  2.5%: gamma = 1
+    itself rounds to 64 / 63.5 on the int8 grid of 2/127 (0.8%); the
+    shift leaves the row's max at 256-512, so its sigma at 64 or more
+    (a max of 2048 normal values lies within 4 sigma), whose square
+    root's floor reads it low by less than 1/64 (1.6%); the reciprocal
+    and the int8 output grid add less than 0.1%."""
+    d, s_in, qmax = 2048, 2.0 ** -9, 1 << 13
+    plan = norms.make_inorm(d, s_in, qmax, 2 / 127, 8 / 127,
+                            subtract_mean=False)
+    assert plan.pre_shift == 5
+    qg, _ = norms.quantize_norm_weights(jnp.ones(d), None, plan)
+    q = np.round(rng.normal(0, std, (8, d))).astype(np.int32)
+    ref = q / np.sqrt((q.astype(np.float64) ** 2).mean(-1, keepdims=True))
+    got = np.asarray(norms.i_norm(jnp.asarray(q), qg, None, plan)) \
+        * plan.s_out
+    ratio = np.linalg.norm(got, axis=-1) / np.linalg.norm(ref, axis=-1)
+    assert np.abs(ratio - 1).max() < 0.025, ratio
+    if std <= 51:
+        old = np.linalg.norm(_old_rmsnorm(q, plan), axis=-1) \
+            / np.linalg.norm(ref, axis=-1)
+        assert np.abs(old - 1).min() > 0.3, old
+
+
 def test_inorm_constant_row():
     d, s_in = 64, 8 / 1024
     plan = norms.make_inorm(d, s_in, 1024, 2 / 127, 8 / 127)
