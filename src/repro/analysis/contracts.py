@@ -100,7 +100,12 @@ def fit_block(blk: int, dim: int, align: int = 1) -> int:
 #: compiles and one of about 67 MiB runs out of VMEM; a folded-wo
 #: attention launch with a 15 MiB ``wo`` block compiles and one with
 #: 16 MiB (Llama-3-8B's 4096 x 4096) does not.  ``vmem_bytes`` below is
-#: the estimate held to it.
+#: the estimate held to it.  The int8 matmul's own limit is lower:
+#: under the chip's default 16 MiB of scoped VMEM, launches estimated at
+#: 24 MiB (int8 out) and 36 MiB (int32 out) run out, and so does a
+#: split-K one at 13.5 MiB (the compiler's int32 tiles come on top).  So
+#: that kernel asks for ``kernels.int8_matmul.VMEM_LIMIT`` of scoped
+#: VMEM and holds its blocks to ``BLOCK_BYTES`` of this estimate.
 VMEM_BUDGET = 64 << 20
 
 #: the TPU's native (sublane, lane) tile: a block's last two dims must

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.analysis.contracts import fit_block as _fit_block
 from repro.kernels import resolve_interpret
-from repro.kernels.int8_matmul import int8_matmul_pallas
+from repro.kernels.int8_matmul import PACKED_BLOCKS, int8_matmul_pallas
 from repro.kernels.int_attention import int_attention_pallas
 from repro.kernels.int_gelu import int_gelu_pallas
 from repro.kernels.int_layernorm import int_layernorm_pallas
@@ -25,18 +25,25 @@ from repro.ops import spec as _spec
 
 
 def _matmul_blocks(opts: dict, m: int, n: int, k: int, packed=False):
-    """Requested matmul blocks fitted to chip-legal divisors: rows a
-    multiple of 8, lanes (bn, and bk — the x block's lane dim) a
-    multiple of 128, else the whole dim (``contracts.fit_block``).
-    Packed weights pair nibbles along K, so bk is fitted on K/2 pairs
-    and doubled (its half is the packed block's row dim)."""
-    bm = _fit_block(opts.pop("bm", 128), m, 8)
-    bn = _fit_block(opts.pop("bn", 128), n, 128)
-    want_k = opts.pop("bk", 512)
+    """Requested matmul blocks (the call's, or a profile's such as
+    ``pallas_tuned``) fitted to chip-legal divisors: rows a multiple of
+    8, lanes (bn, and bk — the x block's lane dim) a multiple of 128,
+    else the whole dim (``contracts.fit_block``).  A dense block not
+    requested stays None: the kernel takes it from the launch's shape
+    (``kernels.int8_matmul.matmul_blocks``).  Packed weights default to
+    ``PACKED_BLOCKS`` and pair nibbles along K, so bk is fitted on K/2
+    pairs and doubled (its half is the packed block's row dim)."""
+    want = PACKED_BLOCKS if packed else (None, None, None)
+    bm, bn, bk = (opts.pop(key, w) for key, w in zip(("bm", "bn", "bk"),
+                                                     want))
+    if bm is not None:
+        bm = _fit_block(bm, m, 8)
+    if bn is not None:
+        bn = _fit_block(bn, n, 128)
     if packed:
-        bk = 2 * _fit_block(max(want_k // 2, 1), k // 2, 64)
-    else:
-        bk = _fit_block(want_k, k, 128)
+        bk = 2 * _fit_block(max(bk // 2, 1), k // 2, 64)
+    elif bk is not None:
+        bk = _fit_block(bk, k, 128)
     return bm, bn, bk
 
 

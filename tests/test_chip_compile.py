@@ -32,6 +32,7 @@ R_H, R_D, R_MODEL, R_FF = 12, 64, 768, 3072
 # still folds wo into the attention launch, Llama-3-8B (head_dim 128,
 # d 4096) is past the VMEM budget and runs it unfolded
 DANUBE_D, DANUBE_MODEL, LLAMA_D, LLAMA_MODEL = 120, 3840, 128, 4096
+LLAMA_FF = 14336
 BATCH, PAGE, CACHE = 8, 128, 1024
 
 
@@ -64,25 +65,44 @@ I8, I32 = jnp.int8, jnp.int32
 
 # ------------------------------------------------------------- matmul --
 
-@pytest.mark.parametrize("form", ["per_tensor", "per_channel_bias",
-                                  "packed", "decode_m8"])
+# form -> (M, K, N, output bits); blocks from the shape rule
+# (``kernels.int8_matmul.matmul_blocks``).  The cells' widest launches
+# pin its byte budget; Llama-3-8B's down projection, split into four K
+# steps, needs more than the chip's default 16 MiB of scoped VMEM and
+# pins ``int8_matmul.VMEM_LIMIT``.
+MATMUL_FORMS = {
+    "per_tensor": (256, G_MODEL, G_FF, 8),
+    "per_channel_bias": (256, G_MODEL, G_FF, 8),
+    "packed": (256, G_MODEL, G_FF, 8),
+    "decode_m8": (8, G_MODEL, G_FF, 8),
+    "roberta_up": (32768, R_MODEL, R_FF, 16),
+    "roberta_down": (32768, R_FF, R_MODEL, 16),
+    "granite_w1": (8192, G_MODEL, G_FF, 16),
+    "granite_w2": (8192, G_FF, G_MODEL, 16),
+    "llama_split_k": (2048, LLAMA_FF, LLAMA_MODEL, 8),
+}
+
+
+@pytest.mark.parametrize("form", list(MATMUL_FORMS))
 def test_int8_matmul_compiles(one_chip, form):
     from repro.kernels.int8_matmul import int8_matmul_pallas
     from repro.ops.backends.pallas import _matmul_blocks
-    m = 8 if form == "decode_m8" else 256
-    k, n = G_MODEL, G_FF
-    packed = form == "packed"
-    bm, bn, bk = _matmul_blocks({}, m, n, k, packed=packed)
-    dn = fit_dyadic(1 / 4000.0, k * 127 * 127)
+    m, k, n, out_bits = MATMUL_FORMS[form]
+    out_dtype = I8 if out_bits <= 8 else I32
 
-    if form == "per_channel_bias":
+    if form not in ("per_tensor", "packed", "decode_m8"):
         # how every model projection runs: per-channel multipliers + bias
         def fn(x, w, bias, bvec):
             return int8_matmul_pallas(x, w, bias, b_vec=bvec, c=28, pre=7,
-                                      bm=bm, bn=bn, bk=bk, interpret=False)
+                                      out_bits=out_bits,
+                                      out_dtype=out_dtype, interpret=False)
         _compile(one_chip, fn, ((m, k), I8), ((k, n), I8), ((n,), I32),
                  ((n,), I32))
         return
+
+    packed = form == "packed"
+    bm, bn, bk = _matmul_blocks({}, m, n, k, packed=packed)
+    dn = fit_dyadic(1 / 4000.0, k * 127 * 127)
 
     def fn(x, w):
         return int8_matmul_pallas(x, w, None, dn=dn, bm=bm, bn=bn, bk=bk,

@@ -17,23 +17,88 @@ PALLAS = get_backend("pallas")
 REF = get_backend("ref")
 
 
-@pytest.mark.parametrize("m,k,n,bm,bn,bk", [
-    (128, 256, 128, 128, 128, 256),
-    (256, 1024, 384, 128, 128, 256),
-    (64, 128, 512, 64, 128, 128),
-    (128, 896, 128, 128, 128, 128),
+FUSED = get_backend("pallas_fused")
+
+
+def _epilogue(form, rng, k, n):
+    """A matmul epilogue named ``<requant><out bits>[_bias]``: the
+    RequantSpec and the bias and per-channel multipliers it takes."""
+    bias = rng.integers(-2**18, 2**18, (n,)).astype(np.int32) \
+        if form.endswith("_bias") else None
+    bits = 8 if "8" in form else 16
+    if form.startswith("tensor"):
+        dn = fit_dyadic(1 / 4000.0, k * 127 * 127 + 2**18)
+        return RequantSpec.per_tensor(dn, bits), bias, None
+    bvec = rng.integers(1000, 30000, (n,)).astype(np.int32)
+    return RequantSpec.per_channel(28, 7, bits), bias, bvec
+
+
+# blocks None take the shape rule (kernels.int8_matmul.matmul_blocks)
+@pytest.mark.parametrize("m,k,n,bm,bn,bk,form", [
+    pytest.param(128, 256, 128, 128, 128, 256, "tensor8_bias",
+                 id="128-256-128-128-128-256"),
+    pytest.param(256, 1024, 384, 128, 128, 256, "tensor8_bias",
+                 id="256-1024-384-128-128-256"),
+    pytest.param(64, 128, 512, 64, 128, 128, "tensor8_bias",
+                 id="64-128-512-64-128-128"),
+    pytest.param(128, 896, 128, 128, 128, 128, "tensor8_bias",
+                 id="128-896-128-128-128-128"),
+    # the rule: one K step, so no accumulator (RoBERTa's q, up, down)
+    (256, 768, 768, None, None, None, "tensor8"),
+    (64, 768, 3072, None, None, None, "channel16_bias"),
+    (64, 3072, 768, None, None, None, "channel16"),
+    # the rule splits K = 8192 into two steps (a decode-sized w2)
+    (16, 8192, 2048, None, None, None, "channel16_bias"),
+    # an odd N takes its whole width as one block
+    (8, 512, 300, None, None, None, "channel8"),
+    # explicit blocks: split K and one K step, both epilogues
+    (64, 1024, 256, 64, 128, 256, "channel8_bias"),
+    (128, 512, 256, 128, 256, 512, "tensor16_bias"),
 ])
-def test_int8_matmul_shapes(rng, m, k, n, bm, bn, bk):
-    x = rng.integers(-127, 128, (m, k)).astype(np.int8)
-    w = rng.integers(-127, 128, (k, n)).astype(np.int8)
-    bias = rng.integers(-2**18, 2**18, (n,)).astype(np.int32)
-    dn = fit_dyadic(1 / 4000.0, k * 127 * 127 + 2**18)
-    got = np.asarray(PALLAS.int8_matmul(
-        jnp.asarray(x), jnp.asarray(w), RequantSpec.per_tensor(dn),
-        bias32=jnp.asarray(bias), bm=bm, bn=bn, bk=bk))
-    want = np.asarray(ref.ref_int8_matmul(
-        jnp.asarray(x), jnp.asarray(w), jnp.asarray(bias), dn))
+def test_int8_matmul_shapes(rng, m, k, n, bm, bn, bk, form):
+    x = jnp.asarray(rng.integers(-127, 128, (m, k)).astype(np.int8))
+    w = jnp.asarray(rng.integers(-127, 128, (k, n)).astype(np.int8))
+    spec, bias, bvec = (jnp.asarray(v) if isinstance(v, np.ndarray) else v
+                        for v in _epilogue(form, rng, k, n))
+    blocks = {key: v for key, v in zip(("bm", "bn", "bk"), (bm, bn, bk))
+              if v is not None}
+    got = np.asarray(FUSED.int8_matmul(x, w, spec, bias32=bias, b_vec=bvec,
+                                       **blocks))
+    want = np.asarray(REF.int8_matmul(x, w, spec, bias32=bias, b_vec=bvec))
+    assert got.dtype == spec.out_dtype
     assert np.array_equal(got, want)
+    # most outputs lie inside the clip, so the requant itself is compared
+    assert (np.abs(want) < (1 << (spec.out_bits - 1)) - 1).mean() > 0.5
+    assert np.abs(want).max() > 0
+
+
+# (M, K, N, output bits, most grid steps): the benchmark cells' dense
+# projections (RoBERTa-base over 512 x 64 tokens, Granite-3-2B over
+# 8 x 1024), a decode step, one tp = 4 shard, and an odd N
+@pytest.mark.parametrize("m,k,n,out_bits,max_steps", [
+    (32768, 768, 768, 8, 64),          # RoBERTa q, k, v, o
+    (32768, 768, 3072, 16, 192),       # RoBERTa up
+    (32768, 3072, 768, 16, 64),        # RoBERTa down
+    (8192, 2048, 2048, 8, 32),         # Granite q, o
+    (8192, 2048, 512, 8, 16),          # Granite k, v
+    (8192, 2048, 8192, 16, 128),       # Granite w1, w3
+    (8192, 8192, 2048, 16, 128),       # Granite w2
+    (8, 2048, 8192, 16, None),         # Granite w1, decode
+    (256, 2048, 8192 // 4, 16, None),  # Granite w1, a tp = 4 shard
+    (8, 2048, 12292, 16, None),        # N with no 128-multiple divisor
+])
+def test_int8_matmul_block_rule(m, k, n, out_bits, max_steps):
+    from repro.analysis.contracts import check_launch, tpu_block_violations
+    from repro.kernels.int8_matmul import BLOCK_BYTES, matmul_blocks
+    bm, bn, bk = matmul_blocks(m, n, k, out_bits, True, True)
+    assert m % bm == 0 and n % bn == 0 and k % bk == 0
+    assert not tpu_block_violations("x8", (bm, bk), (m, k))
+    assert not tpu_block_violations("w8", (bk, bn), (k, n))
+    rep = check_launch("int8_matmul", m=m, n=n, k=k, bm=bm, bn=bn, bk=bk,
+                       out_bits=out_bits, has_bias=True, per_channel=True)
+    assert rep.ok and rep.vmem_bytes <= BLOCK_BYTES
+    if max_steps is not None:
+        assert math.prod(rep.grid) <= max_steps
 
 
 def test_int8_matmul_perchannel(rng):
