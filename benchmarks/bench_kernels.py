@@ -1,7 +1,7 @@
 """Kernel microbenchmarks: ref (jnp) path timing + Pallas interpret-mode
 validation cost, per kernel.  On real TPU the same harness times the
 compiled kernels; on CPU it documents the oracle path and asserts
-ref/pallas agreement as a by-product."""
+ref/pallas_fused agreement as a by-product."""
 import time
 
 import jax
@@ -11,7 +11,6 @@ import numpy as np
 from repro import ops
 from repro.core import attention as iattn
 from repro.core import norms
-from repro.core import softmax as ism
 from repro.core.dyadic import fit_dyadic
 from repro.ops import RequantSpec
 
@@ -39,12 +38,6 @@ def run():
     flops = 2 * m * k * n
     rows.append(("kernel_int8_matmul_us", round(us, 1),
                  f"{flops / us / 1e3:.1f} GOP/s (ref path, CPU)"))
-
-    sp = ism.make_isoftmax(3.5e-4, 128 * 127 * 127)
-    sc = jnp.asarray(rng.integers(-60000, 60000, (256, 1024)), jnp.int32)
-    f = jax.jit(lambda s: be.int_softmax(s, sp))
-    rows.append(("kernel_int_softmax_us", round(_t(f, sc), 1),
-                 "256x1024 rows"))
 
     d = 4096
     pl = norms.make_inorm(d, 2**-9, 1 << 13, 2 / 127, 8 / 127)

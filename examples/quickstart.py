@@ -56,7 +56,7 @@ def main():
     toks = jnp.asarray(batch["tokens"])
     # integer ops dispatch through the repro.ops backend registry; the
     # use_backend context (or REPRO_BACKEND=...) swaps implementations —
-    # "ref" / "pallas" / "pallas_tuned" / "pallas_fused", docs/OPS_API.md
+    # "ref" (the oracle) / "pallas_fused" (the TPU kernels), docs/OPS_API.md
     with rops.use_backend("ref"):
         logits_int = it.int_prefill(qp, {"tokens": toks}, plans, cfg)
     logits_f, _ = tf.forward_float(params, {"tokens": toks,

@@ -41,7 +41,7 @@ def main():
 
     qp, plans = convert.quantize_params(params, cfg)
     # the engine takes one OpSet handle at construction (repro.ops
-    # registry); swap "ref" for "pallas"/"pallas_tuned"/"pallas_fused"
+    # registry); swap "ref" for "pallas_fused" (the TPU kernels)
     # — or set the REPRO_BACKEND env var — without touching the model
     # code (docs/OPS_API.md lists the built-ins).  The default cache is
     # the paged pool; num_pages undersubscribes it so KV memory tracks

@@ -29,11 +29,7 @@ import dataclasses
 
 from repro.analysis.budgets import MAX_ROWSUM_LEN, MAX_SQ
 
-#: the online (one-pass) kernel's own row budget: its running rescale
-#: bounds the accumulator differently, see kernels/int_attention.py
-MAX_SKV_ONLINE = 1 << 16
-
-#: backend tiling-policy default (ops.backends.pallas_fused min_block)
+#: the tiling policy's smallest attention block (ops.backends.pallas_fused)
 MIN_BLOCK = 16
 
 
@@ -167,38 +163,35 @@ def check_blocks(op: str, blocks: dict) -> LaunchReport:
 
 # ---------------------------------------------------------------- policy --
 
-def can_tile(sq: int, skv: int, bq: int, bkv: int,
-             min_block: int = MIN_BLOCK) -> bool:
+def can_tile(sq: int, skv: int, bq: int, bkv: int) -> bool:
     """Fused prefill-attention tiling policy (pallas_fused backend)."""
     if skv > MAX_ROWSUM_LEN:
         return False          # exact row sum leaves the int32 budget
-    if sq < min_block or skv < min_block:
+    if sq < MIN_BLOCK or skv < MIN_BLOCK:
         return False          # tiny problem (e.g. decode): oracle wins
-    if bq < min_block or bkv < min_block:
+    if bq < MIN_BLOCK or bkv < MIN_BLOCK:
         return False          # no usable divisor (e.g. prime Sq)
     return True
 
 
-def can_tile_decode(sq: int, L: int, d: int, bkv: int,
-                    min_block: int = MIN_BLOCK) -> bool:
+def can_tile_decode(sq: int, L: int, d: int, bkv: int) -> bool:
     """Fused decode tiling policy (pallas_fused backend)."""
     if sq > MAX_SQ:
         return False          # scratch holds at most MAX_SQ query rows
     if L > MAX_ROWSUM_LEN:
         return False          # exact row sum leaves the int32 budget
-    if bkv < min_block:
+    if bkv < MIN_BLOCK:
         return False          # no usable cache-block divisor
     if d % 2:
         return False          # odd head dims: lane-hostile, oracle wins
     return True
 
 
-def can_tile_prefill(L: int, d: int, bq: int, bkv: int,
-                     min_block: int = MIN_BLOCK) -> bool:
+def can_tile_prefill(L: int, d: int, bq: int, bkv: int) -> bool:
     """Fused paged-prefill tiling policy (pallas_fused backend)."""
     if L > MAX_ROWSUM_LEN:
         return False          # exact row sum leaves the int32 budget
-    if bq < min_block or bkv < min_block:
+    if bq < MIN_BLOCK or bkv < MIN_BLOCK:
         return False          # tiny chunk / page: oracle wins
     if d % 2:
         return False          # odd head dims: lane-hostile, oracle wins
@@ -309,40 +302,28 @@ def can_fold_wo(rows, h, hkv, d, bkv, n_out, kv_d=None,
 
 
 def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
-                         out_bits=8, per_channel=False,
-                         min_block=MIN_BLOCK, online=False):
-    """The fused prefill launch (``online=True``: the ``pallas``
-    backend's one-pass kernel, which is off the chip path and not held
-    to the chip's block rule)."""
-    op = "int_attention_online" if online else "int_attention"
+                         out_bits=8, per_channel=False):
+    """The fused prefill launch."""
+    op = "int_attention"
     bq, bkv = min(bq, sq), min(bkv, skv)    # the kernels' own clamping
     reasons, policy = [], []
     _attn_common(h, hkv, reasons)
-    budget = MAX_SKV_ONLINE if online else MAX_ROWSUM_LEN
-    if skv > budget:
-        reasons.append(f"row-sum int32 budget: Skv <= {budget} "
+    if skv > MAX_ROWSUM_LEN:
+        reasons.append(f"row-sum int32 budget: Skv <= {MAX_ROWSUM_LEN} "
                        f"(got {skv})")
     if sq % bq or skv % bkv:
         reasons.append(f"blocks must divide (Sq,Skv)=({sq},{skv}): "
                        f"(bq,bkv)=({bq},{bkv})")
-    if not online:
-        reasons += check_blocks(op, _attn_blocks(
-            sq, bq, h, hkv, d, b, skv, bkv, d, per_channel, False,
-            0)).reasons
-    if not can_tile(sq, skv, bq, bkv, min_block):
+    reasons += check_blocks(op, _attn_blocks(
+        sq, bq, h, hkv, d, b, skv, bkv, d, per_channel, False, 0)).reasons
+    if not can_tile(sq, skv, bq, bkv):
         policy.append(f"tiling policy declines: sq={sq}, skv={skv}, "
-                      f"bq={bq}, bkv={bkv}, min_block={min_block}")
-    out_elem = 1 if (online or out_bits <= 8) else 4
+                      f"bq={bq}, bkv={bkv}, min_block={MIN_BLOCK}")
+    out_elem = 1 if out_bits <= 8 else 4
     vmem = _attn_vmem(bq, h, hkv, d, bkv, d, out_elem, per_channel,
                       False, 0)
-    if not online:
-        reasons += vmem_violations(op, vmem)
-    if sq % bq or skv % bkv:
-        grid = ()
-    elif online:
-        grid = (b, h, sq // bq, skv // bkv)
-    else:
-        grid = (b, sq // bq, 2, skv // bkv)
+    reasons += vmem_violations(op, vmem)
+    grid = () if sq % bq or skv % bkv else (b, sq // bq, 2, skv // bkv)
     return LaunchReport(
         op=op, ok=not reasons, fused=not (reasons or policy),
         reasons=tuple(reasons + policy), grid=grid,
@@ -352,8 +333,7 @@ def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
 def _check_int_decode_attention(b, sq, h, hkv, d, L=None, bkv=128,
                                 max_pages=0, page_size=0, out_bits=8,
                                 per_channel=False, fold=False, n_out=0,
-                                kv_pack=False, num_pages=0,
-                                min_block=MIN_BLOCK):
+                                kv_pack=False, num_pages=0):
     paged = page_size > 0
     if paged:
         L = max_pages * page_size
@@ -388,9 +368,9 @@ def _check_int_decode_attention(b, sq, h, hkv, d, L=None, bkv=128,
         sq, sq, h, hkv, d, max(num_pages, 1) if paged else b,
         page_size if paged else L, bkv, kv_d, per_channel, fold,
         n_out)).reasons
-    if not can_tile_decode(sq, L, d, bkv, min_block):
+    if not can_tile_decode(sq, L, d, bkv):
         policy.append(f"tiling policy declines: sq={sq}, L={L}, d={d}, "
-                      f"bkv={bkv}, min_block={min_block}")
+                      f"bkv={bkv}, min_block={MIN_BLOCK}")
     prefetch = [("valid_len", (b,))]
     if paged:
         prefetch.append(("pages", (b, max_pages)))
@@ -414,8 +394,7 @@ def _check_int_decode_attention(b, sq, h, hkv, d, L=None, bkv=128,
 def _check_int_paged_prefill(b, c, h, hkv, d, max_pages, page_size,
                              bq=128, bkv=128, out_bits=8,
                              per_channel=False, fold=False, n_out=0,
-                             kv_pack=False, num_pages=0,
-                             min_block=MIN_BLOCK):
+                             kv_pack=False, num_pages=0):
     L = max_pages * page_size
     reasons, policy = [], []
     _attn_common(h, hkv, reasons)
@@ -439,9 +418,9 @@ def _check_int_paged_prefill(b, c, h, hkv, d, max_pages, page_size,
     reasons += check_blocks("int_paged_prefill", _attn_blocks(
         c, bq, h, hkv, d, max(num_pages, 1), page_size, bkv, kv_d,
         per_channel, fold, n_out)).reasons
-    if not can_tile_prefill(L, d, bq, bkv, min_block):
+    if not can_tile_prefill(L, d, bq, bkv):
         policy.append(f"tiling policy declines: L={L}, d={d}, bq={bq}, "
-                      f"bkv={bkv}, min_block={min_block}")
+                      f"bkv={bkv}, min_block={MIN_BLOCK}")
     vmem = _attn_vmem(bq, h, hkv, d, bkv, kv_d,
                       1 if out_bits <= 8 else 4, per_channel, fold, n_out)
     reasons += vmem_violations("int_paged_prefill", vmem)
@@ -469,8 +448,8 @@ _CHECKS = {
 
 def check_launch(op: str, **params) -> LaunchReport:
     """Statically validate a kernel launch.  ``op`` is one of
-    ``int8_matmul`` / ``int_attention`` (pass ``online=True`` for the
-    one-pass kernel) / ``int_decode_attention`` / ``int_paged_prefill``;
+    ``int8_matmul`` / ``int8_matmul_packed`` / ``int_attention`` /
+    ``int_decode_attention`` / ``int_paged_prefill``;
     ``params`` are the launch shapes (see the per-kernel helpers).
     Never executes or imports jax — safe anywhere, including CI."""
     if op not in _CHECKS:
@@ -609,7 +588,7 @@ def require_request(prompt_len: int, max_new_tokens: int, cache_len: int,
 
 
 __all__ = [
-    "KernelContractError", "LaunchReport", "MAX_SKV_ONLINE", "MIN_BLOCK",
+    "KernelContractError", "LaunchReport", "MIN_BLOCK",
     "RequestInfeasible", "TPU_TILE", "can_tile", "can_tile_decode",
     "can_tile_prefill", "check_blocks", "check_launch", "check_request",
     "check_tp_launch", "fit_block", "require_launch", "require_request",

@@ -4,9 +4,10 @@
 (``quant.plans.build_layer_plans``) layer-kind by layer-kind, pushing
 worst-case :class:`~repro.analysis.ranges.IntRange` intervals through the
 transfer functions of every op in the ``repro.ops`` API — ``int8_matmul``,
-``int8_matmul_packed``, ``int_softmax``, ``int_gelu``, ``int_layernorm``,
+``int8_matmul_packed``, ``int_gelu``, ``int_layernorm``,
 ``int_attention``, ``int_decode_attention`` / ``int_paged_prefill``
-(both also at their int4-KV-page operand ranges) — at a given
+(both also at their int4-KV-page operand ranges), and the MoE gate's
+``i_softmax`` — at a given
 ``(seq_len, cache_len)``, and raises a typed, location-bearing
 :class:`~repro.analysis.budgets.BitBudgetError` if *any* intermediate of
 the exact integer computation could leave int32.  On success it returns

@@ -19,8 +19,8 @@ Scale plan (all frozen at design time):
   * probabilities leave as int8 at scale ``2^-7`` (ready for the P*V INT8
     matmul, Fig. 10's Requantization block).
 
-Attention does not take ``i_softmax``'s probabilities (the MoE gate and
-the standalone ``int_softmax`` op do).  A probability rounded to 2^-7
+Attention does not take ``i_softmax``'s probabilities (the MoE gate
+does).  A probability rounded to 2^-7
 before P·V is ``128 / L`` steps over ``L`` comparable keys: a 25% error
 per weight at 64 keys, and a row of zeros past 256.  Attention instead
 weights V by the exponentials themselves, rounded to 2^-7 *of the row

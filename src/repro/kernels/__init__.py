@@ -1,11 +1,11 @@
 """Pallas TPU kernels for the SwiftTron integer datapath.
 
-One module per op (``int8_matmul``, ``int_softmax``, ``int_gelu``,
-``int_layernorm``, ``int_attention`` — online softmax,
+One module per op (``int8_matmul``, ``int_gelu``, ``int_layernorm``,
 ``int_attention_fused`` — bit-exact attention+requant,
 ``int_decode_attention`` — fused ragged-cache decode with valid_len
-scalar-prefetch masking) plus the pure-jnp oracles (``ref``) they are
-tested against.  Models never import these directly: dispatch goes
+scalar-prefetch masking), the Shiftmax tile helpers those attention
+kernels share (``int_softmax``), plus the pure-jnp oracles (``ref``)
+they are tested against.  Models never import these directly: dispatch goes
 through the ``repro.ops`` backend registry (see docs/KERNELS.md for the
 contract, docs/OPS_API.md for the API).  The old ``ops.py``
 string-dispatch shims are removed; importing them raises with a pointer

@@ -180,7 +180,7 @@ def int8_matmul_pallas(x8, w8, bias32=None, dn: Dyadic = None,
     rule's (:func:`matmul_blocks`; :data:`PACKED_BLOCKS` for packed
     weights).  M/K/N must divide by the (clamped) block shapes, and the
     blocks must be chip-legal: bm a multiple of 8, bn and bk multiples
-    of 128, or the whole dim (``ops.backends.pallas._matmul_blocks``
+    of 128, or the whole dim (``ops.backends.pallas_fused._matmul_blocks``
     fits explicit ones).
 
     ``packed=True`` switches the weight operand to int4 nibbles:

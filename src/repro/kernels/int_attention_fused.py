@@ -6,11 +6,9 @@ One kernel launch computes the whole SwiftTron attention datapath
 streaming over KV blocks with int32 accumulators, so the O(Sq·Skv) score
 matrix never exists in HBM.
 
-Relation to ``int_attention.py`` (the ``pallas`` backend's kernel): that
-kernel keeps a one-pass *online* softmax whose running rescales round
-(±LSB vs the oracle).  This kernel instead makes **two streaming
-sweeps** over the KV blocks per query block and is *bit-exact* against
-the reference (``kernels.ref.ref_int_attention``):
+The kernel makes **two streaming sweeps** over the KV blocks per query
+block and is *bit-exact* against the reference
+(``kernels.ref.ref_int_attention``):
 
   sweep 0  row max       m = max_k(scores)                 (int32 compare)
   sweep 1  weights + AV  u8 = min(⌊e16(scores - m) + 2⁷⌋»8, 127);
@@ -19,8 +17,8 @@ the reference (``kernels.ref.ref_int_attention``):
 
 Each sweep recomputes the int8 Q·Kᵀ block product instead of storing it —
 the FlashAttention recompute-over-store trade, paid once more here to
-buy exactness (integer maxima and sums are associative; the online
-rescale of ``int_attention.py`` is not).  Normalising after P·V
+buy exactness (integer maxima and sums are associative; an online
+rescale is not).  Normalising after P·V
 (``core.softmax.normalize_rows``) instead of before it is what lets the
 weight sum and P·V share one sweep; the division is exact integer
 floor division, done here from a float32 estimate that one exact int32

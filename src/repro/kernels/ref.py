@@ -2,8 +2,8 @@
 
 Each ``ref_*`` mirrors the exact integer semantics of its kernel (same
 rounding, same staging) by delegating to ``repro.core`` — the kernels are
-*implementations* of the core numerics with explicit VMEM tiling, so kernel
-vs. ref mismatches beyond +-1 LSB are bugs.
+*implementations* of the core numerics with explicit VMEM tiling, so any
+kernel vs. ref mismatch is a bug.
 """
 from __future__ import annotations
 
@@ -11,7 +11,6 @@ import jax.numpy as jnp
 
 from repro.core import attention as iattn
 from repro.core import norms as inorms
-from repro.core import softmax as ism
 from repro.core.dyadic import Dyadic, apply_dyadic, clip_to_bits
 from repro.core.intmath import IGeluPlan, i_gelu
 
@@ -35,10 +34,6 @@ def ref_int8_matmul_perchannel(x8, w8, bias32, b_vec, c: int, pre: int,
         acc = acc + bias32[None, :]
     out = apply_dyadic_perchannel(acc, b_vec, c, pre, axis=-1)
     return clip_to_bits(out, out_bits)
-
-
-def ref_int_softmax(q_scores, plan: ism.ISoftmaxPlan, where=None):
-    return ism.i_softmax(q_scores, plan, axis=-1, where=where)
 
 
 def ref_int_gelu(q, plan: IGeluPlan, dn_out: Dyadic, out_bits: int = 8):

@@ -78,7 +78,7 @@ class ArchConfig:
 
     # numerics / execution
     dtype: str = "bfloat16"
-    kernel_backend: str = "ref"  # ref | pallas
+    kernel_backend: str = "ref"  # ref | pallas_fused
     remat: bool = True
     scan_layers: bool = True
     # quantization design scales (shared across layers; DESIGN.md §4)

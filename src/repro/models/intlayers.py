@@ -178,12 +178,11 @@ def int_attn_fwd(qp, x8, plans: qplans.AttnPlan, cfg: ArchConfig,
     k8 = shard(k8, "batch", "seq", "kv_heads", None)
     v8 = shard(v8, "batch", "seq", "kv_heads", None)
 
-    # the configured backend handles attention in every branch (the old
-    # code hardcoded the pallas/ref choice here); backends without a
-    # fused kernel fall back to chunked streaming on long sequences, and
-    # fused backends fall back internally on shapes their kernel can't
-    # tile (see ops.backends.pallas_fused).  The epilogue travels as a
-    # typed RequantSpec, same as the matmul call sites.
+    # the configured backend handles attention in every branch: backends
+    # without a fused kernel fall back to chunked streaming on long
+    # sequences, and fused backends fall back internally on shapes their
+    # kernel can't tile (see ops.backends.pallas_fused).  The epilogue
+    # travels as a typed RequantSpec, same as the matmul call sites.
     attn_backend = ops.backend_for("int_attention")
     if fuse_attention and attn_backend.fused_attention:
         o8 = ops.int_attention(q8, k8, v8, plans.attn,

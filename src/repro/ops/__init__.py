@@ -1,7 +1,8 @@
 """repro.ops — the unified operator API for the integer datapath.
 
-Single entry point for SwiftTron's six integer ops (INT8 matmul,
-Attention, Decode Attention, Softmax, GELU, LayerNorm):
+Single entry point for SwiftTron's integer ops (INT8 matmul, Attention,
+Decode Attention, GELU, LayerNorm; Shiftmax runs inside the attention
+ops):
 
   * :class:`RequantSpec` — typed, validated union of the three requant
     epilogue forms (per-tensor dyadic / per-channel vector / raw int32);
@@ -37,34 +38,18 @@ __all__ = [
     "register_backend", "resolve_ops", "unregister_backend",
     "use_backend", "DEFAULT_BACKEND", "ENV_VAR", "OP_NAMES",
     "REQUIRED_OPS", "PER_CHANNEL", "PER_TENSOR", "RAW",
-    "int8_matmul", "int8_matmul_packed", "int_softmax", "int_gelu",
+    "int8_matmul", "int8_matmul_packed", "int_gelu",
     "int_layernorm", "int_attention", "int_decode_attention",
     "int_paged_prefill",
 ]
 
 
 def _register_builtin_backends():
-    from repro.ops.backends.pallas import PallasBackend
     from repro.ops.backends.pallas_fused import PallasFusedBackend
     from repro.ops.backends.ref import RefBackend
     register_backend("ref", RefBackend(), overwrite=True)
-    register_backend("pallas", lambda: PallasBackend(), overwrite=True)
-    # single-launch attention+requant kernel, bit-exact vs the two-pass
-    # reference — see docs/KERNELS.md
-    register_backend("pallas_fused", lambda: PallasFusedBackend(),
-                     overwrite=True)
-    # tuned tile profile: wider matmul K-blocks + deeper row-blocking for
-    # the elementwise kernels; exists to prove per-op backend config needs
-    # no model changes (swap via REPRO_BACKEND=pallas_tuned)
-    register_backend(
-        "pallas_tuned",
-        lambda: PallasBackend(name="pallas_tuned", blocks={
-            "int8_matmul": dict(bm=256, bn=256, bk=1024),
-            "int_attention": dict(bq=256, bkv=256),
-            "int_softmax": dict(block_rows=16),
-            "int_layernorm": dict(block_rows=16),
-            "int_gelu": dict(block=8192),
-        }), overwrite=True)
+    # the TPU kernels, bit-exact vs ref — see docs/KERNELS.md
+    register_backend("pallas_fused", PallasFusedBackend, overwrite=True)
 
 
 _register_builtin_backends()
@@ -81,10 +66,6 @@ def int8_matmul(x8, w8, spec, *, bias32=None, b_vec=None, ops=None, **opts):
 
 def int8_matmul_packed(x8, qw, spec, *, ops=None, **opts):
     return resolve_ops(ops).int8_matmul_packed(x8, qw, spec, **opts)
-
-
-def int_softmax(scores, plan, *, ops=None, **opts):
-    return resolve_ops(ops).int_softmax(scores, plan, **opts)
 
 
 def int_gelu(q, plan, dn_out, out_bits: int = 8, *, ops=None, **opts):

@@ -1,6 +1,5 @@
 """Built-in backend implementations (registered by ``repro.ops``)."""
 from repro.ops.backends.ref import RefBackend
-from repro.ops.backends.pallas import PallasBackend
 from repro.ops.backends.pallas_fused import PallasFusedBackend
 
-__all__ = ["RefBackend", "PallasBackend", "PallasFusedBackend"]
+__all__ = ["RefBackend", "PallasFusedBackend"]

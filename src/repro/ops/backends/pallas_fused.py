@@ -1,21 +1,21 @@
-"""`pallas_fused` backend: bit-exact fused attention+requant kernel.
+"""`pallas_fused` backend: the TPU kernels, interpret-mode on CPU.
 
-Everything except attention reuses the :class:`PallasBackend` kernels;
+Every op is bit-exact against the ``ref`` oracle.  ``int8_matmul``,
+``int_gelu`` and ``int_layernorm`` launch their kernels
+(``kernels.int8_matmul`` / ``int_gelu`` / ``int_layernorm``), each
+taking its blocks from the launch's shape unless the call names them.
 ``int_attention`` routes to ``kernels.int_attention_fused`` — one kernel
-launch for Q·Kᵀ → Shiftmax → P·V → requant, streaming over KV blocks —
-and is **bit-exact** against the two-pass reference
-(``kernels.ref.ref_int_attention``), unlike the ``pallas`` backend's
-one-pass online kernel (±LSB).  ``int_decode_attention`` routes to
-``kernels.int_decode_attention`` — the same fused datapath for the
-serving hot path (Sq ≤ 8 queries over a ragged KV cache, per-slot
-``valid_len`` as a scalar-prefetch operand, dead blocks skipped) —
-bit-exact against ``kernels.ref.ref_int_decode_attention``.  The
-backend additionally advertises the two optional decode capabilities
+launch for Q·Kᵀ → Shiftmax → P·V → requant, streaming over KV blocks.
+``int_decode_attention`` routes to ``kernels.int_decode_attention`` —
+the same fused datapath for the serving hot path (Sq ≤ 8 queries over a
+ragged KV cache, per-slot ``valid_len`` as a scalar-prefetch operand,
+dead blocks skipped).  The backend advertises every optional capability
 (docs/KERNELS.md): ``paged_decode`` (the page table rides as a second
 scalar-prefetch operand and KV blocks translate through it in the index
 map) and ``decode_wo_fold`` (the o-projection + its per-channel requant
-run as the launch's epilogue).  ``wo`` folds only while its whole
-``(H·D, N)`` block fits the chip's VMEM budget
+run as the launch's epilogue), their chunked-prefill twins, packed
+weights and KV pages, and tensor-parallel serving.  ``wo`` folds only
+while its whole ``(H·D, N)`` block fits the chip's VMEM budget
 (``analysis.contracts.can_fold_wo``); a wider projection runs after an
 unfolded launch, through the matmul kernel, with the same integers.
 
@@ -26,20 +26,28 @@ with identical numerics:
     chunked two-pass streaming formulation takes over (per-tensor
     epilogues only, which is all the model datapath uses at such
     lengths);
-  * awkward sequence lengths (no block divisor ≥ ``min_block`` — e.g. a
-    prime Sq) and tiny problems, where a grid of degenerate blocks would
-    be slower than the full-matrix oracle.
+  * awkward sequence lengths (no block divisor ≥
+    ``contracts.MIN_BLOCK`` — e.g. a prime Sq) and tiny problems, where
+    a grid of degenerate blocks would be slower than the full-matrix
+    oracle.
 
 See docs/KERNELS.md for the kernel contract this backend satisfies.
 """
 from __future__ import annotations
 
+from typing import Optional
+
+import jax.numpy as jnp
+
 from repro.analysis import contracts as _contracts
 from repro.analysis.budgets import MAX_ROWSUM_LEN as MAX_SKV
+from repro.analysis.contracts import fit_block as _fit_block
 from repro.kernels import ref as _ref
+from repro.kernels import resolve_interpret
+from repro.kernels.int8_matmul import PACKED_BLOCKS, int8_matmul_pallas
+from repro.kernels.int_gelu import int_gelu_pallas
+from repro.kernels.int_layernorm import int_layernorm_pallas
 from repro.ops import spec as _spec
-from repro.ops.backends.pallas import (PallasBackend, _fit_block,
-                                       _matmul_blocks)
 from repro.ops.paged import gather_pages as _gather
 
 # NOTE: the fused kernel modules (kernels.int_attention_fused /
@@ -50,7 +58,31 @@ from repro.ops.paged import gather_pages as _gather
 # imports a kernel before the ops package.
 
 
-class PallasFusedBackend(PallasBackend):
+def _matmul_blocks(opts: dict, m: int, n: int, k: int, packed=False):
+    """The call's requested matmul blocks fitted to chip-legal divisors:
+    rows a multiple of 8, lanes (bn, and bk — the x block's lane dim) a
+    multiple of 128, else the whole dim (``contracts.fit_block``).  A
+    dense block not requested stays None: the kernel takes it from the
+    launch's shape (``kernels.int8_matmul.matmul_blocks``).  Packed
+    weights default to ``PACKED_BLOCKS`` and pair nibbles along K, so bk
+    is fitted on K/2 pairs and doubled (its half is the packed block's
+    row dim)."""
+    want = PACKED_BLOCKS if packed else (None, None, None)
+    bm, bn, bk = (opts.pop(key, w) for key, w in zip(("bm", "bn", "bk"),
+                                                     want))
+    if bm is not None:
+        bm = _fit_block(bm, m, 8)
+    if bn is not None:
+        bn = _fit_block(bn, n, 128)
+    if packed:
+        bk = 2 * _fit_block(max(bk // 2, 1), k // 2, 64)
+    elif bk is not None:
+        bk = _fit_block(bk, k, 128)
+    return bm, bn, bk
+
+
+class PallasFusedBackend:
+    name = "pallas_fused"
     fused_attention = True
     fused_decode = True       # single-launch valid_len-masked decode kernel
     paged_decode = True       # consumes page-table KV pools directly
@@ -64,10 +96,50 @@ class PallasFusedBackend(PallasBackend):
     #   hkv/tp shapes; analysis.contracts.check_tp_launch is its
     #   offline twin)
 
-    def __init__(self, name: str = "pallas_fused", interpret=None,
-                 blocks=None, min_block: int = 16):
-        super().__init__(name, interpret=interpret, blocks=blocks)
-        self.min_block = min_block
+    def __init__(self, interpret: Optional[bool] = None):
+        self._interpret = interpret
+
+    def _interp(self) -> bool:
+        return resolve_interpret(self._interpret)
+
+    # ---------------------------------------------- matmul, GELU, norm --
+
+    def int8_matmul(self, x8, w8, spec, *, bias32=None, b_vec=None, **opts):
+        if spec.is_raw:
+            # no requant epilogue to fuse -> nothing for the kernel to
+            # add over XLA's int8 dot, and raw consumers (lm head,
+            # router, dt proj) often have odd N where divisor-fitted
+            # blocks would degenerate; keep the MXU dot
+            acc = jnp.dot(x8, w8, preferred_element_type=jnp.int32)
+            if bias32 is not None:
+                acc = acc + bias32[None, :]
+            return acc
+        m, k = x8.shape
+        n = w8.shape[-1]
+        bm, bn, bk = _matmul_blocks(opts, m, n, k)
+        if spec.kind == _spec.PER_TENSOR:
+            return int8_matmul_pallas(x8, w8, bias32, dn=spec.dn,
+                                      out_bits=spec.out_bits,
+                                      out_dtype=spec.out_dtype,
+                                      bm=bm, bn=bn, bk=bk,
+                                      interpret=self._interp(), **opts)
+        if b_vec is None:
+            raise ValueError("per-channel RequantSpec needs the b_vec "
+                             "multiplier vector (QuantLinearParams.b_mult)")
+        return int8_matmul_pallas(x8, w8, bias32, b_vec=b_vec, c=spec.c,
+                                  pre=spec.pre, out_bits=spec.out_bits,
+                                  out_dtype=spec.out_dtype,
+                                  bm=bm, bn=bn, bk=bk,
+                                  interpret=self._interp(), **opts)
+
+    def int_gelu(self, q, plan, dn_out, out_bits: int = 8, **opts):
+        return int_gelu_pallas(q, plan, dn_out, out_bits,
+                               interpret=self._interp(), **opts)
+
+    def int_layernorm(self, q, q_gamma, q_beta, plan, out_bits: int = 8,
+                      **opts):
+        return int_layernorm_pallas(q, q_gamma, q_beta, plan, out_bits,
+                                    interpret=self._interp(), **opts)
 
     # --------------------------------------------------- packed matmul --
 
@@ -85,13 +157,10 @@ class PallasFusedBackend(PallasBackend):
           dyadic epilogue, so the result is bit-exact vs the unpacked
           reference for every RequantSpec form.
         """
-        from repro.kernels.int8_matmul import int8_matmul_pallas
         from repro.ops.packed import msr4_correction
         from repro.core.dyadic import (apply_dyadic,
                                        apply_dyadic_perchannel,
                                        clip_to_bits)
-        import jax.numpy as jnp
-        opts = self._opts("int8_matmul", opts)
         qw = _spec.QuantLinearParams.of(qw)
         meta = qw.pack_meta
         m, k = x8.shape
@@ -140,7 +209,6 @@ class PallasFusedBackend(PallasBackend):
                       window: int = 0, out_bits: int = 8, requant=None,
                       b_vec=None, **opts):
         from repro.kernels.int_attention_fused import int_attention_fused
-        opts = self._opts("int_attention", opts)
         if requant is None:
             requant = _spec.RequantSpec.per_tensor(plan.dn_out, out_bits)
         sq, skv = q8.shape[1], k8.shape[1]
@@ -163,7 +231,6 @@ class PallasFusedBackend(PallasBackend):
                              wo_spec=None, kv_shifts=None, **opts):
         from repro.kernels.int_decode_attention import \
             int_decode_attention_fused
-        opts = self._opts("int_decode_attention", opts)
         if requant is None:
             requant = _spec.RequantSpec.per_tensor(plan.dn_out, out_bits)
         sq, d = q8.shape[1], q8.shape[3]
@@ -240,8 +307,6 @@ class PallasFusedBackend(PallasBackend):
         from repro.kernels.int_attention_fused import \
             int_paged_prefill_fused
         from repro.ops.paged import scatter_chunk
-        import jax.numpy as jnp
-        opts = self._opts("int_paged_prefill", opts)
         if requant is None:
             requant = _spec.RequantSpec.per_tensor(plan.dn_out, out_bits)
         c, d = q8.shape[1], q8.shape[3]
@@ -322,15 +387,9 @@ class PallasFusedBackend(PallasBackend):
     # the fused-vs-fallback tiling policy is owned declaratively by
     # repro.analysis.contracts so offline certification predicts the
     # exact same dispatch this backend takes
-
-    def _can_tile_prefill(self, L: int, d: int, bq: int, bkv: int) -> bool:
-        return _contracts.can_tile_prefill(L, d, bq, bkv, self.min_block)
-
-    def _can_tile_decode(self, sq: int, L: int, d: int, bkv: int) -> bool:
-        return _contracts.can_tile_decode(sq, L, d, bkv, self.min_block)
-
-    def _can_tile(self, sq: int, skv: int, bq: int, bkv: int) -> bool:
-        return _contracts.can_tile(sq, skv, bq, bkv, self.min_block)
+    _can_tile_prefill = staticmethod(_contracts.can_tile_prefill)
+    _can_tile_decode = staticmethod(_contracts.can_tile_decode)
+    _can_tile = staticmethod(_contracts.can_tile)
 
     def _two_pass_fallback(self, q8, k8, v8, plan, causal, window,
                            requant, b_vec):
@@ -344,7 +403,6 @@ class PallasFusedBackend(PallasBackend):
                     f"Skv={skv} needs the chunked streaming path, which "
                     "supports per-tensor requant only")
             from repro.core import attention as iattn
-            import jax.numpy as jnp
             h, hkv = q8.shape[2], k8.shape[2]
             if hkv != h:
                 k8 = jnp.repeat(k8, h // hkv, axis=2)

@@ -13,7 +13,7 @@ from repro.ops import spec as _spec
 
 class RefBackend:
     name = "ref"
-    fused_attention = False   # full-matrix oracle, not an online kernel
+    fused_attention = False   # full-matrix oracle, not a fused kernel
     fused_decode = False      # decode runs the full-matrix oracle too
     # no paged/wo-fold decode or chunked-prefill capabilities: OpSet
     # lowers all four operands (gather-into-contiguous / unfolded
@@ -41,10 +41,6 @@ class RefBackend:
         return _ref.ref_int8_matmul_perchannel(x8, w8, bias32, b_vec,
                                                spec.c, spec.pre,
                                                spec.out_bits)
-
-    def int_softmax(self, scores, plan, **opts):
-        return _ref.ref_int_softmax(scores, plan,
-                                    where=opts.get("where"))
 
     def int_gelu(self, q, plan, dn_out, out_bits: int = 8, **opts):
         return _ref.ref_int_gelu(q, plan, dn_out, out_bits)

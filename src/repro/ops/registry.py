@@ -1,8 +1,8 @@
 """Backend protocol, registry, and the OpSet dispatch handle.
 
-Every integer operator (INT8 matmul, attention, decode attention,
-softmax, GELU, LayerNorm) is implemented by a *backend* — an object with
-the six methods of :class:`Backend`.  Backends register under a name
+Every integer operator (INT8 matmul, attention, decode attention, GELU,
+LayerNorm) is implemented by a *backend* — an object with the five
+methods of :class:`Backend`.  Backends register under a name
 (``register_backend``) and models receive a resolved :class:`OpSet`
 handle once at construction instead of threading ``backend="ref"``
 strings through every call.
@@ -26,8 +26,8 @@ from typing import Any, Callable, Dict, Optional, Protocol, Union, \
 ENV_VAR = "REPRO_BACKEND"
 DEFAULT_BACKEND = "ref"
 
-# the six methods every backend MUST implement
-REQUIRED_OPS = ("int8_matmul", "int_softmax", "int_gelu", "int_layernorm",
+# the five methods every backend MUST implement
+REQUIRED_OPS = ("int8_matmul", "int_gelu", "int_layernorm",
                 "int_attention", "int_decode_attention")
 # ... plus ops that are pure capabilities: a backend advertising the
 # matching flag implements them natively, everyone else is served by an
@@ -38,7 +38,7 @@ OP_NAMES = REQUIRED_OPS + ("int_paged_prefill", "int8_matmul_packed")
 
 @runtime_checkable
 class Backend(Protocol):
-    """The six integer ops every backend implements.
+    """The five integer ops every backend implements.
 
     ``fused_attention`` advertises a single-kernel attention path (the
     model layer falls back to the streaming/chunked formulation when the
@@ -117,8 +117,6 @@ class Backend(Protocol):
     def int8_matmul(self, x8, w8, spec, *, bias32=None, b_vec=None,
                     **opts): ...
 
-    def int_softmax(self, scores, plan, **opts): ...
-
     def int_gelu(self, q, plan, dn_out, out_bits: int = 8, **opts): ...
 
     def int_layernorm(self, q, q_gamma, q_beta, plan, out_bits: int = 8,
@@ -132,7 +130,7 @@ class Backend(Protocol):
 
 
 def _is_backend(obj) -> bool:
-    """A backend *instance*: the six required ops plus
+    """A backend *instance*: the five required ops plus
     name/fused_attention (capability ops like ``int_paged_prefill`` are
     optional — OpSet lowers them for backends without the flag).
 
@@ -245,10 +243,6 @@ class OpSet:
     def int8_matmul(self, x8, w8, spec, *, bias32=None, b_vec=None, **opts):
         return self.backend_for("int8_matmul").int8_matmul(
             x8, w8, spec, bias32=bias32, b_vec=b_vec, **opts)
-
-    def int_softmax(self, scores, plan, **opts):
-        return self.backend_for("int_softmax").int_softmax(
-            scores, plan, **opts)
 
     def int_gelu(self, q, plan, dn_out, out_bits: int = 8, **opts):
         return self.backend_for("int_gelu").int_gelu(
@@ -481,10 +475,10 @@ def current_opset() -> Optional[OpSet]:
 
 @contextlib.contextmanager
 def use_backend(spec, **per_op):
-    """Scope a backend choice: ``with use_backend("pallas"): ...``.
+    """Scope a backend choice: ``with use_backend("pallas_fused"): ...``.
 
     ``per_op`` overrides route individual ops elsewhere, e.g.
-    ``use_backend("ref", int_attention="pallas")``.
+    ``use_backend("ref", int_attention="pallas_fused")``.
     """
     ops = OpSet(_as_backend(spec),
                 per_op or None) if not isinstance(spec, OpSet) \

@@ -27,8 +27,6 @@ KERNELS = (
     "int8_matmul",              # kernels/int8_matmul.py
     "int_norm",                 # kernels/int_layernorm.py
     "int_gelu",                 # kernels/int_gelu.py
-    "int_softmax",              # kernels/int_softmax.py
-    "int_attention",            # kernels/int_attention.py (two-pass)
     "int_attention_fused",      # kernels/int_attention_fused.py
     "int_paged_prefill_fused",  # kernels/int_attention_fused.py
     "int_decode_attention",     # kernels/int_decode_attention.py

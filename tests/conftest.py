@@ -13,3 +13,20 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def gathered_backend():
+    """A registered ``ref`` backend that does not advertise
+    ``tp_serving``: a ``tp > 1`` engine over it takes the exact
+    single-device gather lowering.  Yields its registered name."""
+    from repro.ops import register_backend, unregister_backend
+    from repro.ops.backends.ref import RefBackend
+
+    class GatheredRef(RefBackend):
+        name = "ref_gathered"
+        tp_serving = False
+
+    register_backend(GatheredRef.name, GatheredRef(), overwrite=True)
+    yield GatheredRef.name
+    unregister_backend(GatheredRef.name)

@@ -145,10 +145,10 @@ def test_check_launch_attention_budget():
                        h=4, hkv=4, d=64)
     assert not rep.ok
     assert any("row-sum int32 budget" in r for r in rep.reasons)
-    # the online kernel has a bigger budget: same shape passes
-    rep = check_launch("int_attention", b=1, sq=128, skv=1 << 16,
-                       h=4, hkv=4, d=64, online=True)
-    assert rep.ok
+    # the longest row inside the budget passes
+    rep = check_launch("int_attention", b=1, sq=128, skv=MAX_ROWSUM_LEN,
+                       h=4, hkv=4, d=64)
+    assert rep.ok and rep.fused
 
 
 def test_check_launch_policy_decline_is_not_an_error():
@@ -245,11 +245,11 @@ def test_backend_policy_delegates_to_contracts():
              (128, MAX_ROWSUM_LEN + 128, 128, 128)]
     for sq, skv, bq, bkv in cases:
         assert be._can_tile(sq, skv, bq, bkv) == \
-            contracts.can_tile(sq, skv, bq, bkv, be.min_block)
+            contracts.can_tile(sq, skv, bq, bkv)
     assert be._can_tile_decode(1, 256, 64, 128) == \
-        contracts.can_tile_decode(1, 256, 64, 128, be.min_block)
+        contracts.can_tile_decode(1, 256, 64, 128)
     assert be._can_tile_prefill(512, 64, 128, 64) == \
-        contracts.can_tile_prefill(512, 64, 128, 64, be.min_block)
+        contracts.can_tile_prefill(512, 64, 128, 64)
 
 
 def test_kernel_wrapper_raises_contract_error():
@@ -270,7 +270,7 @@ def test_lint_rr001_kernel_import_scoping():
     assert [f.code for f in bad] == ["RR001"]
     assert "backend registry" in bad[0].message
     # allowed scopes: kernels themselves and the backends
-    assert lint.lint_source(src, "src/repro/ops/backends/pallas.py") == []
+    assert lint.lint_source(src, "src/repro/ops/backends/pallas_fused.py") == []
     assert lint.lint_source(src, "src/repro/kernels/ref.py") == []
     # tests/ and benchmarks/ are out of scope entirely
     assert lint.lint_source(src, "tests/test_kernels.py") == []

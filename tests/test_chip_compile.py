@@ -20,7 +20,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import attention as iattn
 from repro.core import intmath, norms
-from repro.core import softmax as ism
 from repro.core.dyadic import fit_dyadic
 from repro.ops import RequantSpec
 
@@ -86,7 +85,7 @@ MATMUL_FORMS = {
 @pytest.mark.parametrize("form", list(MATMUL_FORMS))
 def test_int8_matmul_compiles(one_chip, form):
     from repro.kernels.int8_matmul import int8_matmul_pallas
-    from repro.ops.backends.pallas import _matmul_blocks
+    from repro.ops.backends.pallas_fused import _matmul_blocks
     m, k, n, out_bits = MATMUL_FORMS[form]
     out_dtype = I8 if out_bits <= 8 else I32
 
@@ -287,10 +286,3 @@ def test_layernorm_compiles(one_chip, lead, d, subtract_mean):
                                     interpret=False)
     _compile(one_chip, fn, *shapes)
 
-
-def test_softmax_compiles(one_chip):
-    from repro.kernels.int_softmax import int_softmax_pallas
-    plan = ism.make_isoftmax(s_score=3.5e-4, qmax_score=128 * 127 * 127)
-    _compile(one_chip,
-             lambda s: int_softmax_pallas(s, plan, interpret=False),
-             ((BATCH, R_H, 256, 256), I32))

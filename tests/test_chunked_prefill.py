@@ -107,8 +107,8 @@ def test_paged_prefill_scatter_routes_overflow_to_null_page(rng):
 
 def test_paged_prefill_dispatch_parity_all_backends(rng):
     """OpSet capability negotiation: pallas_fused consumes the table
-    natively, ref/pallas get the exact scatter/gather lowering — all
-    three return identical attention outputs AND identical pool bytes."""
+    natively, ref gets the exact scatter/gather lowering — both return
+    identical attention outputs AND identical pool bytes."""
     b, h, hkv, d, ps, num_pages, c = 2, 2, 1, 16, 16, 7, 16
     plan = _plan(d)
     q8 = _chunk(rng, b, c, h, d)
@@ -118,7 +118,7 @@ def test_paged_prefill_dispatch_parity_all_backends(rng):
     base = jnp.asarray([5, 32], jnp.int32)
     want, kpr, vpr = kref.ref_int_paged_prefill(
         q8, kn, vn, kp, vp, plan, base, pages, ps)
-    for name in ("ref", "pallas", "pallas_fused"):
+    for name in ("ref", "pallas_fused"):
         o, kk, vv = resolve_ops(name).int_paged_prefill(
             q8, kn, vn, kp, vp, plan, base, pages, ps)
         assert np.array_equal(np.asarray(o), np.asarray(want)), name

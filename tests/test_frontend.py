@@ -154,14 +154,14 @@ def test_streaming_is_incremental(setup):
     assert states[0] == "active"            # mid-generation, not done
 
 
-def test_frontend_tp2_streams_match_solo(setup):
+def test_frontend_tp2_streams_match_solo(setup, gathered_backend):
     """Lifecycle ops compose with a tp=2 engine: frontend streams match
     the unsharded solo reference — sharded over ``ref`` where the
     process has two devices (the 4-device CI matrix), else the exact
     gathered lowering of a backend without ``tp_serving`` (a sharded
     engine on one device is refused, not silently gathered)."""
     prompts = _prompts(6)
-    ops = "ref" if jax.device_count() >= 2 else "pallas"
+    ops = "ref" if jax.device_count() >= 2 else gathered_backend
 
     async def main():
         fe = _frontend(setup, tp=2, ops=ops, max_pending=8)

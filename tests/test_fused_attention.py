@@ -2,10 +2,9 @@
 
 The contract under test (docs/KERNELS.md): the single-launch fused
 attention+requant kernel is *bit-exact* against
-``kernels.ref.ref_int_attention`` — not ±LSB like the online-softmax
-``pallas`` kernel — for every RequantSpec epilogue form, on self- and
-cross-attention, across head dims / sequence lengths / masks, including
-shapes that force the backend's two-pass fallback.
+``kernels.ref.ref_int_attention`` for every RequantSpec epilogue form,
+on self- and cross-attention, across head dims / sequence lengths /
+masks, including shapes that force the backend's two-pass fallback.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -100,7 +99,7 @@ def test_untileable_shapes_fall_back_exactly(rng, sq, skv):
 
 
 def _fit2(sq, skv):
-    from repro.ops.backends.pallas import _fit_block
+    from repro.analysis.contracts import fit_block as _fit_block
     return _fit_block(128, sq), _fit_block(128, skv)
 
 

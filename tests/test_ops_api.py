@@ -1,5 +1,10 @@
 """The unified operator API: RequantSpec forms, backend registry dispatch,
-ref<->pallas parity across the ops, and the removed deprecation shims."""
+ref<->pallas_fused parity across the ops, and the removed deprecation
+shims."""
+import os
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,7 +12,6 @@ import pytest
 from repro import ops
 from repro.core import attention as iattn
 from repro.core import intmath, norms
-from repro.core import softmax as ism
 from repro.core.dyadic import fit_dyadic
 from repro.ops import (OpSet, QuantLinearParams, RequantSpec, get_backend,
                        register_backend, resolve_ops, unregister_backend,
@@ -134,8 +138,8 @@ def test_resolve_ops_cfg_and_errors(monkeypatch):
     from repro.configs.registry import get_config
     from repro.models import model as M
     cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32",
-                          kernel_backend="pallas")
-    assert resolve_ops(None, cfg).name == "pallas"
+                          kernel_backend="pallas_fused")
+    assert resolve_ops(None, cfg).name == "pallas_fused"
     with pytest.raises(KeyError):
         get_backend("no-such-backend")
     with pytest.raises(KeyError):
@@ -177,13 +181,13 @@ def test_fuse_attention_false_uses_exact_oracle(rng):
     attn_qp = convert._q_attn(attn_qp, plans.attn)
     x8 = jnp.asarray(rng.integers(-127, 128, (1, 16, cfg.d_model)),
                      jnp.int8)
-    unfused = il.int_attn_fwd(attn_qp, x8, plans.attn, cfg, ops="pallas",
-                              fuse_attention=False)
+    unfused = il.int_attn_fwd(attn_qp, x8, plans.attn, cfg,
+                              ops="pallas_fused", fuse_attention=False)
     exact = il.int_attn_fwd(attn_qp, x8, plans.attn, cfg, ops="ref")
     assert np.array_equal(np.asarray(unfused), np.asarray(exact))
 
 
-# -------------------------------------------- ref<->pallas parity ---------
+# -------------------------------------- ref<->pallas_fused parity ---------
 
 @pytest.mark.parametrize("form", ["per_tensor", "per_channel", "raw"])
 def test_matmul_parity_all_requant_forms(rng, form):
@@ -201,12 +205,12 @@ def test_matmul_parity_all_requant_forms(rng, form):
     else:
         spec = RequantSpec.raw()
     got = {}
-    for name in ("ref", "pallas"):
+    for name in ("ref", "pallas_fused"):
         got[name] = np.asarray(resolve_ops(name).int8_matmul(
             x, w, spec, bias32=bias, b_vec=b_vec))
-    assert np.array_equal(got["ref"], got["pallas"])
+    assert np.array_equal(got["ref"], got["pallas_fused"])
     if form == "raw":
-        assert got["pallas"].dtype == np.int32
+        assert got["pallas_fused"].dtype == np.int32
         # raw == plain int32 accumulator + bias
         acc = np.asarray(x, np.int64) @ np.asarray(w, np.int64) \
             + np.asarray(bias)[None, :]
@@ -214,12 +218,9 @@ def test_matmul_parity_all_requant_forms(rng, form):
 
 
 def test_all_five_ops_parity_through_registry(rng):
-    """Every op of the Backend protocol: ref vs pallas via the registry."""
-    ref, pall = resolve_ops("ref"), resolve_ops("pallas")
-
-    sp = ism.make_isoftmax(s_score=3.5e-4, qmax_score=128 * 127 * 127)
-    sc = jnp.asarray(rng.integers(-60000, 60000, (16, 128)), jnp.int32)
-    assert np.array_equal(ref.int_softmax(sc, sp), pall.int_softmax(sc, sp))
+    """The prefill ops of the Backend protocol, ref vs pallas_fused via
+    the registry: bit-identical."""
+    ref, pall = resolve_ops("ref"), resolve_ops("pallas_fused")
 
     gplan = intmath.make_igelu(16 / 1024, 1024)
     gdn = fit_dyadic(gplan.s_out / (8 / 127), 1024 * 2 * gplan.q_one)
@@ -240,25 +241,37 @@ def test_all_five_ops_parity_through_registry(rng):
                              127), jnp.int8)
     k8 = jnp.asarray(np.clip(rng.normal(0, 40, (1, 128, 2, 64)), -127,
                              127), jnp.int8)
-    a_ref = np.asarray(ref.int_attention(q8, k8, k8, plan), int)
-    a_pl = np.asarray(pall.int_attention(q8, k8, k8, plan, bq=64,
-                                         bkv=64), int)
-    # online-softmax rescaling vs exact normalisation: +-LSB tolerance
-    assert np.abs(a_ref - a_pl).max() <= 4
+    a_ref = np.asarray(ref.int_attention(q8, k8, k8, plan))
+    a_pl = np.asarray(pall.int_attention(q8, k8, k8, plan, bq=64, bkv=64))
+    assert np.array_equal(a_ref, a_pl)
 
     mm = _tiny_matmul(ref), _tiny_matmul(pall)
     assert np.array_equal(np.asarray(mm[0]), np.asarray(mm[1]))
 
 
-def test_pallas_tuned_backend_parity(rng):
-    """Third registered backend (per-op tiled blocks) needs no model code."""
+def test_explicit_matmul_blocks_parity(rng):
+    """Blocks named by the call win over the shape rule: fitted to
+    chip-legal divisors of odd shapes, bit-identical to ref."""
     x = jnp.asarray(rng.integers(-127, 128, (96, 192)), jnp.int8)   # odd
     w = jnp.asarray(rng.integers(-127, 128, (192, 48)), jnp.int8)   # shapes
     spec = RequantSpec.per_channel(c=28, pre=7)
     bv = jnp.asarray(rng.integers(1000, 30000, (48,)), jnp.int32)
     a = resolve_ops("ref").int8_matmul(x, w, spec, b_vec=bv)
-    b = resolve_ops("pallas_tuned").int8_matmul(x, w, spec, b_vec=bv)
+    b = resolve_ops("pallas_fused").int8_matmul(x, w, spec, b_vec=bv,
+                                                bm=32, bn=256, bk=1024)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_builtin_backends():
+    """A fresh process registers the oracle and the one Pallas backend."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [src] + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    out = subprocess.run(
+        [sys.executable, "-c", "from repro import ops; "
+         "print(ops.available_backends())"],
+        capture_output=True, text=True, check=True, env=env)
+    assert out.stdout.strip() == "['pallas_fused', 'ref']"
 
 
 # ------------------------------------------------- deprecation shims ------

@@ -27,7 +27,7 @@ from repro.ops import QuantLinearParams, RequantSpec, packed, resolve_ops
 from repro.ops.paged import gather_pages
 from repro.quant.pack import pack_int4, pack_linear, pack_msr4, pack_tree
 
-BACKENDS = ("ref", "pallas", "pallas_fused")
+BACKENDS = ("ref", "pallas_fused")
 
 
 # ------------------------------------------------- pack -> unpack ---------

@@ -78,8 +78,8 @@ def test_paged_kernel_matches_gathered_oracle_ragged(rng, sq):
 
 def test_paged_dispatch_parity_all_backends(rng):
     """OpSet capability negotiation: pallas_fused consumes the table
-    natively, ref/pallas get the exact gather lowering — all three
-    return identical integers."""
+    natively, ref gets the exact gather lowering — both return identical
+    integers."""
     b, h, hkv, d, ps, m, num_pages = 3, 2, 1, 16, 16, 3, 7
     plan = _plan(d)
     q8 = jnp.asarray(rng.integers(-127, 128, (b, 1, h, d)), jnp.int8)
@@ -89,11 +89,10 @@ def test_paged_dispatch_parity_all_backends(rng):
         jnp.int32)
     vl = jnp.asarray([1, 17, 48], jnp.int32)
     outs = {}
-    for name in ("ref", "pallas", "pallas_fused"):
+    for name in ("ref", "pallas_fused"):
         ops = resolve_ops(name)
         outs[name] = np.asarray(ops.int_decode_attention(
             q8, kp, vp, plan, vl, pages=pages, page_size=ps))
-    assert np.array_equal(outs["ref"], outs["pallas"])
     assert np.array_equal(outs["ref"], outs["pallas_fused"])
     want = np.asarray(kref.ref_int_paged_decode_attention(
         q8, kp, vp, plan, vl, pages, ps))

@@ -8,9 +8,10 @@ in here:
 
   * token-stream parity sharded-vs-single-device for tp ∈ {2, 4} across
     backend × cache_mode × chunked/streaming prefill;
-  * the ``tp_serving`` capability negotiation — the plain pallas backend
-    does not advertise it, so a tp=4 engine over it takes the exact
-    single-device gather lowering (same tokens, no mesh, no API change);
+  * the ``tp_serving`` capability negotiation — a backend that does not
+    advertise it (a ``ref`` subclass registered in the child) makes a
+    tp=4 engine take the exact single-device gather lowering (same
+    tokens, no mesh, no API change);
   * ``describe()`` reporting mesh geometry and per-device KV bytes;
   * mesh geometry in the compiled-step cache key: tp=2 / tp=4 / unsharded
     engines land distinct entries, same-mesh engines share one;
@@ -76,13 +77,24 @@ for ops, mode in MATRIX:
         assert d["fold_wo"] is False        # requant-rounds-once
         assert f"tp={tp}:sharded" in eng.describe_str()
 
-# the pallas backend does not advertise tp_serving: a tp=4 engine over
-# it takes the exact single-device gather lowering — same API, same
-# tokens, no mesh
-b_pal, _ = serve(1, "pallas", **MODES["chunked"])
-got, eng = serve(4, "pallas", **MODES["chunked"])
-assert eng.describe()["tp"]["mode"] == "gathered"
-assert eng.mesh is None and got == b_pal
+# a backend that does not advertise tp_serving: a tp=4 engine over it
+# takes the exact single-device gather lowering — same API, same tokens,
+# no mesh
+from repro.ops import register_backend, unregister_backend
+from repro.ops.backends.ref import RefBackend
+
+class GatheredRef(RefBackend):
+    name = "ref_gathered"
+    tp_serving = False
+
+register_backend(GatheredRef.name, GatheredRef())
+try:
+    b_gat, _ = serve(1, GatheredRef.name, **MODES["chunked"])
+    got, eng = serve(4, GatheredRef.name, **MODES["chunked"])
+    assert eng.describe()["tp"]["mode"] == "gathered"
+    assert eng.mesh is None and got == b_gat
+finally:
+    unregister_backend(GatheredRef.name)
 
 # mesh geometry is part of the compiled-step cache key: sharded tp=2 /
 # tp=4 engines and every unsharded engine (tp=1 AND the gathered

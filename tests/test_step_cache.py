@@ -71,16 +71,16 @@ def test_step_key_carries_mesh_element(setup):
     assert ("mesh", 1) in eng._step_key("decode")
 
 
-def test_gathered_tp_fallback_shares_tp1_executable(setup):
+def test_gathered_tp_fallback_shares_tp1_executable(setup,
+                                                    gathered_backend):
     """A tp=2 engine in gathered-fallback mode traces the identical
     single-device program, so it must hit the tp=1 entry (its key
-    carries the same ("mesh", 1) element).  Pinned to the pallas
-    backend — it never advertises ``tp_serving``, so the engine gathers
-    regardless of how many devices this process happens to have (the
-    multi-device CI job runs this file under a forced 4-device
-    count)."""
-    e1 = _engine(setup, ops="pallas")
-    e2 = _engine(setup, ops="pallas", tp=2)
+    carries the same ("mesh", 1) element).  Pinned to a backend that
+    never advertises ``tp_serving``, so the engine gathers regardless
+    of how many devices this process happens to have (the multi-device
+    CI job runs this file under a forced 4-device count)."""
+    e1 = _engine(setup, ops=gathered_backend)
+    e2 = _engine(setup, ops=gathered_backend, tp=2)
     assert e2.describe()["tp"]["mode"] == "gathered"
     assert ("mesh", 1) in e2._step_key("decode")
     assert e1._decode is e2._decode

@@ -37,18 +37,18 @@ def test_cell_matrix_accounting():
     assert skipped == 7          # 7 documented long_500k skips
 
 
-def test_kernel_backend_flag():
-    """Models run with the Pallas kernel backend (interpret mode on CPU)."""
+def test_kernel_backend_flag(monkeypatch):
+    """Models run with the Pallas kernel backend (interpret mode on CPU)
+    that ``kernel_backend`` names, bit-identical to the ref oracle."""
+    from repro import ops
+    monkeypatch.delenv(ops.ENV_VAR, raising=False)
     cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32",
-                          kernel_backend="pallas")
+                          kernel_backend="pallas_fused")
     params = tf.init_params(jax.random.key(0), cfg)
     batch = {"tokens": jax.random.randint(jax.random.key(1), (1, 16), 0,
                                           cfg.vocab)}
     qp, plans = convert.quantize_params(params, cfg)
+    assert ops.resolve_ops(None, cfg).name == "pallas_fused"
     ref_logits = it.int_prefill(qp, batch, plans, cfg, ops="ref")
-    pl_logits = it.int_prefill(qp, batch, plans, cfg, ops="pallas")
-    corr = np.corrcoef(np.asarray(ref_logits).ravel(),
-                       np.asarray(pl_logits).ravel())[0, 1]
-    # fused online-softmax attention differs from the two-pass ref by
-    # +-2 int8 LSB per layer (see test_fused_attention_kernel)
-    assert corr > 0.99
+    pl_logits = it.int_prefill(qp, batch, plans, cfg)
+    assert np.array_equal(np.asarray(ref_logits), np.asarray(pl_logits))

@@ -18,7 +18,6 @@ from repro import trace_names
 from repro.configs.registry import get_config
 from repro.core import attention as iattn
 from repro.core import intmath, norms
-from repro.core import softmax as ism
 from repro.core.dyadic import fit_dyadic
 from repro.models import inttransformer as it
 from repro.models import model as M
@@ -70,22 +69,6 @@ def _int_gelu():
             ((16, 128), I32))
 
 
-def _int_softmax():
-    from repro.kernels.int_softmax import int_softmax_pallas
-    plan = ism.make_isoftmax(s_score=3.5e-4, qmax_score=128 * 127 * 127)
-    return (lambda s: int_softmax_pallas(s, plan, interpret=True),
-            ((8, 128), I32))
-
-
-def _int_attention():
-    from repro.kernels.int_attention import int_attention_pallas
-    plan = _attn_plan(32)
-    qkv = ((1, 64, 2, 32), I8)
-    return (lambda q, k, v: int_attention_pallas(q, k, v, plan, bq=64,
-                                                 bkv=64, interpret=True),
-            qkv, qkv, qkv)
-
-
 def _int_attention_fused():
     from repro.kernels.int_attention_fused import int_attention_fused
     plan = _attn_plan(32)
@@ -115,8 +98,7 @@ def _int_decode_attention():
 
 
 LAUNCHES = {"int8_matmul": _int8_matmul, "int_norm": _int_norm,
-            "int_gelu": _int_gelu, "int_softmax": _int_softmax,
-            "int_attention": _int_attention,
+            "int_gelu": _int_gelu,
             "int_attention_fused": _int_attention_fused,
             "int_paged_prefill_fused": _int_paged_prefill_fused,
             "int_decode_attention": _int_decode_attention}
@@ -144,22 +126,52 @@ def test_names_outside_their_table_are_refused(table, check):
 
 # ------------------------------------------------------- model scopes --
 
-#: the sublayers of a pre-LN encoder (RoBERTa-shaped: LayerNorm, GELU)
-ENCODER_SCOPES = ("embed", "norm1", "attn", "residual", "norm2", "ffn.up",
-                  "ffn.act", "ffn.down", "final_norm", "head")
+#: the sublayers of a pre-norm block, both benchmark configurations
+MODEL_SCOPES = ("embed", "norm1", "attn", "residual", "norm2", "ffn.up",
+                "ffn.act", "ffn.down", "final_norm", "head")
+
+#: the launches ``pallas_fused`` makes per configuration, each with the
+#: sublayers it may run in.  RoBERTa: LayerNorm, i-GELU, bidirectional
+#: attention.  Granite: RMSNorm, RoPE, GQA, causal attention, and i-SiLU
+#: under ``ffn.act`` with no kernel of its own; its raw head is XLA's dot.
+LAUNCH_OWNERS = {
+    "roberta-base": {"int_norm": {"norm1", "norm2", "final_norm"},
+                     "int_gelu": {"ffn.act"},
+                     "int_attention_fused": {"attn"},
+                     "int8_matmul": {"attn", "ffn.up", "ffn.down",
+                                     "head"}},
+    "granite-3-2b": {"int_norm": {"norm1", "norm2", "final_norm"},
+                     "int_attention_fused": {"attn"},
+                     "int8_matmul": {"attn", "ffn.up", "ffn.down"}},
+}
 
 
 @pytest.fixture(scope="module")
-def tiny_encoder():
-    cfg = M.reduce_config(get_config("roberta-base"), dtype="float32")
-    params = tf.init_params(jax.random.key(0), cfg)
-    qp, plans = convert.quantize_params(params, cfg)
-    return cfg, qp, plans
+def tiny_models():
+    built = {}
+
+    def build(arch):
+        if arch not in built:
+            cfg = M.reduce_config(get_config(arch), dtype="float32")
+            params = tf.init_params(jax.random.key(0), cfg)
+            built[arch] = (cfg,) + convert.quantize_params(params, cfg)
+        return built[arch]
+    return build
 
 
-@pytest.mark.parametrize("ops", ["pallas_fused", "ref"])
-def test_int_prefill_names_every_sublayer_and_launch(tiny_encoder, ops):
-    cfg, qp, plans = tiny_encoder
+@pytest.mark.parametrize("arch,ops", [
+    pytest.param("roberta-base", "pallas_fused", id="pallas_fused"),
+    pytest.param("roberta-base", "ref", id="ref"),
+    pytest.param("granite-3-2b", "pallas_fused",
+                 id="granite-3-2b-pallas_fused"),
+    pytest.param("granite-3-2b", "ref", id="granite-3-2b-ref"),
+])
+def test_int_prefill_names_every_sublayer_and_launch(tiny_models, arch,
+                                                     ops):
+    cfg, qp, plans = tiny_models(arch)
+    if arch == "granite-3-2b":
+        assert cfg.pos == "rope" and cfg.is_causal
+        assert cfg.n_kv_heads < cfg.n_heads
 
     def encode(q, t):
         return it.int_prefill(q, {"tokens": t}, plans, cfg, ops=ops)
@@ -167,20 +179,17 @@ def test_int_prefill_names_every_sublayer_and_launch(tiny_encoder, ops):
         qp, jax.ShapeDtypeStruct((2, 16), I32)).compile().as_text()
     paths = re.findall(r'op_name="([^"]*)"', text)
     comps = {c for p in paths for c in p.split("/")}
-    assert set(ENCODER_SCOPES) <= comps
+    assert set(MODEL_SCOPES) <= comps
     assert "int_prefill" in comps
     launches = [_kernels_in(p) for p in paths if _kernels_in(p)]
     assert all(len(k) == 1 for k in launches)
     found = {k[0] for k in launches}
+    owner = LAUNCH_OWNERS[arch]
     if ops == "ref":
         assert not found
     else:
-        assert {"int8_matmul", "int_norm", "int_gelu",
-                "int_attention_fused"} <= found
-    # each launch runs inside the sublayer that owns it
-    owner = {"int_norm": {"norm1", "norm2", "final_norm"},
-             "int_gelu": {"ffn.act"}, "int_attention_fused": {"attn"},
-             "int8_matmul": {"attn", "ffn.up", "ffn.down", "head"}}
+        assert found == set(owner)
+    # each launch runs inside a sublayer that owns it
     for p in paths:
         k = _kernels_in(p)
         if k:
