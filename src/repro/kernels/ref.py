@@ -37,8 +37,10 @@ def ref_int8_matmul_perchannel(x8, w8, bias32, b_vec, c: int, pre: int,
 
 
 def ref_int_gelu(q, plan: IGeluPlan, dn_out: Dyadic, out_bits: int = 8):
-    return clip_to_bits(apply_dyadic(i_gelu(q.astype(jnp.int32), plan),
-                                     dn_out), out_bits)
+    """int8 where ``out_bits <= 8``, int32 otherwise."""
+    out = clip_to_bits(apply_dyadic(i_gelu(q.astype(jnp.int32), plan),
+                                    dn_out), out_bits)
+    return out.astype(jnp.int8) if out_bits <= 8 else out
 
 
 def ref_int_layernorm(q, q_gamma, q_beta, plan: inorms.INormPlan,

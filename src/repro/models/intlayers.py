@@ -458,9 +458,8 @@ def int_ffn_fwd(qp, x8, plans: qplans.FfnPlan, cfg: ArchConfig,
             prod = a8 * h3                                  # s8 * s10
             h = clip_to_bits(plans.dn_gate(prod), 8).astype(jnp.int8)
         else:
-            a = ops.int_gelu(h1, plans.act_gelu.gelu,
+            h = ops.int_gelu(h1, plans.act_gelu.gelu,
                              plans.act_gelu.dn_out, out_bits=8)
-            h = a.astype(jnp.int8)
     with trace_names.scope("ffn.down"):
         h = shard(h, "batch", "seq", "ffn")
         return shard(int_linear(h, qp["w2"], plans.down, ops),
@@ -513,8 +512,7 @@ def int_moe_fwd(qp, x8, plans: qplans.MoePlan, cfg: ArchConfig,
         h = clip_to_bits(plans.expert.dn_gate(a8 * h3), 8).astype(jnp.int8)
     else:
         h = ops.int_gelu(h1, plans.expert.act_gelu.gelu,
-                         plans.expert.act_gelu.dn_out,
-                         out_bits=8).astype(jnp.int8)
+                         plans.expert.act_gelu.dn_out, out_bits=8)
     y8 = int_expert_linear(h, qp["w2"], plans.expert.down)   # s_res int32
     y8 = shard(y8, "batch", "experts", None, "embed")
 

@@ -258,13 +258,19 @@ def test_wide_wo_compiles_unfolded(one_chip, launch):
 
 # --------------------------------------------------------- elementwise --
 
-def test_gelu_compiles(one_chip):
+@pytest.mark.parametrize("shape", [
+    (BATCH * 256, R_FF),
+    (512 * 64, R_FF),      # the encoder benchmark's batch
+    (16, R_FF),            # a decode step: one block
+], ids=["3072", "roberta-512x64", "decode16"])
+def test_gelu_compiles(one_chip, shape):
+    """Under the shape-chosen layout and row block (``gelu_layout``)."""
     from repro.kernels.int_gelu import int_gelu_pallas
     plan = intmath.make_igelu(16 / 1024, 1024)
     dn = fit_dyadic(plan.s_out / (8 / 127), 1024 * 2 * plan.q_one)
     _compile(one_chip,
              lambda q: int_gelu_pallas(q, plan, dn, interpret=False),
-             ((BATCH * 256, R_FF), I32))
+             (shape, I32))
 
 
 @pytest.mark.parametrize("lead,d,subtract_mean", [

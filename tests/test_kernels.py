@@ -138,15 +138,38 @@ def test_exp16_tile_matches_core(rows, rowlen):
     assert len(np.unique(want)) > 1000
 
 
-@pytest.mark.parametrize("shape", [(512,), (3, 7, 512), (16, 1024)])
+@pytest.mark.parametrize("shape", [
+    (512,), (3, 7, 512), (16, 1024),
+    (2, 64, 3072),      # the up projection's own layout
+    (16, 3072),         # a decode step: one block
+    (5, 1536),          # rows not a multiple of 8
+    (3, 7, 100),        # last dim not a multiple of 128: flattened
+])
 def test_int_gelu_kernel(rng, shape):
     s = 16 / 1024
     plan = intmath.make_igelu(s, 1024)
     dn = fit_dyadic(plan.s_out / (8 / 127), 1024 * 2 * plan.q_one)
     q = rng.integers(-1024, 1025, shape).astype(np.int32)
-    got = np.asarray(PALLAS.int_gelu(jnp.asarray(q), plan, dn))
-    want = np.asarray(REF.int_gelu(jnp.asarray(q), plan, dn))
-    assert np.array_equal(got, want)
+    got = PALLAS.int_gelu(jnp.asarray(q), plan, dn, out_bits=8)
+    want = REF.int_gelu(jnp.asarray(q), plan, dn, out_bits=8)
+    assert got.dtype == want.dtype == jnp.int8
+    assert got.shape == shape
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_int_gelu_layout():
+    """The view and row block ``int_gelu`` launches with, per shape."""
+    from repro.kernels.int_gelu import gelu_layout
+    # RoBERTa-base's up projection at 512 x 64 tokens: (br, n) blocks of
+    # the natural layout, 256 grid steps a layer
+    rows, lanes, br, natural = gelu_layout((512, 64, 3072))
+    assert (rows, lanes, br, natural) == (32768, 3072, 128, True)
+    assert rows // br == 256
+    assert gelu_layout((32768, 3072)) == (32768, 3072, 128, True)
+    assert gelu_layout((16, 3072)) == (16, 3072, 16, True)
+    assert gelu_layout((5, 1536)) == (5, 1536, 5, True)
+    # 2,100 elements padded to three (8, 128) tiles, one block
+    assert gelu_layout((3, 7, 100)) == (24, 128, 24, False)
 
 
 def _norm_case(rng, d, subtract_mean, qmax=1024):
