@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """Chip smoke test: the integer serving path, compiled, on one TPU chip.
 
-    python chip_smoke.py           # one chip: RoBERTa-base + Granite-3-2B
+    python chip_smoke.py           # one chip: RoBERTa-base, Granite-3-2B,
+                                   # Moonlight-16B-A3B cut to 3 layers
+    python chip_smoke.py --only moonlight   # one of those phases
     python chip_smoke.py --tp 4    # four chips: tp=4 sharded vs tp=1
 
 Runs the ``pallas_fused`` path through the entry points a user calls, at
@@ -21,7 +23,10 @@ the ``ref`` integer oracle on the same chip:
      token came from bit-identical to ``ops="ref"`` (a per-request
      digest), fused decode and native paged prefill in ``describe()``,
      and the offline certifier predicts no fallback path at these
-     shapes.
+     shapes;
+  4. Moonlight-16B-A3B cut to 3 layers at its published widths:
+     ``int_prefill`` on 4 x 256 tokens, logits and MoE counters
+     bit-identical to ``ops="ref"`` (``moonlight_phase``).
 
 ``--tp 4`` runs only the sharded phase: Granite-3-2B served at tp=4
 (head-sharded over four devices) and at tp=1 on the first device, in
@@ -53,6 +58,10 @@ sys.path.insert(0, os.path.join(HERE, "src"))
 ARCH = "granite-3-2b"
 GRANITE = dict(requests=8, prompt_len=512, shared_prefix=256, max_new=32,
                batch=8, cache_len=1024, page_size=128, prefill_chunk=256)
+
+
+#: the one-chip phases, in the order they run
+PHASES = ("roberta", "granite", "moonlight")
 
 
 def device_check(need: int = 1) -> dict:
@@ -142,6 +151,52 @@ def roberta_phase(cfg, batch: int = 8, seq: int = 256, seed: int = 0):
           "(information; float32 reference of the pre-LN graph the "
           "integer path runs)", flush=True)
     return {"bit_exact": True, "corr": corr}
+
+
+def moonlight_phase(cfg=None, batch: int = 4, seq: int = 256,
+                    seed: int = 0):
+    """Moonlight-16B-A3B cut to 3 layers (the dense first layer and two
+    MoE layers) at its published widths, 16 of its 64 experts held and
+    a 32,768-row vocabulary: ``int_prefill`` with the MoE counters under
+    pallas_fused vs ref — latent attention (192-wide queries and keys,
+    128-wide values), the sigmoid router, the grouped expert matmul —
+    bit-identical logits and counters."""
+    import jax
+    import numpy as np
+    from repro.configs.registry import get_config
+    from repro.models import inttransformer as it
+    from repro.models import transformer as tf
+    from repro.quant import convert
+
+    if cfg is None:
+        cfg = dataclasses.replace(get_config("moonlight-16b-a3b"),
+                                  num_layers=3, experts_held=16,
+                                  vocab=32768)
+    _no_fallback(cfg, seq, seq, 64, seq)
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    params = tf.init_params(jax.random.key(seed), cfg, served=True)
+    tokens = jax.random.randint(jax.random.key(seed + 1), (batch, seq), 1,
+                                cfg.vocab)
+    qp, plans = convert.quantize_params(params, cfg)
+    del params
+    out = {}
+    for ops in ("pallas_fused", "ref"):
+        fn = jax.jit(lambda q, t, ops=ops: it.int_prefill(
+            q, {"tokens": t}, plans, cfg, ops=ops, return_stats=True))
+        t0 = time.time()
+        logits, stats = jax.block_until_ready(fn(qp, tokens))
+        out[ops] = (np.asarray(logits), np.asarray(stats["moe_rows"]))
+        print(f"[moonlight] ops={ops}: compile + run {time.time() - t0:.1f}s"
+              f"; rows on held experts {out[ops][1].tolist()}", flush=True)
+    (got, rows), (want, rows_ref) = out["pallas_fused"], out["ref"]
+    assert np.isfinite(got).all()
+    assert (got.max(axis=1) > got.min(axis=1)).all(), "constant logits"
+    assert np.array_equal(rows, rows_ref) and (rows > 0).all()
+    assert np.array_equal(got, want), \
+        f"pallas_fused logits differ from ref in {(got != want).sum()} places"
+    print("[moonlight] pallas_fused logits and counters bit-identical to "
+          "ref: True", flush=True)
+    return {"bit_exact": True, "moe_rows": rows.tolist()}
 
 
 def _serve_args(ops: str, t: dict, tp: int = 1,
@@ -242,6 +297,8 @@ def main(argv=None):
     ap.add_argument("--tp", type=int, default=1,
                     help="run only the tp-way sharded phase (needs tp "
                          "devices); 1 = the one-chip phases")
+    ap.add_argument("--only", choices=PHASES, default=None,
+                    help="run one of the one-chip phases")
     args = ap.parse_args(argv)
     dev = device_check(need=args.tp)
     from repro.configs.registry import get_config
@@ -250,8 +307,11 @@ def main(argv=None):
     if args.tp > 1:
         tp_phase(args.tp)
     else:
-        roberta_phase(get_config("roberta-base"))
-        granite_phase()
+        phases = {"roberta": lambda: roberta_phase(
+                      get_config("roberta-base")),
+                  "granite": granite_phase, "moonlight": moonlight_phase}
+        for name in [args.only] if args.only else PHASES:
+            phases[name]()
     print(json.dumps({"ok": True, "device": dev}))
 
 

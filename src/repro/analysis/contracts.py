@@ -234,6 +234,36 @@ def _check_int8_matmul(m, n, k, bm=128, bn=128, bk=512, out_bits=8,
         blocks={"bm": bm, "bn": bn, "bk": bk}, vmem_bytes=vmem)
 
 
+def _check_int_grouped_matmul(m, k, n, groups, tm=512, tn=512, out_bits=8,
+                              per_channel=True):
+    """The grouped (MoE expert) matmul: row tiles of ``tm`` over the
+    sorted rows, column tiles of ``tn``, K whole; one visit per (row
+    tile, group) pair with live rows (``kernels.int_grouped_matmul``)."""
+    tm, tn = min(tm, m), min(tn, n)
+    reasons = []
+    if m % tm or n % tn:
+        reasons.append("blocks must divide the problem: "
+                       f"(M,N)=({m},{n}) %% (tm,tn)=({tm},{tn})")
+    blocks = {"x8": ((tm, k), (m, k)), "w": ((1, k, tn), (groups, k, n)),
+              "out": ((tm, tn), (m, n))}
+    if per_channel:
+        blocks["b_vec"] = ((1, 1, tn), (groups, 1, n))
+    reasons += check_blocks("int_grouped_matmul", blocks).reasons
+    out_elem = 1 if out_bits <= 8 else 4
+    blk = tm * k + k * tn + tm * tn * out_elem + (4 * tn if per_channel
+                                                  else 0)
+    vmem = 2 * blk + tm * tn * 4                    # + the int32 tile
+    reasons += vmem_violations("int_grouped_matmul", vmem)
+    visits = m // tm + groups - 1 if not reasons else 0
+    return LaunchReport(
+        op="int_grouped_matmul", ok=not reasons, fused=not reasons,
+        reasons=tuple(reasons),
+        grid=(n // tn, visits) if not reasons else (),
+        blocks={"tm": tm, "tn": tn}, vmem_bytes=vmem,
+        scalar_prefetch=tuple((a, (visits,)) for a in
+                              ("gid", "tid", "lo", "hi", "first")))
+
+
 def _check_int8_matmul_packed(m, n, k, bm=128, bn=128, bk=512, out_bits=8,
                               has_bias=False, per_channel=False):
     return _check_int8_matmul(m, n, k, bm=bm, bn=bn, bk=bk,
@@ -247,44 +277,50 @@ def _attn_common(h, hkv, reasons):
 
 
 def _attn_blocks(q_rows, rows, h, hkv, d, kv_lead, kv_rows, bkv, kv_d,
-                 per_channel, fold, n_out):
+                 per_channel, fold, n_out, dv=None):
     """The fused attention launches' operand blocks (all heads per
     block; head-major output), as ``check_blocks`` input.  ``q_rows`` /
     ``rows``: the query axis length and its block; ``kv_lead`` /
     ``kv_rows``: the cache's leading dims (batch or pages, length or
-    page size)."""
+    page size); ``dv``: the value head width where it differs from the
+    query/key width ``d`` (prefill launches)."""
+    dv = d if dv is None else dv
+    kv_dv = kv_d if dv == d else dv
     blocks = {
         "q": ((1, rows, h, d), (1, q_rows, h, d)),
         "k": ((1, bkv, hkv, kv_d), (kv_lead, kv_rows, hkv, kv_d)),
-        "v": ((1, bkv, hkv, kv_d), (kv_lead, kv_rows, hkv, kv_d)),
+        "v": ((1, bkv, hkv, kv_dv), (kv_lead, kv_rows, hkv, kv_dv)),
     }
     if per_channel:
-        blocks["b_vec"] = ((h, d), (h, d))
+        blocks["b_vec"] = ((h, dv), (h, dv))
     if fold:
         blocks["wo"] = ((h * d, n_out), (h * d, n_out))
         blocks["wo_vec"] = ((1, n_out), (1, n_out))
         blocks["out"] = ((1, rows, n_out), (1, q_rows, n_out))
     else:
-        blocks["out"] = ((1, h, rows, d), (1, h, q_rows, d))
+        blocks["out"] = ((1, h, rows, dv), (1, h, q_rows, dv))
     return blocks
 
 
 def _attn_vmem(rows, h, hkv, d, bkv, kv_d, out_elem, per_channel, fold,
-               n_out):
+               n_out, dv=None):
     """Per-step VMEM estimate of a fused attention launch: q / k / v /
     output blocks and epilogue operands, each double-buffered, plus the
-    per-head m/s/acc scratch.  A folded ``wo`` block counts four times:
+    per-head m/s/acc scratch (``dv``: the value head width, as in
+    :func:`_attn_blocks`).  A folded ``wo`` block counts four times:
     two pipeline buffers, and the per-head ``(D, N)`` slab reads stage
     about as much again (the topology compile of a 16 MiB block runs
     out of VMEM where a 50 MiB matmul does not)."""
-    blk = rows * h * d + 2 * bkv * hkv * kv_d       # q + k + v blocks
+    dv = d if dv is None else dv
+    kv_dv = kv_d if dv == d else dv
+    blk = rows * h * d + bkv * hkv * (kv_d + kv_dv)  # q + k + v blocks
     if per_channel:
-        blk += h * d * 4
+        blk += h * dv * 4
     if fold:
         blk += 2 * n_out * 4 + rows * n_out * out_elem  # vectors + out
     else:
-        blk += h * rows * d * out_elem
-    vmem = 2 * blk + 2 * h * rows * 4 + h * rows * d * 4  # + m/s/acc
+        blk += h * rows * dv * out_elem
+    vmem = 2 * blk + 2 * h * rows * 4 + h * rows * dv * 4  # + m/s/acc
     if fold:
         vmem += 4 * h * d * n_out + rows * n_out * 4  # wo + accumulator
     return vmem
@@ -302,8 +338,9 @@ def can_fold_wo(rows, h, hkv, d, bkv, n_out, kv_d=None,
 
 
 def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
-                         out_bits=8, per_channel=False):
-    """The fused prefill launch."""
+                         out_bits=8, per_channel=False, dv=None):
+    """The fused prefill launch (``dv``: the value head width, where it
+    differs from the query/key width ``d``)."""
     op = "int_attention"
     bq, bkv = min(bq, sq), min(bkv, skv)    # the kernels' own clamping
     reasons, policy = [], []
@@ -315,13 +352,14 @@ def _check_int_attention(b, sq, skv, h, hkv, d, bq=128, bkv=128,
         reasons.append(f"blocks must divide (Sq,Skv)=({sq},{skv}): "
                        f"(bq,bkv)=({bq},{bkv})")
     reasons += check_blocks(op, _attn_blocks(
-        sq, bq, h, hkv, d, b, skv, bkv, d, per_channel, False, 0)).reasons
+        sq, bq, h, hkv, d, b, skv, bkv, d, per_channel, False, 0,
+        dv=dv)).reasons
     if not can_tile(sq, skv, bq, bkv):
         policy.append(f"tiling policy declines: sq={sq}, skv={skv}, "
                       f"bq={bq}, bkv={bkv}, min_block={MIN_BLOCK}")
     out_elem = 1 if out_bits <= 8 else 4
     vmem = _attn_vmem(bq, h, hkv, d, bkv, d, out_elem, per_channel,
-                      False, 0)
+                      False, 0, dv=dv)
     reasons += vmem_violations(op, vmem)
     grid = () if sq % bq or skv % bkv else (b, sq // bq, 2, skv // bkv)
     return LaunchReport(
@@ -440,6 +478,7 @@ def _check_int_paged_prefill(b, c, h, hkv, d, max_pages, page_size,
 _CHECKS = {
     "int8_matmul": _check_int8_matmul,
     "int8_matmul_packed": _check_int8_matmul_packed,
+    "int_grouped_matmul": _check_int_grouped_matmul,
     "int_attention": _check_int_attention,
     "int_decode_attention": _check_int_decode_attention,
     "int_paged_prefill": _check_int_paged_prefill,
@@ -448,7 +487,8 @@ _CHECKS = {
 
 def check_launch(op: str, **params) -> LaunchReport:
     """Statically validate a kernel launch.  ``op`` is one of
-    ``int8_matmul`` / ``int8_matmul_packed`` / ``int_attention`` /
+    ``int8_matmul`` / ``int8_matmul_packed`` / ``int_grouped_matmul`` /
+    ``int_attention`` /
     ``int_decode_attention`` / ``int_paged_prefill``;
     ``params`` are the launch shapes (see the per-kernel helpers).
     Never executes or imports jax — safe anywhere, including CI."""

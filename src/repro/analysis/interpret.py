@@ -40,6 +40,7 @@ from repro.analysis.ranges import (INT4, INT4_KV, INT8, MSR4_DELTA_MAX,
                                    t_dyadic, t_dyadic_perchannel, t_gelu,
                                    t_layernorm, t_matmul_acc,
                                    t_requant_spec, t_silu, t_softmax)
+from repro.core.softmax import PROB_SHIFT
 
 #: nominal folded-bias bound at accumulator scale: |B| <= 4 real units
 #: over s_act8 * S_W8 ~ 1e-3 -> ~4e3; listed as an assumption per config
@@ -176,6 +177,28 @@ def check_int_softmax(sm, score: IntRange, rowlen: int, layer: str,
         t("row sum", rowlen * (1 << 15))
     return out, OpReport(op, layer, t.worst,
                          path="exact" if exact else "streaming")
+
+
+def check_sigmoid_gate(gp, logits: IntRange, top_k: int, layer: str,
+                       op: str = "sigmoid_gate"):
+    """A sigmoid router's gates (``models.intlayers.int_route``): the
+    logits' dyadic onto the i-sigmoid grid, i-exp there, the 2^-15
+    sigmoids plus a correction bias on the same grid, the routed-scaling
+    numerators ``sig * rs_b`` and the rounded division of each, shifted
+    onto the gate grid, by the sum of the k."""
+    from repro.core.activations import SIG_FRAC
+    t = _Track()
+    q = t("sigmoid input", t_dyadic(logits, gp.sig.dn_in,
+                                   what="i-sigmoid input dyadic", op=op,
+                                   layer=layer))
+    t("i-exp polynomial", gp.sig.iexp.q_b ** 2 + gp.sig.iexp.q_c)
+    one = 1 << SIG_FRAC
+    t("biased score", static_check(2 * one, "biased sigmoid score",
+                                   op=op, layer=layer))
+    num = static_check(one * gp.rs_b << (gp.gate_frac - gp.rs_frac),
+                       "gate numerator", op=op, layer=layer)
+    t("gate numerator", num + top_k * one // 2)
+    return OpReport(op, layer, max(t.worst, q.qmax), note="sigmoid router")
 
 
 def check_int_gelu(ffn, x: IntRange, layer: str, op: str = "int_gelu"):
@@ -416,6 +439,14 @@ def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
     if plans.attn is not None:
         _, rep = check_int8_matmul(plans.attn.qkv, "attn.qkv")
         ops.append(rep)
+        if cfg.is_mla:
+            # the latent to the residual grid, its RMSNorm, its expansion
+            for name in ("kv_a", "kv_b"):
+                _, rep = check_int8_matmul(getattr(plans.attn, name),
+                                           f"attn.{name}")
+                ops.append(rep)
+            _, rep = check_int_layernorm(plans.attn.kv_norm, "attn.kv_norm")
+            ops.append(rep)
         _, rep = check_int8_matmul_packed(plans.attn.qkv,
                                           "attn.qkv[msr4]")
         ops.append(rep)
@@ -427,7 +458,7 @@ def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
         static_check(y.qmax, "attention residual write",
                      budget=cfg.qmax_res, op="int8_matmul",
                      layer="attn.out")
-        if cfg.is_causal:
+        if cfg.is_causal and not cfg.is_mla:     # MLA runs prefill only
             _, rep = check_int_decode_attention(
                 plans.attn.attn, cache_len, "attn.decode",
                 page_size=page_size)
@@ -477,17 +508,27 @@ def certify_config(cfg, seq_len: int = 4096, cache_len: int = 32768,
     if plans.moe is not None:
         logits, rep = check_int8_matmul(plans.moe.router, "moe.router")
         ops.append(rep)
-        _, rep = check_int_softmax(plans.moe.gate_sm, logits,
-                                   cfg.n_experts, "moe.gate")
+        gp = plans.moe.gate
+        if cfg.router == "sigmoid":
+            rep = check_sigmoid_gate(gp, logits, cfg.top_k, "moe.gate")
+            # a gate is at most the routed scaling (each sigmoid <= their
+            # sum)
+            gate, shift = (gp.rs_b << (gp.gate_frac - gp.rs_frac)) + 1, \
+                gp.gate_frac
+        else:
+            _, rep = check_int_softmax(gp, logits, cfg.n_experts,
+                                       "moe.gate")
+            gate, shift = 1 << PROB_SHIFT, PROB_SHIFT
         ops.append(rep)
-        _check_ffn(plans.moe.expert, "moe.expert", ops)
+        y = _check_ffn(plans.moe.expert, "moe.expert", ops)
         if plans.moe.shared is not None:
             _check_ffn(plans.moe.shared, "moe.shared", ops)
-        combine = IntRange.symmetric(
-            static_check(cfg.top_k * 127 * 127, "moe combine sum",
-                         op="int8_matmul", layer="moe.combine"))
-        t_dyadic(combine, plans.moe.dn_combine, what="moe combine dyadic",
-                 op="int8_matmul", layer="moe.combine")
+        # the combine (``int_moe_fwd``): each pair's expert output times
+        # its gate, shifted off the gate grid, summed over the k
+        prod = static_check(y.qmax * gate, "moe combine product",
+                            op="int8_matmul", layer="moe.combine")
+        static_check(cfg.top_k * ((prod >> shift) + 1), "moe combine sum",
+                     op="int8_matmul", layer="moe.combine")
     if plans.mamba is not None:
         _check_mamba(plans.mamba, cfg, ops, assumptions)
     _, rep = check_int8_matmul(
@@ -504,5 +545,5 @@ __all__ = [
     "check_int8_matmul", "check_int8_matmul_packed", "check_int_attention",
     "check_int_decode_attention", "check_int_gelu",
     "check_int_layernorm", "check_int_paged_prefill",
-    "check_int_softmax", "check_requant_spec",
+    "check_int_softmax", "check_requant_spec", "check_sigmoid_gate",
 ]

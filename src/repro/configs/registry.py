@@ -1,17 +1,18 @@
-"""--arch <id> registry: the 10 assigned architectures + the paper's own
-evaluation models (RoBERTa-base/large, DeiT-S)."""
+"""--arch <id> registry: the 10 assigned architectures, the paper's own
+evaluation models (RoBERTa-base/large, DeiT-S) and Moonlight-16B-A3B
+(MLA and sigmoid-routed experts, prefill only)."""
 from repro.configs import (codeqwen1_5_7b, deit_s, granite_3_2b,
                            h2o_danube_3_4b, jamba_v0_1_52b, llama3_8b,
                            llama3_2_vision_90b, mamba2_130m,
-                           qwen2_moe_a2_7b, qwen3_moe_235b_a22b,
-                           roberta_base, roberta_large,
-                           seamless_m4t_large_v2)
+                           moonlight_16b_a3b, qwen2_moe_a2_7b,
+                           qwen3_moe_235b_a22b, roberta_base,
+                           roberta_large, seamless_m4t_large_v2)
 
 ARCHS = {m.CONFIG.name: m.CONFIG for m in (
     h2o_danube_3_4b, llama3_8b, codeqwen1_5_7b, granite_3_2b,
     seamless_m4t_large_v2, llama3_2_vision_90b, qwen3_moe_235b_a22b,
     qwen2_moe_a2_7b, mamba2_130m, jamba_v0_1_52b,
-    roberta_base, roberta_large, deit_s,
+    roberta_base, roberta_large, deit_s, moonlight_16b_a3b,
 )}
 
 ASSIGNED = [

@@ -191,11 +191,11 @@ def i_attention_chunked(q8, k8, v8, plan: IAttnPlan, chunk: int,
     them.  One division per row at the end.
     """
     b, sq, h, d = q8.shape
-    sk = k8.shape[1]
+    sk, dv = k8.shape[1], v8.shape[-1]
     assert sk % chunk == 0, (sk, chunk)
     n_chunks = sk // chunk
     k8c = k8.reshape(b, n_chunks, chunk, h, d).transpose(1, 0, 2, 3, 4)
-    v8c = v8.reshape(b, n_chunks, chunk, h, d).transpose(1, 0, 2, 3, 4)
+    v8c = v8.reshape(b, n_chunks, chunk, h, dv).transpose(1, 0, 2, 3, 4)
 
     def chunk_mask_dyn(ci):
         if not causal and window <= 0:
@@ -223,7 +223,7 @@ def i_attention_chunked(q8, k8, v8, plan: IAttnPlan, chunk: int,
         return _add_pv(run, _pv(u, vc), chunk, sk > MAX_PV_KEYS), None
 
     zero = jnp.zeros((b, sq, h, 1), jnp.int32)
-    run0 = (jnp.zeros((b, sq, h, d), jnp.int32), zero, zero)
+    run0 = (jnp.zeros((b, sq, h, dv), jnp.int32), zero, zero)
     (acc, s, _), _ = jax.lax.scan(pass2, run0,
                                   (jnp.arange(n_chunks), k8c, v8c))
     return clip_to_bits(plan.dn_out(ism.normalize_rows(acc, s)), out_bits)

@@ -86,7 +86,7 @@ def _streaming_attn_body(phase, kv_step, n_kv, live, blk_live, m_ref,
     once per block (``k_of(g)`` / ``v_of(g)``, an int8 ``(bkv, D)``
     tile) and serves its ``group`` query heads (``q_of(h)``, ``(rows,
     D)``).  Scratch is per head: ``m_ref`` / ``s_ref`` ``(H, rows, 1)``,
-    ``acc_ref`` ``(H, rows, D)``.  On the last step ``emit(h, tile)``
+    ``acc_ref`` ``(H, rows, Dv)``, the value width.  On the last step ``emit(h, tile)``
     receives each head's requantized tile, then ``finish()`` runs.
 
     Everything downstream of mask construction is identical between the
@@ -333,17 +333,21 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
                         b_vec=None, causal: bool = True, window: int = 0,
                         bq: int = 128, bkv: int = 128, out_bits: int = 8,
                         interpret: Optional[bool] = None):
-    """q8: (B, Sq, H, D) int8; k8/v8: (B, Skv, Hkv, D) int8 (GQA: Hkv | H).
+    """q8: (B, Sq, H, D) int8; k8: (B, Skv, Hkv, D) int8; v8: (B, Skv,
+    Hkv, Dv) int8 (GQA: Hkv | H; MLA's values are narrower than its
+    queries and keys, ``Dv`` 128 against ``D`` 192).
 
     ``requant``: a :class:`RequantSpec` for the epilogue (default: the
     plan's per-tensor ``dn_out``); ``b_vec``: int32 per-channel
-    multipliers, shape (H*D,) or (H, D), required iff per-channel.
+    multipliers, shape (H*Dv,) or (H, Dv), required iff per-channel.
 
     Grid ``(B, Sq/bq, 2, Skv/bkv)``: every step takes all heads of a
-    ``(bq, H, D)`` query block and a ``(bkv, Hkv, D)`` KV block and
-    loops over the heads in-kernel (``_streaming_attn_body``).
+    ``(bq, H, D)`` query block and ``(bkv, Hkv, D)`` / ``(bkv, Hkv,
+    Dv)`` K / V blocks and loops over the heads in-kernel
+    (``_streaming_attn_body``); the accumulator and the output are
+    ``Dv`` wide.
 
-    Returns (B, Sq, H, D): int8 when the epilogue clips to ≤ 8 bits,
+    Returns (B, Sq, H, Dv): int8 when the epilogue clips to ≤ 8 bits,
     int32 otherwise (raw / wide output).  Bit-exact against
     ``kernels.ref.ref_int_attention`` for the same arguments.
 
@@ -354,17 +358,19 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
     """
     b, sq, h, d = q8.shape
     _, skv, hkv, _ = k8.shape
+    dv = v8.shape[-1]
     require_launch(check_launch(
         "int_attention", b=b, sq=sq, skv=skv, h=h, hkv=hkv, d=d,
         bq=bq, bkv=bkv, out_bits=out_bits,
-        per_channel=requant is not None and requant.kind == PER_CHANNEL))
+        per_channel=requant is not None and requant.kind == PER_CHANNEL,
+        dv=dv))
     group = h // hkv
     bq = min(bq, sq)
     bkv = min(bkv, skv)
     n_kv = skv // bkv
 
     requant, has_bvec, b2, out_dtype = _epilogue_setup(
-        requant, plan, out_bits, b_vec, h, d)
+        requant, plan, out_bits, b_vec, h, dv)
 
     kernel = functools.partial(
         _fused_kernel, plan=plan, requant=requant, has_bvec=has_bvec,
@@ -375,22 +381,22 @@ def int_attention_fused(q8, k8, v8, plan: IAttnPlan, requant=None,
         pl.BlockSpec((1, bq, h, d), lambda bi, qi, ph, ki: (bi, qi, 0, 0)),
         pl.BlockSpec((1, bkv, hkv, d),
                      lambda bi, qi, ph, ki: (bi, ki, 0, 0)),
-        pl.BlockSpec((1, bkv, hkv, d),
+        pl.BlockSpec((1, bkv, hkv, dv),
                      lambda bi, qi, ph, ki: (bi, ki, 0, 0)),
     ]
     args = [q8, k8, v8]
     if has_bvec:
-        in_specs.append(pl.BlockSpec((h, d), _const_map))
+        in_specs.append(pl.BlockSpec((h, dv), _const_map))
         args.append(b2)
 
     out = pl.pallas_call(
         kernel,
         grid=(b, sq // bq, 2, n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, bq, d),
+        out_specs=pl.BlockSpec((1, h, bq, dv),
                                lambda bi, qi, ph, ki: (bi, 0, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), out_dtype),
-        scratch_shapes=_attn_scratch(h, bq, d),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), out_dtype),
+        scratch_shapes=_attn_scratch(h, bq, dv),
         name=trace_names.kernel("int_attention_fused"),
         interpret=resolve_interpret(interpret),
     )(*args)

@@ -36,6 +36,32 @@ def ref_int8_matmul_perchannel(x8, w8, bias32, b_vec, c: int, pre: int,
     return clip_to_bits(out, out_bits)
 
 
+def ref_int_grouped_matmul(x8, w8, group_sizes, b_vec=None, c: int = 0,
+                           pre: int = 0, out_bits: int = 8):
+    """Oracle for the grouped matmul kernel: row ``r`` of ``x8`` (M, K),
+    in group ``g`` by the sorted ``group_sizes`` (G,), takes ``x8[r] @
+    w8[g]`` (``w8`` (G, K, N)), then group ``g``'s per-channel
+    multipliers ``b_vec[g]`` with the shared ``(c, pre)`` staging and a
+    clip to ``out_bits`` — or, with no ``b_vec``, the raw int32
+    accumulator.  Rows past ``sum(group_sizes)`` are 0.  One full matmul
+    per group, each masked to its rows: an oracle, not a fast path."""
+    from repro.core.dyadic import rshift_round
+    m = x8.shape[0]
+    sizes = jnp.asarray(group_sizes, jnp.int32)
+    ends = jnp.cumsum(sizes)
+    row = jnp.arange(m, dtype=jnp.int32)[:, None]
+    out = jnp.zeros((m, w8.shape[-1]), jnp.int32)
+    for g in range(w8.shape[0]):
+        acc = jnp.dot(x8, w8[g], preferred_element_type=jnp.int32)
+        if b_vec is not None:
+            b = jnp.asarray(b_vec, jnp.int32)[g][None, :]
+            acc = clip_to_bits(rshift_round(rshift_round(acc, pre) * b,
+                                            c - pre), out_bits)
+        out = jnp.where((row >= ends[g] - sizes[g]) & (row < ends[g]),
+                        acc, out)
+    return out.astype(jnp.int8) if out_bits <= 8 else out
+
+
 def ref_int_gelu(q, plan: IGeluPlan, dn_out: Dyadic, out_bits: int = 8):
     """int8 where ``out_bits <= 8``, int32 otherwise."""
     out = clip_to_bits(apply_dyadic(i_gelu(q.astype(jnp.int32), plan),

@@ -44,10 +44,8 @@ PARAM_RULES = [
     ("*cross/wo/w8", (..., "model", None)),
     ("*attn/wo/b_mult", (..., None)),
     ("*moe/router/w8", (..., None, "model")),
-    ("*moe/w1/w8", (..., "model", None, "data")),
-    ("*moe/w1/b_mult", (..., "model", "data")),
-    ("*moe/w3/w8", (..., "model", None, "data")),
-    ("*moe/w3/b_mult", (..., "model", "data")),
+    ("*moe/w13/w8", (..., "model", None, "data")),
+    ("*moe/w13/b_mult", (..., "model", "data")),
     ("*moe/w2/w8", (..., "model", "data", None)),
     ("*moe/w2/b_mult", (..., "model", None)),
     ("*moe/shared/w1/*", (..., None, "model")),

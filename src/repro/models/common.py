@@ -32,6 +32,7 @@ class ArchConfig:
     # ffn / activation / norm
     activation: str = "swiglu"   # swiglu | gelu
     norm: str = "rmsnorm"        # rmsnorm | layernorm
+    norm_eps: float = 1e-6       # the float graph's; integer norms have none
     post_norm: bool = False      # True: BERT/RoBERTa-style post-LN
     tie_embeddings: bool = False
 
@@ -42,7 +43,30 @@ class ArchConfig:
     moe_d_ff: int = 0
     moe_every: int = 1           # MoE FFN on layers where idx % moe_every == moe_offset
     moe_offset: int = 0
-    capacity_factor: float = 1.25
+    # the leading ``first_k_dense`` layers keep a dense FFN of width
+    # ``d_ff`` and run ahead of the (MoE) layer stack (DeepSeek-V3's
+    # ``first_k_dense_replace``)
+    first_k_dense: int = 0
+    # router: "softmax" (top-k of the softmax, renormalised) or
+    # "sigmoid" (DeepSeek-V3's ``noaux_tc``: top-k of sigmoid scores
+    # plus a per-expert correction bias, gates the unbiased scores
+    # normalised over the k, times ``routed_scaling``)
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    # the chip's share of the routed experts: experts
+    # [expert_first, expert_first + experts_held) of the router's
+    # n_experts (0: all of them).  Pairs routed elsewhere add nothing.
+    expert_first: int = 0
+    experts_held: int = 0
+
+    # multi-head latent attention (DeepSeek-V2/V3) without a q LoRA:
+    # ``kv_lora_rank`` > 0 switches it on; a query/key head is
+    # ``qk_nope_head_dim + qk_rope_head_dim`` wide (``head_dim``), a
+    # value head ``v_head_dim``
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
@@ -92,6 +116,25 @@ class ArchConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def vd(self) -> int:
+        """Width of a value head (the attention output per head)."""
+        return self.v_head_dim if self.is_mla else self.hd
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the part of a query/key head that RoPE rotates."""
+        return self.qk_rope_head_dim if self.is_mla else self.hd
+
+    @property
+    def held_experts(self) -> int:
+        """How many routed experts this chip holds (all, by default)."""
+        return self.experts_held or self.padded_experts()
 
     @property
     def score_scale(self) -> float:
@@ -146,7 +189,12 @@ class ArchConfig:
     def layer_param_count(self, idx: int, cross: bool = False) -> int:
         d, hd = self.d_model, self.hd
         n = 0
-        if self._layer_kind(idx) in ("attn", "cross") or cross:
+        if self.is_mla and self._layer_kind(idx) == "attn":
+            r, h = self.kv_lora_rank, self.n_heads
+            n += d * h * hd + d * (r + self.qk_rope_head_dim) + r
+            n += r * h * (self.qk_nope_head_dim + self.v_head_dim)
+            n += h * self.v_head_dim * d
+        elif self._layer_kind(idx) in ("attn", "cross") or cross:
             n += d * (self.n_heads + 2 * self.n_kv_heads) * hd
             n += self.n_heads * hd * d
         if self._layer_kind(idx) == "ssm":
@@ -190,7 +238,7 @@ class ArchConfig:
         return "attn"
 
     def _is_moe_layer(self, idx: int) -> bool:
-        return (self.n_experts > 0
+        return (self.n_experts > 0 and idx >= self.first_k_dense
                 and idx % self.moe_every == self.moe_offset)
 
 

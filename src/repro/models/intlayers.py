@@ -25,6 +25,7 @@ from repro.core.dyadic import apply_dyadic_perchannel, clip_to_bits, \
 from repro.distributed.collectives import psum_int32
 from repro.distributed.sharding import shard
 from repro.models.common import ArchConfig
+from repro.models.layers import sort_pairs
 from repro.ops import QuantLinearParams, RequantSpec
 from repro.ops import get_backend, resolve_ops
 from repro.ops.packed import pack_kv
@@ -99,21 +100,6 @@ def _tp_wo_project(o8, qw, plan: qplans.LinearPlan, tp_axis: str,
 
 # ------------------------------------------------------------- norms ------
 
-def int_expert_linear(x8, qw, plan: qplans.LinearPlan):
-    """Batched-per-expert linear: x8 (G,E,C,K) x w8 (E,K,N) -> (G,E,C,N).
-
-    Per-channel requant with b_mult (E,N); shared static (c, pre)."""
-    qw = QuantLinearParams.of(qw)
-    acc = jnp.einsum("geck,ekn->gecn", x8, qw.w8,
-                     preferred_element_type=jnp.int32)
-    if qw.bias32 is not None:
-        acc = acc + qw.bias32[None, :, None, :]
-    b = qw.b_mult[None, :, None, :].astype(jnp.int32)
-    out = rshift_round(rshift_round(acc, plan.pre) * b, plan.c - plan.pre)
-    out = clip_to_bits(out, plan.out_bits)
-    return out.astype(jnp.int8) if plan.out_bits <= 8 else out
-
-
 def int_norm(qnorm, q32, plan: norms.INormPlan, ops=None):
     """q32 (..., D) int32 at s_res -> int8 at s_act8."""
     ops = resolve_ops(ops)
@@ -174,28 +160,40 @@ def int_attn_fwd(qp, x8, plans: qplans.AttnPlan, cfg: ArchConfig,
         pos = positions if positions is not None else jnp.arange(s)
         q8 = apply_int_rope(q8, pos, rope_tab)
         k8 = apply_int_rope(k8, pos, rope_tab)
+    o8 = _attend(q8, k8, v8, plans.attn, cfg, ops,
+                 causal=causal and memory8 is None, window=window,
+                 streamable=memory8 is None, fuse_attention=fuse_attention)
+    out32 = int_linear(o8.reshape(b, s, cfg.n_heads * cfg.hd), qp["wo"],
+                       plans.out, ops)
+    return shard(out32, "batch", "seq", "embed")
+
+
+def _attend(q8, k8, v8, plan, cfg: ArchConfig, ops, causal: bool,
+            window: int = 0, streamable: bool = True,
+            fuse_attention: bool = True):
+    """Integer attention of int8 ``q8`` (B,S,H,D) over ``k8`` (B,Sk,Hkv,
+    D) and ``v8`` (B,Sk,Hkv,Dv) -> int8 (B,S,H,Dv) at the plan's output
+    grid.  The configured backend handles attention in every branch:
+    backends without a fused kernel fall back to chunked streaming on
+    long self-attention (``streamable``), and fused backends fall back
+    internally on shapes their kernel can't tile (see
+    ops.backends.pallas_fused).  The epilogue travels as a typed
+    RequantSpec, same as the matmul call sites."""
+    s, sk = q8.shape[1], k8.shape[1]
     q8 = shard(q8, "batch", "seq", "heads", None)
     k8 = shard(k8, "batch", "seq", "kv_heads", None)
     v8 = shard(v8, "batch", "seq", "kv_heads", None)
-
-    # the configured backend handles attention in every branch: backends
-    # without a fused kernel fall back to chunked streaming on long
-    # sequences, and fused backends fall back internally on shapes their
-    # kernel can't tile (see ops.backends.pallas_fused).  The epilogue
-    # travels as a typed RequantSpec, same as the matmul call sites.
     attn_backend = ops.backend_for("int_attention")
     if fuse_attention and attn_backend.fused_attention:
-        o8 = ops.int_attention(q8, k8, v8, plans.attn,
-                               causal=causal and memory8 is None,
+        o8 = ops.int_attention(q8, k8, v8, plan, causal=causal,
                                window=window,
-                               requant=RequantSpec.per_tensor(
-                                   plans.attn.dn_out))
-    elif s * sk > (4096 * 4096) // 4 and memory8 is None:
+                               requant=RequantSpec.per_tensor(plan.dn_out))
+    elif s * sk > (4096 * 4096) // 4 and streamable:
         # memory-bounded two-pass streaming path
         rep = cfg.q_group
         k8r = jnp.repeat(k8, rep, 2) if rep > 1 else k8
         v8r = jnp.repeat(v8, rep, 2) if rep > 1 else v8
-        o8 = iattn.i_attention_chunked(q8, k8r, v8r, plans.attn,
+        o8 = iattn.i_attention_chunked(q8, k8r, v8r, plan,
                                        chunk=min(1024, sk), causal=causal,
                                        window=window)
         o8 = o8.astype(jnp.int8)
@@ -204,12 +202,43 @@ def int_attn_fwd(qp, x8, plans: qplans.AttnPlan, cfg: ArchConfig,
         # a fused backend must not be re-entered here — use the oracle
         be = (get_backend("ref") if attn_backend.fused_attention
               else attn_backend)
-        o8 = be.int_attention(q8, k8, v8, plans.attn,
-                              causal=causal and memory8 is None,
+        o8 = be.int_attention(q8, k8, v8, plan, causal=causal,
                               window=window)
-    o8 = shard(o8, "batch", "seq", "heads", None)
-    out32 = int_linear(o8.reshape(b, s, cfg.n_heads * cfg.hd), qp["wo"],
-                       plans.out, ops)
+    return shard(o8, "batch", "seq", "heads", None)
+
+
+def int_mla_fwd(qp, x8, plans: qplans.MlaPlan, cfg: ArchConfig,
+                rope_tab=None, positions=None, causal=True, ops=None):
+    """Multi-head latent attention, prefill form (no q LoRA).
+    x8: (B,S,D) int8 -> (B,S,D) int32 at s_res.
+
+    ``W_q x`` gives H query heads of ``nope + rope`` lanes; ``W_kva x``
+    the K/V latent (to the residual grid, then RMSNorm to int8) and
+    ``W_kpe x`` the one RoPE key every head shares; ``W_kvb`` expands the
+    normed latent to per-head ``k_nope`` and ``v``.  RoPE turns the
+    queries' rope lanes and the shared key (rotate-half pairing), keys
+    are ``[k_nope | k_pe]``, and the causal attention runs with
+    query/key width ``nope + rope`` and value width ``v_head_dim``.
+    Every projection is an ``int_linear`` (an ``int8_matmul`` launch)."""
+    ops = resolve_ops(ops, cfg)
+    b, s, _ = x8.shape
+    h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    q8 = int_linear(x8, qp["wq"], plans.qkv, ops).reshape(b, s, h, -1)
+    c32 = int_linear(x8, qp["wkv_a"], plans.kv_a, ops)
+    kpe8 = int_linear(x8, qp["wk_pe"], plans.qkv, ops)[:, :, None, :]
+    c8 = int_norm(qp["kv_norm"], c32, plans.kv_norm, ops)
+    kv8 = int_linear(c8, qp["wkv_b"], plans.kv_b, ops).reshape(
+        b, s, h, dn + dv)
+    if rope_tab is not None:
+        pos = positions if positions is not None else jnp.arange(s)
+        q8 = jnp.concatenate(
+            [q8[..., :dn], apply_int_rope(q8[..., dn:], pos, rope_tab)], -1)
+        kpe8 = apply_int_rope(kpe8, pos, rope_tab)
+    k8 = jnp.concatenate(
+        [kv8[..., :dn], jnp.broadcast_to(kpe8, (b, s, h, kpe8.shape[-1]))],
+        -1)
+    o8 = _attend(q8, k8, kv8[..., dn:], plans.attn, cfg, ops, causal=causal)
+    out32 = int_linear(o8.reshape(b, s, h * dv), qp["wo"], plans.out, ops)
     return shard(out32, "batch", "seq", "embed")
 
 
@@ -468,65 +497,88 @@ def int_ffn_fwd(qp, x8, plans: qplans.FfnPlan, cfg: ArchConfig,
 
 # --------------------------------------------------------------- moe ------
 
-def int_moe_fwd(qp, x8, plans: qplans.MoePlan, cfg: ArchConfig,
-                ops=None, group_size: int = 512):
-    """Integer MoE: int32 router logits, integer top-k gates (i-softmax
-    over the selected k logits), int8 expert FFNs, integer combine."""
-    ops = resolve_ops(ops, cfg)
-    b, s, d = x8.shape
-    e = cfg.padded_experts()
-    k = cfg.top_k
-    g = max(1, s // group_size)
-    tg = s // g
-    cap = max(4, int(cfg.capacity_factor * tg * k / e))
-    xg = x8.reshape(b * g, tg, d)
+def int_route(logits, qp, plan: qplans.MoePlan, cfg: ArchConfig):
+    """(expert ids (T, k), gates (T, k) int32, gate shift) of int32
+    router logits (T, E).
 
-    logits = int_linear(xg, qp["router"], plans.router, ops)      # int32
+    Softmax routers: top-k of the logits, i-softmax over the k (int8
+    gates at 2^-7).  Sigmoid routers (DeepSeek-V3's ``noaux_tc``):
+    i-sigmoid of every logit at 2^-15, the top-k chosen on it plus the
+    correction bias (``router_bias_q``, on the same grid), the gates the
+    unbiased sigmoids of the k — normalised over them by one rounded
+    integer division — times the routed scaling, at 2^-``gate_frac``."""
+    e, k = cfg.padded_experts(), cfg.top_k
     if e != cfg.n_experts:
         padmask = jnp.arange(e) >= cfg.n_experts
-        logits = jnp.where(padmask[None, None], jnp.int32(-(2 ** 30)),
-                           logits)
-    top_logits, expert_ids = jax.lax.top_k(logits, k)       # (g,t,k)
-    gates8 = ism.i_softmax(top_logits, plans.gate_sm, axis=-1)  # 2^-7 int8
+        logits = jnp.where(padmask, jnp.int32(-(2 ** 30)), logits)
+    if cfg.router != "sigmoid":
+        top_logits, ids = jax.lax.top_k(logits, k)
+        gates = ism.i_softmax(top_logits, plan.gate, axis=-1)
+        return ids, gates.astype(jnp.int32), ism.PROB_SHIFT
+    gp = plan.gate
+    sig = iact.i_sigmoid(logits, gp.sig)                    # 2^-15
+    _, ids = jax.lax.top_k(sig + qp["router_bias_q"], k)
+    sk = jnp.take_along_axis(sig, ids, axis=-1)
+    num = sk * jnp.int32(gp.rs_b)                           # 2^-(15+rs)
+    den = jnp.maximum(jnp.sum(sk, -1, keepdims=True), 1)
+    gates = (num * (1 << (gp.gate_frac - gp.rs_frac)) + den // 2) // den
+    return ids, gates, gp.gate_frac
 
-    dispatch = jnp.zeros((b * g, tg, e, cap), jnp.int8)
-    counts = jnp.zeros((b * g, e), jnp.int32)
-    slot_oh = []
-    for slot in range(k):
-        a = jax.nn.one_hot(expert_ids[..., slot], e, dtype=jnp.int32)
-        pos = counts[:, None, :] + jnp.cumsum(a, axis=1) - a
-        keep = (pos < cap) & (a > 0)
-        oh = (jax.nn.one_hot(pos, cap, dtype=jnp.int32)
-              * keep[..., None]).astype(jnp.int8)           # (g,t,e,cap)
-        slot_oh.append(oh)
-        dispatch = dispatch + oh
-        counts = counts + jnp.sum(a, axis=1)
 
-    buf = jnp.einsum("gtd,gtec->gecd", xg, dispatch,
-                     preferred_element_type=jnp.int32).astype(jnp.int8)
-    buf = shard(buf, "batch", "experts", None, "embed")
-    h1 = int_expert_linear(buf, qp["w1"], plans.expert.up)
-    if cfg.activation == "swiglu":
-        h3 = int_expert_linear(buf, qp["w3"], plans.expert.up)
-        a8 = iact.i_silu(h1, plans.expert.act_silu, out_bits=8)
-        h = clip_to_bits(plans.expert.dn_gate(a8 * h3), 8).astype(jnp.int8)
-    else:
-        h = ops.int_gelu(h1, plans.expert.act_gelu.gelu,
-                         plans.expert.act_gelu.dn_out, out_bits=8)
-    y8 = int_expert_linear(h, qp["w2"], plans.expert.down)   # s_res int32
-    y8 = shard(y8, "batch", "experts", None, "embed")
+def int_moe_fwd(qp, x8, plans: qplans.MoePlan, cfg: ArchConfig,
+                ops=None, return_stats: bool = False):
+    """Integer dropless MoE.  x8 (B,S,D) int8 -> int32 at s_res.
 
-    out32 = jnp.zeros((b * g, tg, d), jnp.int32)
-    for slot in range(k):
-        y_slot = jnp.einsum("gecd,gtec->gtd", y8, slot_oh[slot],
-                            preferred_element_type=jnp.int32)
-        gate = gates8[..., slot].astype(jnp.int32)[..., None]
-        out32 = out32 + rshift_round(y_slot * gate, ism.PROB_SHIFT)
+    Every token's top-k (token, choice) pairs are sorted by expert
+    (``models.layers.sort_pairs``), pairs of experts this chip holds
+    first, in a static buffer of T*k rows: nothing is ever dropped, and
+    the grouped matmul (``ops.int_grouped_matmul``; ``w13`` = w1 and w3
+    side by side, then ``w2``) computes only the live rows.  i-SiLU and
+    the gate product run between, as in ``int_ffn_fwd``; the combine
+    gathers each pair's output back to its token, times its gate.  Pairs
+    of experts held elsewhere add nothing.  The shared experts run once,
+    through ``int_ffn_fwd``.
+
+    ``return_stats``: also return ``(rows computed on held experts,
+    the largest held expert's rows)`` as int32 scalars."""
+    ops = resolve_ops(ops, cfg)
+    b, s, d = x8.shape
+    k = cfg.top_k
+    x2 = x8.reshape(b * s, d)
+    with trace_names.scope("moe.route"):
+        logits = int_linear(x2, qp["router"], plans.router, ops)  # int32
+        ids, gates, shift = int_route(logits, qp, plans, cfg)
+    with trace_names.scope("moe.dispatch"):
+        order, held, sizes = sort_pairs(ids, cfg)
+        xs = x2[order // k]
+    ex = plans.expert
+    w13, w2 = QuantLinearParams.of(qp["w13"]), QuantLinearParams.of(qp["w2"])
+    with trace_names.scope("moe.experts"):
+        h13 = ops.int_grouped_matmul(xs, w13.w8, sizes,
+                                     RequantSpec.for_linear(ex.up),
+                                     b_vec=w13.b_mult)
+        if cfg.activation == "swiglu":
+            f = h13.shape[-1] // 2
+            a8 = iact.i_silu(h13[:, :f], ex.act_silu, out_bits=8)
+            h = clip_to_bits(ex.dn_gate(a8 * h13[:, f:]), 8)
+        else:
+            h = ops.int_gelu(h13, ex.act_gelu.gelu, ex.act_gelu.dn_out,
+                             out_bits=8)
+        y = ops.int_grouped_matmul(h.astype(jnp.int8), w2.w8, sizes,
+                                   RequantSpec.for_linear(ex.down),
+                                   b_vec=w2.b_mult)
+    with trace_names.scope("moe.combine"):
+        g = jnp.where(held, gates.reshape(-1), 0)[order]
+        contrib = rshift_round(y * g[:, None], shift)
+        out32 = contrib[jnp.argsort(order)].reshape(b * s, k, d).sum(1)
     out32 = out32.reshape(b, s, d)
     if plans.shared is not None:
         out32 = out32 + int_ffn_fwd(qp["shared"], x8, plans.shared, cfg,
                                     ops)
-    return shard(out32, "batch", "seq", "embed")
+    out32 = shard(out32, "batch", "seq", "embed")
+    if return_stats:
+        return out32, (jnp.sum(sizes), jnp.max(sizes))
+    return out32
 
 
 # -------------------------------------------------------------- mamba -----
@@ -624,14 +676,7 @@ def int_mamba_step(qp, u8_t, state: IntMambaState, mp: qplans.MambaPlan,
 
 def _silu16(zq, plan: iact.ISiluPlan):
     """sigmoid(z) as a 2^-15 fraction (int32), z int32 at plan.s_in."""
-    q = zq.astype(jnp.int32)
-    e = intmath.i_exp(-jnp.abs(q), plan.iexp)
-    e16 = jnp.clip(plan.dn_e16(e), 0, 1 << 15)
-    one16 = jnp.int32(1 << 15)
-    den = one16 + e16
-    r = jnp.int32(1 << 30) // den
-    num = jnp.where(q >= 0, one16, e16)
-    return (num * r) >> 15
+    return iact.i_sigmoid16(zq.astype(jnp.int32), plan.iexp, plan.dn_e16)
 
 
 def int_mamba_prefill(qp, u8, mp: qplans.MambaPlan, cfg: ArchConfig,

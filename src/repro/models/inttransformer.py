@@ -21,7 +21,7 @@ from repro import trace_names
 from repro.distributed.sharding import shard
 from repro.models import intlayers as il
 from repro.models.common import ArchConfig
-from repro.models.transformer import layer_group_spec
+from repro.models.transformer import LEAD_KIND, layer_group_spec
 from repro.ops import resolve_ops
 from repro.quant import plans as qplans
 
@@ -45,11 +45,18 @@ def _sub_plans(plans: qplans.LayerPlans, kind):
 
 
 def _int_sublayer_fwd(qp, x32, plans: qplans.LayerPlans, cfg: ArchConfig,
-                      kind, rope_tab, positions, causal, memory8, ops):
-    """Pre-norm integer sublayer.  x32: (B,S,D) int32 at s_res."""
+                      kind, rope_tab, positions, causal, memory8, ops,
+                      return_stats: bool = False):
+    """Pre-norm integer sublayer.  x32: (B,S,D) int32 at s_res.  With
+    ``return_stats``, returns ``(x32, stats)``: an MoE sublayer's
+    ``int_moe_fwd`` row counts, None for any other."""
     mix, ff, has_cross = kind
     h8 = _norm(qp, "norm1", x32, plans.norm, ops)
-    if mix == "attn":
+    if mix == "attn" and cfg.is_mla:
+        with trace_names.scope("attn"):
+            a32 = il.int_mla_fwd(qp["attn"], h8, plans.attn, cfg, rope_tab,
+                                 positions, causal=causal, ops=ops)
+    elif mix == "attn":
         with trace_names.scope("attn"):
             a32 = il.int_attn_fwd(qp["attn"], h8, plans.attn, cfg,
                                   rope_tab, positions, causal=causal,
@@ -71,21 +78,24 @@ def _int_sublayer_fwd(qp, x32, plans: qplans.LayerPlans, cfg: ArchConfig,
                                   positions, causal=False, memory8=memory8,
                                   ops=ops)
         x32 = _residual_add(x32, c32, cfg)
+    stats = None
     if ff is not None:
         h8 = _norm(qp, "norm2", x32, plans.norm, ops)
-        f32 = _int_ff(qp, h8, plans, cfg, ff, ops)
+        f32 = _int_ff(qp, h8, plans, cfg, ff, ops, return_stats)
+        if return_stats and ff == "moe":
+            f32, stats = f32
         x32 = _residual_add(x32, f32, cfg)
-    return x32
+    return (x32, stats) if return_stats else x32
 
 
 def _int_ff(qp, h8, plans: qplans.LayerPlans, cfg: ArchConfig, ff, ops,
-            **moe_kw):
+            return_stats: bool = False):
     """The feed-forward sublayer: an MoE (scoped ``moe``) or a dense FFN
     (scoped ``ffn.*`` inside ``int_ffn_fwd``)."""
     if ff == "moe":
         with trace_names.scope("moe"):
             return il.int_moe_fwd(qp["moe"], h8, plans.moe, cfg, ops,
-                                  **moe_kw)
+                                  return_stats=return_stats)
     return il.int_ffn_fwd(qp["ffn"], h8, plans.ffn, cfg, ops)
 
 
@@ -114,18 +124,45 @@ def logits_int(qparams, x32, plans: qplans.LayerPlans, cfg: ArchConfig,
             * plans.head.s_in
 
 
+class MLADecodeError(NotImplementedError):
+    """Multi-head latent attention runs through ``int_prefill`` only:
+    decoding it wants a latent paged cache (the normed K/V latent and
+    the shared RoPE key per position), which the engine does not have."""
+
+    def __init__(self, what: str, cfg: ArchConfig):
+        super().__init__(
+            f"{what} does not serve {cfg.name!r}: multi-head latent "
+            "attention (kv_lora_rank > 0) runs through int_prefill only; "
+            "decoding it needs a latent paged KV cache, which is not "
+            "built yet")
+
+
+def refuse_mla(cfg: ArchConfig, what: str):
+    """Raise :class:`MLADecodeError` for an MLA architecture (and for
+    any with leading dense layers, which only MLA configs have)."""
+    if cfg.is_mla or cfg.first_k_dense:
+        raise MLADecodeError(what, cfg)
+
+
 @trace_names.entry_point
 def int_prefill(qparams, batch, plans: qplans.LayerPlans, cfg: ArchConfig,
                 ops=None, return_cache=False, cache_len: int = 0,
-                rope_tab=None):
+                rope_tab=None, return_stats=False):
     """Full-sequence integer forward; returns last-position float logits
     (+ decode caches when ``return_cache``).
 
     ``ops``: an ``repro.ops.OpSet`` (or backend name) resolved once here
     and handed down — per-call backend strings are gone.
     ``rope_tab``: int32 (cos, sin) design tables passed as *arguments* so
-    they are inputs, not multi-MB HLO constants."""
+    they are inputs, not multi-MB HLO constants.
+    ``return_stats``: also return, after the logits, the MoE layers'
+    counters — ``{"moe_rows": (layers,), "moe_rows_max": (layers,)}``
+    int32, the (token, choice) rows each MoE layer computed on the
+    experts this chip holds and its largest held expert's rows.  Off,
+    the program is the same as without the argument."""
     ops = resolve_ops(ops, cfg)
+    if return_cache:
+        refuse_mla(cfg, "int_prefill(return_cache=True)")
     gl, ng, kinds = layer_group_spec(cfg)
     tokens = batch["tokens"]
     b, s = tokens.shape
@@ -136,21 +173,38 @@ def int_prefill(qparams, batch, plans: qplans.LayerPlans, cfg: ArchConfig,
     elif cfg.family == "vlm":
         memory8 = quantize_memory(batch["img_embeds"], cfg)
     if rope_tab is None and cfg.pos == "rope":
-        rope_tab = il.build_rope_table(max(s, cache_len) + 1, cfg.hd,
+        rope_tab = il.build_rope_table(max(s, cache_len) + 1, cfg.rope_dim,
                                        cfg.rope_theta)
     positions = jnp.arange(s)
     x32 = embed_int(qparams, tokens, plans, cfg)
 
+    if cfg.first_k_dense:
+        def lead(x32, qp):
+            return _int_sublayer_fwd(qp, x32, plans, cfg, LEAD_KIND,
+                                     rope_tab, positions, cfg.is_causal,
+                                     memory8, ops), None
+        x32, _ = jax.lax.scan(lead, x32, qparams["lead_layers"])
+
     def body(x32, qp_group):
+        stats = []
         for j, kind in enumerate(kinds):
             x32 = _int_sublayer_fwd(qp_group[j], x32, plans, cfg, kind,
                                     rope_tab, positions, cfg.is_causal,
-                                    memory8, ops)
-        return x32, None
+                                    memory8, ops, return_stats)
+            if return_stats:
+                x32, st = x32
+                stats += [st] if st is not None else []
+        return x32, (stats or None)
 
-    x32, _ = jax.lax.scan(body, x32, tuple(qparams["layers"]))
+    x32, stats = jax.lax.scan(body, x32, tuple(qparams["layers"]))
     last = x32[:, -1:, :]
     logits = logits_int(qparams, last, plans, cfg, ops)[:, 0]
+    if return_stats:
+        rows = jnp.stack([r for r, _ in stats or []], -1).reshape(-1) \
+            if stats else jnp.zeros((0,), jnp.int32)
+        rmax = jnp.stack([m for _, m in stats or []], -1).reshape(-1) \
+            if stats else jnp.zeros((0,), jnp.int32)
+        return logits, {"moe_rows": rows, "moe_rows_max": rmax}
     if not return_cache:
         return logits
     cache = build_cache_from_prefill(qparams, batch, plans, cfg, ops,
@@ -195,6 +249,7 @@ def init_decode_cache(cfg: ArchConfig, batch: int, cache_len: int,
     ``hd // 2``) and carry per-page requant shift arrays ``k_shift`` /
     ``v_shift`` ``(ng, num_pages)`` int32 (``repro.ops.packed.KV_SHIFT``
     everywhere — the static shift the write-side quantizer uses)."""
+    refuse_mla(cfg, "init_decode_cache")
     ops = resolve_ops(ops, cfg)
     gl, ng, kinds = layer_group_spec(cfg)
     L = min(cache_len, cfg.window) if cfg.window > 0 else cache_len
@@ -278,7 +333,7 @@ def _int_sublayer_decode(qp, cache, x32, plans, cfg: ArchConfig, kind,
         x32 = _residual_add(x32, c32, cfg)
     if ff is not None:
         h8 = _norm(qp, "norm2", x32, plans.norm, ops)
-        f32 = _int_ff(qp, h8, plans, cfg, ff, ops, group_size=1)
+        f32 = _int_ff(qp, h8, plans, cfg, ff, ops)
         x32 = _residual_add(x32, f32, cfg)
     return x32, new_cache
 
@@ -319,6 +374,7 @@ def int_decode_step(qparams, caches, tokens, pos, plans, cfg: ArchConfig,
     the local head counts, and each attention o-projection all-reduces
     its int32 partials before requanting once (see
     ``repro.distributed.tp_serving``)."""
+    refuse_mla(cfg, "int_decode_step")
     ops = resolve_ops(ops, cfg)
     gl, ng, kinds = layer_group_spec(cfg)
     x32 = embed_int(qparams, tokens[:, None], plans, cfg)
@@ -350,9 +406,12 @@ def speculative_decode_supported(cfg: ArchConfig) -> bool:
     token (a batched multi-position write would clobber slots earlier
     verify rows still need), and SSM / cross-attention state advances
     destructively per token — a rejected draft could not roll it back.
-    Dense FFN *and* MoE sublayers are fine: decode routes MoE with
-    ``group_size=1`` (one token per routing group), so each verify row
-    routes independently, bit-exact against sequential decode."""
+    Dense FFN *and* MoE sublayers are fine: dropless MoE routes every
+    token on its own, so each verify row routes independently,
+    bit-exact against sequential decode.  MLA architectures decode
+    nothing (:class:`MLADecodeError`)."""
+    if cfg.is_mla or cfg.first_k_dense:
+        return False
     _, _, kinds = layer_group_spec(cfg)
     return cfg.window == 0 and all(mix == "attn" and not has_cross
                                    for (mix, ff, has_cross) in kinds)
@@ -381,13 +440,14 @@ def int_verify_step(qparams, caches, tokens, pos, n_new, plans,
     (``ops.int_decode_attention``; built for exactly this in PR 3)
     limits it to positions ``<= pos + n_new - S + i`` — the visibility
     a sequential decode of the same tokens would have.  Embedding,
-    norms, FFN/MoE(``group_size=1``) and the residual stream are
+    norms, FFN/MoE (dropless, per token) and the residual stream are
     position-independent, and the attention rows are masked
     identically, so each real row's logits are **bit-exact** against
     feeding its token through :func:`int_decode_step` — greedy
     acceptance therefore reproduces the non-speculative stream token
     for token.  Supported archs: :func:`speculative_decode_supported`.
     """
+    refuse_mla(cfg, "int_verify_step")
     ops = resolve_ops(ops, cfg)
     if not speculative_decode_supported(cfg):
         raise ValueError("speculative verify unsupported for arch "
@@ -414,7 +474,7 @@ def int_verify_step(qparams, caches, tokens, pos, n_new, plans,
             _, ff, _ = kind
             if ff is not None:
                 h8 = _norm(qp, "norm2", x32, plans.norm, ops)
-                f32 = _int_ff(qp, h8, plans, cfg, ff, ops, group_size=1)
+                f32 = _int_ff(qp, h8, plans, cfg, ff, ops)
                 x32 = _residual_add(x32, f32, cfg)
             new_group.append(new_cache)
         return x32, tuple(new_group)
@@ -431,10 +491,12 @@ def chunked_prefill_supported(cfg: ArchConfig) -> bool:
     windows interleave rolling-buffer writes and reads token-by-token
     (a batched chunk write would clobber positions earlier chunk rows
     still need), SSM state updates are inherently sequential per lane,
-    MoE capacity-based routing drops tokens per *group* (so chunked
-    grouping would diverge from token streaming), and cross-attention
-    archs carry lane-indexed memory — all of those keep the engine's
-    token-streaming prefill."""
+    the chunk step runs no MoE sublayer, and cross-attention archs carry
+    lane-indexed memory — all of those keep the engine's
+    token-streaming prefill.  MLA architectures decode nothing
+    (:class:`MLADecodeError`)."""
+    if cfg.is_mla or cfg.first_k_dense:
+        return False
     _, _, kinds = layer_group_spec(cfg)
     return cfg.window == 0 and all(kind == ("attn", "ffn", False)
                                    for kind in kinds)
@@ -463,6 +525,7 @@ def int_prefill_chunk_step(qparams, caches, tokens, base_pos, plans,
     one at a time (same ops, same epilogues, row-independent integer
     math).  Supported archs: :func:`chunked_prefill_supported`.
     """
+    refuse_mla(cfg, "int_prefill_chunk_step")
     ops = resolve_ops(ops, cfg)
     if not chunked_prefill_supported(cfg):
         raise ValueError("chunked prefill unsupported for arch "

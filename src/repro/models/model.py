@@ -45,6 +45,11 @@ def reduce_config(cfg: ArchConfig, **over) -> ArchConfig:
         enc_layers=min(cfg.enc_layers, 2) if cfg.enc_layers else 0,
         dec_layers=min(cfg.dec_layers, 2) if cfg.dec_layers else 0,
     )
+    if cfg.first_k_dense:
+        upd["num_layers"] += cfg.first_k_dense
+    if cfg.is_mla:
+        upd.update(kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                   v_head_dim=32, head_dim=48)
     upd.update(over)
     return dataclasses.replace(cfg, **upd)
 
@@ -108,17 +113,31 @@ def _ffn_spec(ng, cfg: ArchConfig, plans: qplans.FfnPlan, f=None):
     return out
 
 
+def _mla_spec(ng, cfg: ArchConfig, plans: qplans.MlaPlan):
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    return {
+        "wq": _lin_spec(ng, d, h * cfg.hd, plans.qkv),
+        "wkv_a": _lin_spec(ng, d, r, plans.kv_a),
+        "wk_pe": _lin_spec(ng, d, cfg.qk_rope_head_dim, plans.qkv),
+        "kv_norm": {"gamma_q": SDS((ng, r) if ng else (r,), jnp.int32)},
+        "wkv_b": _lin_spec(ng, r, h * (dn + dv), plans.kv_b),
+        "wo": _lin_spec(ng, h * dv, d, plans.out),
+    }
+
+
 def _moe_spec(ng, cfg: ArchConfig, plans: qplans.MoePlan):
-    d, e = cfg.d_model, cfg.padded_experts()
+    d, e, eh = cfg.d_model, cfg.padded_experts(), cfg.held_experts
     f = cfg.moe_d_ff or cfg.d_ff
+    f13 = 2 * f if cfg.activation == "swiglu" else f
     out = {
         "router": QuantLinearParams(
             SDS((ng, d, e) if ng else (d, e), jnp.int8)),
-        "w1": _lin_spec(ng, d, f, plans.expert.up, lead=(e,)),
-        "w2": _lin_spec(ng, f, d, plans.expert.down, lead=(e,)),
+        "w13": _lin_spec(ng, d, f13, plans.expert.up, lead=(eh,)),
+        "w2": _lin_spec(ng, f, d, plans.expert.down, lead=(eh,)),
     }
-    if cfg.activation == "swiglu":
-        out["w3"] = _lin_spec(ng, d, f, plans.expert.up, lead=(e,))
+    if cfg.router == "sigmoid":
+        out["router_bias_q"] = SDS((ng, e) if ng else (e,), jnp.int32)
     if cfg.n_shared_experts:
         out["shared"] = _ffn_spec(ng, cfg, plans.shared,
                                   f=f * cfg.n_shared_experts)
@@ -145,7 +164,9 @@ def _mamba_spec(ng, cfg: ArchConfig, mp: qplans.MambaPlan):
 def _sublayer_spec(ng, cfg, plans, kind):
     mix, ff, has_cross = kind
     out = {"norm1": _norm_spec(ng, cfg.d_model, cfg)}
-    if mix == "attn":
+    if mix == "attn" and cfg.is_mla:
+        out["attn"] = _mla_spec(ng, cfg, plans.attn)
+    elif mix == "attn":
         out["attn"] = _attn_spec(ng, cfg, plans.attn)
     elif mix == "cross":
         out["attn"] = _attn_spec(ng, cfg, plans.cross)
@@ -176,6 +197,9 @@ def qparams_spec(cfg: ArchConfig,
         "layers": [_sublayer_spec(ng, cfg, plans, kinds[j])
                    for j in range(gl)],
     }
+    if cfg.first_k_dense:
+        spec["lead_layers"] = _sublayer_spec(cfg.first_k_dense, cfg, plans,
+                                             tf.LEAD_KIND)
     if cfg.family == "encdec":
         spec["enc_layers"] = [_sublayer_spec(cfg.enc_layers, cfg, plans,
                                              ("attn", "ffn", False))]

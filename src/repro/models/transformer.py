@@ -6,6 +6,8 @@ per-tensor transformation and ``lax.scan`` stacks stay homogeneous.
 
 Layer grouping for scan:
   dense / moe / ssm / encoder : all layers identical -> one stacked scan
+                                (``first_k_dense`` leading dense layers,
+                                DeepSeek-V3's, scan first on their own)
   vlm                         : blocks of (cross_every-1 self + 1 cross)
   hybrid (jamba)              : blocks of ``attn_every`` sublayers
                                 (1 attn + rest mamba; MoE per moe_every)
@@ -57,15 +59,23 @@ def layer_group_spec(cfg: ArchConfig):
         ff = "moe" if (cfg.n_experts and cfg.moe_every == 1) else "ffn"
         kinds = [("attn", ff, False)]
     n = cfg.dec_layers if cfg.family == "encdec" else cfg.num_layers
+    n -= cfg.first_k_dense
     assert n % gl == 0, (n, gl)
     return gl, n // gl, kinds
+
+
+#: the kind of the leading dense layers (``cfg.first_k_dense``), which
+#: run as their own stack (``params["lead_layers"]``) ahead of the groups
+LEAD_KIND = ("attn", "ffn", False)
 
 
 def _init_sublayer(key, cfg: ArchConfig, mix: str, ff: Optional[str],
                    cross: bool, dtype):
     ks = jax.random.split(key, 4)
     p = {"norm1": fl.init_norm(cfg, dtype)}
-    if mix in ("attn", "cross"):
+    if mix == "attn" and cfg.is_mla:
+        p["attn"] = fl.init_mla(ks[0], cfg, dtype)
+    elif mix in ("attn", "cross"):
         p["attn"] = fl.init_attn(ks[0], cfg, dtype, cross=(mix == "cross"))
     elif mix == "ssm":
         p["ssm"] = mb.init_mamba(ks[0], cfg, dtype)
@@ -134,6 +144,11 @@ def init_params(key, cfg: ArchConfig, served: bool = False) -> Pytree:
                 for i in range(ng)])
         for j in range(gl)
     ]
+    if cfg.first_k_dense:
+        lkeys = jax.random.split(keys[-5], cfg.first_k_dense)
+        params["lead_layers"] = _stack([
+            _init_sublayer(lkeys[i], cfg, *LEAD_KIND, dtype)
+            for i in range(cfg.first_k_dense)])
     if cfg.family == "encdec":
         ekeys = jax.random.split(keys[-4], cfg.enc_layers)
         params["enc_layers"] = [_stack([
@@ -147,7 +162,7 @@ def _served_scale(params, cfg: ArchConfig):
     """The ``served=True`` rescaling of :func:`init_params`."""
     v = cfg.padded_vocab()
     gain = SERVED_BRANCH_GAIN / cfg.residual_multiplier
-    gains = {"wo": gain / math.sqrt(cfg.hd or 1), "w2": gain}
+    gains = {"wo": gain / math.sqrt(cfg.vd or 1), "w2": gain}
 
     def scale(path, leaf):
         g = gains.get(getattr(path[-1], "key", None), 1.0)
@@ -156,7 +171,7 @@ def _served_scale(params, cfg: ArchConfig):
     std = SERVED_EMBED_STD / cfg.embedding_multiplier
     out["embed"] = (params["embed"] * (std * math.sqrt(v))
                     ).astype(params["embed"].dtype)
-    for name in ("layers", "enc_layers"):
+    for name in ("layers", "enc_layers", "lead_layers"):
         if name in params:
             out[name] = jax.tree_util.tree_map_with_path(scale,
                                                          params[name])
@@ -172,6 +187,9 @@ def _sublayer_fwd_float(p, x, cfg: ArchConfig, kind, positions, qat,
     aux = jnp.zeros((), jnp.float32)
 
     def mixer(h):
+        if mix == "attn" and cfg.is_mla:
+            return fl.mla_fwd(p["attn"], h, cfg, positions, causal=causal,
+                              qat=qat)
         if mix in ("attn", "cross"):
             return fl.attn_fwd(p["attn"], h, cfg, positions, causal=causal,
                                window=window,
@@ -284,8 +302,14 @@ def forward_float(params, batch, cfg: ArchConfig, qat: bool = False,
         memory = batch["img_embeds"].astype(jnp.dtype(cfg.dtype))
     x = embed_tokens(params, batch["tokens"], cfg)
     positions = jnp.arange(x.shape[1])[None]
-    x, aux = _run_stack_float(params["layers"], x, cfg, kinds, positions,
-                              qat, causal=cfg.is_causal, memory=memory)
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.first_k_dense:
+        x, aux = _run_stack_float([params["lead_layers"]], x, cfg,
+                                  [LEAD_KIND], positions, qat,
+                                  causal=cfg.is_causal, memory=memory)
+    x, aux_l = _run_stack_float(params["layers"], x, cfg, kinds, positions,
+                                qat, causal=cfg.is_causal, memory=memory)
+    aux = aux + aux_l
     if return_hidden:
         return x, aux
     return logits_fwd(params, x, cfg, qat), aux

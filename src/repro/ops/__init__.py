@@ -40,7 +40,7 @@ __all__ = [
     "REQUIRED_OPS", "PER_CHANNEL", "PER_TENSOR", "RAW",
     "int8_matmul", "int8_matmul_packed", "int_gelu",
     "int_layernorm", "int_attention", "int_decode_attention",
-    "int_paged_prefill",
+    "int_paged_prefill", "int_grouped_matmul",
 ]
 
 
@@ -98,3 +98,9 @@ def int_paged_prefill(q8, k8_new, v8_new, k_pool, v_pool, plan, base_pos,
     return resolve_ops(ops).int_paged_prefill(
         q8, k8_new, v8_new, k_pool, v_pool, plan, base_pos, pages,
         page_size, out_bits=out_bits, **opts)
+
+
+def int_grouped_matmul(x8, w8, group_sizes, spec, *, b_vec=None, ops=None,
+                       **opts):
+    return resolve_ops(ops).int_grouped_matmul(x8, w8, group_sizes, spec,
+                                               b_vec=b_vec, **opts)

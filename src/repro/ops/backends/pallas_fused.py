@@ -14,7 +14,8 @@ dead blocks skipped).  The backend advertises every optional capability
 scalar-prefetch operand and KV blocks translate through it in the index
 map) and ``decode_wo_fold`` (the o-projection + its per-channel requant
 run as the launch's epilogue), their chunked-prefill twins, packed
-weights and KV pages, and tensor-parallel serving.  ``wo`` folds only
+weights and KV pages, the grouped (MoE expert) matmul, and
+tensor-parallel serving.  ``wo`` folds only
 while its whole ``(H·D, N)`` block fits the chip's VMEM budget
 (``analysis.contracts.can_fold_wo``); a wider projection runs after an
 unfolded launch, through the matmul kernel, with the same integers.
@@ -91,6 +92,8 @@ class PallasFusedBackend:
     prefill_wo_fold = True    # ... with the o-projection folded in too
     packed_matmul = True      # int4/msr4 weights unpacked inside the launch
     packed_kv = True          # int4 KV pages dequantized inside the launch
+    grouped_matmul = True     # MoE experts: one grouped launch, dead
+    #   row tiles skipped (kernels.int_grouped_matmul)
     tp_serving = True         # kernels launch per-shard under shard_map
     #   (the wrapper's require_launch then validates the LOCAL h/tp,
     #   hkv/tp shapes; analysis.contracts.check_tp_launch is its
@@ -131,6 +134,21 @@ class PallasFusedBackend:
                                   out_dtype=spec.out_dtype,
                                   bm=bm, bn=bn, bk=bk,
                                   interpret=self._interp(), **opts)
+
+    def int_grouped_matmul(self, x8, w8, group_sizes, spec, *, b_vec=None,
+                           **opts):
+        from repro.kernels.int_grouped_matmul import \
+            int_grouped_matmul_pallas
+        if spec.kind == _spec.PER_TENSOR:
+            raise ValueError("the grouped matmul takes a per-channel or "
+                             "raw RequantSpec")
+        if not spec.is_raw and b_vec is None:
+            raise ValueError("per-channel RequantSpec needs the b_vec "
+                             "multiplier vector")
+        return int_grouped_matmul_pallas(
+            x8, w8, group_sizes, None if spec.is_raw else b_vec,
+            c=spec.c, pre=spec.pre, out_bits=spec.out_bits,
+            interpret=self._interp(), **opts)
 
     def int_gelu(self, q, plan, dn_out, out_bits: int = 8, **opts):
         return int_gelu_pallas(q, plan, dn_out, out_bits,

@@ -25,6 +25,16 @@ class RefBackend:
     # the pure-jnp oracles trace cleanly inside a shard_map body, so the
     # serving engine may head-shard its launches across a tp mesh
     tp_serving = True
+    grouped_matmul = True
+
+    def int_grouped_matmul(self, x8, w8, group_sizes, spec, *, b_vec=None,
+                           **opts):
+        if spec.kind == _spec.PER_TENSOR:
+            raise ValueError("the grouped matmul takes a per-channel or "
+                             "raw RequantSpec")
+        return _ref.ref_int_grouped_matmul(
+            x8, w8, group_sizes, None if spec.is_raw else b_vec, spec.c,
+            spec.pre, spec.out_bits)
 
     def int8_matmul(self, x8, w8, spec, *, bias32=None, b_vec=None, **opts):
         if spec.is_raw:

@@ -33,7 +33,8 @@ REQUIRED_OPS = ("int8_matmul", "int_gelu", "int_layernorm",
 # matching flag implements them natively, everyone else is served by an
 # exact lowering in OpSet (so OP_NAMES is what dispatch/overrides/
 # describe() route on, REQUIRED_OPS is what the protocol demands)
-OP_NAMES = REQUIRED_OPS + ("int_paged_prefill", "int8_matmul_packed")
+OP_NAMES = REQUIRED_OPS + ("int_paged_prefill", "int8_matmul_packed",
+                           "int_grouped_matmul")
 
 
 @runtime_checkable
@@ -100,6 +101,12 @@ class Backend(Protocol):
         layer dequantizes the pools to int8
         (``repro.ops.packed.unpack_kv_pool``) and proceeds on the
         plain paged path — the declared reference numerics.
+
+      * ``grouped_matmul`` — the backend implements
+        ``int_grouped_matmul`` (a dropless MoE's expert matmuls: rows
+        sorted by group, a per-group per-channel requant) natively.
+        Without the flag the dispatch layer runs the oracle,
+        ``kernels.ref.ref_int_grouped_matmul``.
 
       * ``tp_serving`` — the backend's ops trace inside a ``shard_map``
         body, so the serving engine may head-shard its decode/prefill
@@ -283,6 +290,22 @@ class OpSet:
         from repro.ops.packed import unpack_weights
         return be.int8_matmul(x8, unpack_weights(qw), spec,
                               bias32=qw.bias32, b_vec=qw.b_mult, **opts)
+
+    def int_grouped_matmul(self, x8, w8, group_sizes, spec, *,
+                           b_vec=None, **opts):
+        """Grouped int8 matmul: ``x8`` (M, K) rows sorted by group,
+        ``w8`` (G, K, N), ``group_sizes`` (G,) int32 with ``sum <= M``;
+        row ``r`` of group ``g`` takes ``x8[r] @ w8[g]`` and the
+        ``spec`` epilogue — per-channel with group ``g``'s row of
+        ``b_vec`` (G, N), or raw.  Rows past ``sum(group_sizes)`` are
+        not specified (callers read the live rows only).  Backends
+        advertising ``grouped_matmul`` launch their kernel; the rest get
+        the oracle, so every backend gives the same live rows."""
+        be = self.backend_for("int_grouped_matmul")
+        if not getattr(be, "grouped_matmul", False):
+            be = get_backend("ref")
+        return be.int_grouped_matmul(x8, w8, group_sizes, spec,
+                                     b_vec=b_vec, **opts)
 
     def _compose_wo(self, be, o8, wo, wo_spec):
         """Exact unfolded wo composition: decode output → o-projection.

@@ -17,9 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import activations as iact
 from repro.core import norms
 from repro.models.common import ArchConfig
-from repro.models.transformer import layer_group_spec
+from repro.models.transformer import LEAD_KIND, layer_group_spec
 from repro.ops import QuantLinearParams
 from repro.quant import plans as qplans
 
@@ -99,6 +100,25 @@ def _q_attn(p, plans: qplans.AttnPlan):
     return out
 
 
+def _q_mla(p, plans: qplans.MlaPlan, cfg: ArchConfig):
+    """MLA weights: the query and latent-expansion heads flattened, the
+    latent projection split into the latent (``wkv_a``, to the residual
+    grid for its RMSNorm) and the shared RoPE key (``wk_pe``, int8) —
+    per-channel scales are per column, so the split quantizes each
+    column as the whole would."""
+    r = cfg.kv_lora_rank
+    kva = np.asarray(jax.device_get(p["wkv_a"]), np.float64)
+    wo = np.asarray(jax.device_get(p["wo"]), np.float64)
+    out = {"wq": _q_attn_w(p["wq"], plans.qkv),
+           "wkv_a": _q_linear(kva[..., :r], plans.kv_a)[0],
+           "wk_pe": _q_linear(kva[..., r:], plans.qkv)[0],
+           "kv_norm": _q_norm(p["kv_norm"], plans.kv_norm),
+           "wkv_b": _q_attn_w(p["wkv_b"], plans.kv_b),
+           "wo": _q_linear(wo.reshape(*wo.shape[:-3], -1, wo.shape[-1]),
+                           plans.out)[0]}
+    return out
+
+
 def _q_ffn(p, plans: qplans.FfnPlan):
     out = {}
     out["w1"], s1 = _q_linear(p["w1"], plans.up,
@@ -116,9 +136,18 @@ def _q_moe(p, plans: qplans.MoePlan, s_router=None):
         s_router = np.abs(w).max() / 127.0
     out["router"] = QuantLinearParams(jnp.asarray(
         np.clip(np.round(w / s_router), -127, 127).astype(np.int8)))
-    out["w1"], _ = _q_linear(p["w1"], plans.expert.up)
+    if "router_bias" in p:
+        # the correction bias adds to sigmoid scores at 2^-SIG_FRAC
+        out["router_bias_q"] = jnp.asarray(np.round(
+            np.asarray(jax.device_get(p["router_bias"]), np.float64)
+            * (1 << iact.SIG_FRAC)).astype(np.int32))
+    # w1 and w3 side by side (one grouped launch): per-channel scales
+    # are per column, so each half quantizes as it would alone
+    w1 = np.asarray(jax.device_get(p["w1"]), np.float64)
     if "w3" in p:
-        out["w3"], _ = _q_linear(p["w3"], plans.expert.up)
+        w1 = np.concatenate(
+            [w1, np.asarray(jax.device_get(p["w3"]), np.float64)], -1)
+    out["w13"], _ = _q_linear(w1, plans.expert.up)
     out["w2"], _ = _q_linear(p["w2"], plans.expert.down)
     if "shared" in p:
         out["shared"] = _q_ffn(p["shared"], plans.shared)
@@ -165,7 +194,9 @@ def _q_sublayer(p, plans: qplans.LayerPlans, cfg: ArchConfig, kind,
     mix, ff, has_cross = kind
     scales = scales or {}
     out = {"norm1": _q_norm(p["norm1"], plans.norm)}
-    if mix in ("attn", "cross"):
+    if mix == "attn" and cfg.is_mla:
+        out["attn"] = _q_mla(p["attn"], plans.attn, cfg)
+    elif mix in ("attn", "cross"):
         out["attn"] = _q_attn(p["attn"],
                               plans.attn if mix == "attn" else plans.cross)
     else:
@@ -264,6 +295,9 @@ def quantize_params(params: Pytree, cfg: ArchConfig
         _q_stacked(params["layers"][j], plans, cfg, kinds[j])
         for j in range(gl)
     ]
+    if cfg.first_k_dense:
+        qparams["lead_layers"] = _q_stacked(params["lead_layers"], plans,
+                                            cfg, LEAD_KIND)
     if cfg.family == "encdec":
         qparams["enc_layers"] = [
             _q_stacked(params["enc_layers"][0], plans, cfg,

@@ -13,7 +13,7 @@ vectors with a plan-level shared shift.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Union
 
 import numpy as np
 
@@ -64,6 +64,41 @@ class AttnPlan(NamedTuple):
     out: LinearPlan          # o-proj: s_act8 -> s_res
 
 
+class MlaPlan(NamedTuple):
+    """Multi-head latent attention (no q LoRA), prefill form."""
+    qkv: LinearPlan          # W_q and the RoPE key W_kpe: s_act8 -> s_act8
+    kv_a: LinearPlan         # the K/V latent W_kva: s_act8 -> s_res
+    kv_norm: norms.INormPlan  # RMSNorm of the latent: s_res -> s_act8
+    kv_b: LinearPlan         # W_kvb: latent s_act8 -> k_nope, v at s_act8
+    attn: iattn.IAttnPlan    # 16 x (nope + rope) queries/keys, v heads
+    out: LinearPlan          # o-proj over H * v_head_dim: -> s_branch
+
+
+class SigmoidGatePlan(NamedTuple):
+    """The gates of a sigmoid router (DeepSeek-V3's ``noaux_tc``): the
+    i-sigmoid of the int32 logits at 2^-15, the routed scaling as an
+    integer at 2^-``rs_frac``, and gates at 2^-``gate_frac``."""
+    sig: iact.ISigmoidPlan
+    rs_b: int
+    rs_frac: int
+    gate_frac: int
+
+
+#: a sigmoid router's gate grid and routed-scaling precision: gates at
+#: 2^-12 times a residual branch of at most 14 bits stay within int32
+#: for a routed scaling below 8
+GATE_FRAC, RS_FRAC = 12, 10
+
+
+def make_sigmoid_gate(s_logit: float, qmax_logit: int,
+                      routed_scaling: float) -> SigmoidGatePlan:
+    rs_b = int(round(routed_scaling * (1 << RS_FRAC)))
+    if not 0 < rs_b < 8 << RS_FRAC:
+        raise ValueError(f"routed_scaling {routed_scaling} outside (0, 8)")
+    return SigmoidGatePlan(iact.make_isigmoid(s_logit, qmax_logit), rs_b,
+                           RS_FRAC, GATE_FRAC)
+
+
 class FfnPlan(NamedTuple):
     up: LinearPlan           # w1 (and w3): s_act8 -> s_act10
     act_gelu: Optional[iact.IGeluActPlan]
@@ -74,9 +109,8 @@ class FfnPlan(NamedTuple):
 
 class MoePlan(NamedTuple):
     router: LinearPlan       # s_act8 -> int32 logits
-    gate_sm: ism.ISoftmaxPlan
+    gate: Union[ism.ISoftmaxPlan, SigmoidGatePlan]   # by ``cfg.router``
     expert: FfnPlan
-    dn_combine: Dyadic       # sum_k gate*y (s_act8 * 2^-7) -> s_res
     shared: Optional[FfnPlan]
 
 
@@ -125,7 +159,7 @@ class LayerPlans(NamedTuple):
     cfg_name: str
     embed: EmbedPlan
     norm: norms.INormPlan
-    attn: Optional[AttnPlan]
+    attn: Optional[Union[AttnPlan, MlaPlan]]    # MlaPlan where MLA
     ffn: Optional[FfnPlan]
     moe: Optional[MoePlan]
     mamba: Optional[MambaPlan]
@@ -172,8 +206,17 @@ def build_layer_plans(cfg: ArchConfig, calib: Optional[dict] = None
         ia = iattn.make_iattention(cfg.hd, s8, s8, s8, s8,
                                    score_scale=cfg.attention_multiplier)
         out = make_linear_plan(s8, S_W8, cfg.s_branch,
-                               cfg.n_heads * cfg.hd, out_bits=14)
-        attn = AttnPlan(qkv, ia, out)
+                               cfg.n_heads * cfg.vd, out_bits=14)
+        if cfg.is_mla:
+            r = cfg.kv_lora_rank
+            attn = MlaPlan(
+                qkv, make_linear_plan(s8, S_W8, cfg.s_res, d, out_bits=14),
+                norms.make_inorm(r, cfg.s_res, cfg.qmax_res,
+                                 s_gamma=2.0 / 127.0, s_out=s8,
+                                 subtract_mean=False),
+                make_linear_plan(s8, S_W8, s8, r), ia, out)
+        else:
+            attn = AttnPlan(qkv, ia, out)
         if cfg.family in ("encdec", "vlm"):
             cross = attn
 
@@ -182,15 +225,18 @@ def build_layer_plans(cfg: ArchConfig, calib: Optional[dict] = None
         router = make_linear_plan(s8, S_W8, 0.0, d)
         # router logits int32 at s8 * s_router (per-tensor router weights)
         s_router = calib.get("s_router", S_W8)
-        gate_sm = ism.make_isoftmax(s8 * s_router, router.acc_qmax)
+        if cfg.router == "sigmoid":
+            gate = make_sigmoid_gate(s8 * s_router, router.acc_qmax,
+                                     cfg.routed_scaling)
+        else:
+            gate = ism.make_isoftmax(s8 * s_router, router.acc_qmax)
         f = cfg.moe_d_ff or cfg.d_ff
         expert = _ffn_plan(cfg, d, f)
-        dn_combine = fit_dyadic(s8 * ism.S_PROB / cfg.s_branch,
-                                cfg.top_k * 127 * 127)
         shared = _ffn_plan(cfg, d, f * cfg.n_shared_experts) \
             if cfg.n_shared_experts else None
-        moe = MoePlan(router, gate_sm, expert, dn_combine, shared)
-    if cfg.family != "ssm" and not (cfg.n_experts and cfg.moe_every == 1):
+        moe = MoePlan(router, gate, expert, shared)
+    if cfg.family != "ssm" and not (cfg.n_experts and cfg.moe_every == 1
+                                    and not cfg.first_k_dense):
         ffn = _ffn_plan(cfg, d, cfg.d_ff)
 
     mamba = None

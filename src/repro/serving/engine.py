@@ -250,6 +250,7 @@ class ServingEngine:
         if prefill_budget is not None and prefill_budget < 1:
             raise ValueError("prefill_budget must be >= 1 token/step, "
                              f"got {prefill_budget}")
+        it.refuse_mla(cfg, "ServingEngine")
         self.cfg = cfg
         self.plans = plans
         self.qparams = qparams

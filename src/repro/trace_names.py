@@ -30,6 +30,7 @@ KERNELS = (
     "int_attention_fused",      # kernels/int_attention_fused.py
     "int_paged_prefill_fused",  # kernels/int_attention_fused.py
     "int_decode_attention",     # kernels/int_decode_attention.py
+    "int_grouped_matmul",       # kernels/int_grouped_matmul.py
 )
 
 #: sublayer scopes; a norm's scope is its parameter's name
@@ -39,6 +40,10 @@ SCOPES = (
     "attn", "cross_attn", "ssm",
     "residual",
     "ffn.up", "ffn.act", "ffn.down", "moe",
+    # the MoE sublayer's stages, nested inside ``moe``: routing, the
+    # sort of (token, choice) pairs by expert, the grouped expert
+    # matmuls with i-SiLU between, and the gated gather-add
+    "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
     "head",
 )
 

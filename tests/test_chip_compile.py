@@ -292,3 +292,41 @@ def test_layernorm_compiles(one_chip, lead, d, subtract_mean):
                                     interpret=False)
     _compile(one_chip, fn, *shapes)
 
+
+
+# ---------------------------------------------------------- Moonlight --
+
+# Moonlight-16B-A3B: 16 heads, queries/keys 128 + 64 wide, values 128,
+# d 2048, routed experts 1408 wide; the benchmark cell's call is 64 x
+# 512 tokens, 6 of 64 experts a token, 16 held
+M_H, M_D, M_DV, M_MODEL, M_FE, M_HELD = 16, 192, 128, 2048, 1408, 16
+M_ROWS = 64 * 512 * 6
+
+
+def test_mla_prefill_attention_compiles(one_chip):
+    """Causal attention with values narrower than queries and keys, at
+    the cell's call, per-tensor epilogue (as the model runs it)."""
+    from repro.kernels.int_attention_fused import int_attention_fused
+    plan = _attn_plan(M_D)
+
+    def fn(q8, k8, v8):
+        return int_attention_fused(q8, k8, v8, plan, causal=True,
+                                   interpret=False)
+    _compile(one_chip, fn, ((64, 512, M_H, M_D), I8),
+             ((64, 512, M_H, M_D), I8), ((64, 512, M_H, M_DV), I8))
+
+
+@pytest.mark.parametrize("launch", ["w13", "w2"])
+def test_grouped_matmul_compiles(one_chip, launch):
+    """The dropless MoE's expert launches at the cell's rows: w1 and w3
+    side by side to 11-bit int32, then w2 to the 14-bit branch, each
+    with its blocks from the shape (``int_grouped_matmul.gmm_blocks``)."""
+    from repro.kernels.int_grouped_matmul import int_grouped_matmul_pallas
+    k, n = (M_MODEL, 2 * M_FE) if launch == "w13" else (M_FE, M_MODEL)
+    out_bits = 11 if launch == "w13" else 14
+
+    def fn(x8, w8, gs, bvec):
+        return int_grouped_matmul_pallas(x8, w8, gs, bvec, c=24, pre=8,
+                                         out_bits=out_bits, interpret=False)
+    _compile(one_chip, fn, ((M_ROWS, k), I8), ((M_HELD, k, n), I8),
+             ((M_HELD,), I32), ((M_HELD, n), I32))

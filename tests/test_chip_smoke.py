@@ -32,6 +32,14 @@ def test_roberta_phase_reduced():
     assert -1.0 <= out["corr"] <= 1.0
 
 
+def test_moonlight_phase_reduced():
+    cfg = M.reduce_config(get_config("moonlight-16b-a3b"), n_experts=16,
+                          top_k=4)
+    out = chip_smoke.moonlight_phase(cfg, batch=2, seq=32)
+    assert out["bit_exact"]
+    assert out["moe_rows"] == [2 * 32 * 4] * (cfg.num_layers - 1)
+
+
 def test_granite_phase_reduced():
     out = chip_smoke.granite_phase(traffic=SMALL, reduced=True)
     fused, ref = out["pallas_fused"], out["ref"]

@@ -239,8 +239,7 @@ def test_prefill_wo_fold_rejects_non_int8_attention_epilogue(rng):
 
 @pytest.fixture(scope="module")
 def engine_setup():
-    cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32",
-                          capacity_factor=8.0)
+    cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32")
     params = tf.init_params(jax.random.key(0), cfg)
     qp, plans = convert.quantize_params(params, cfg)
     return cfg, qp, plans

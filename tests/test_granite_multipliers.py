@@ -23,18 +23,23 @@ NEUTRAL = dict(embedding_multiplier=1.0, attention_multiplier=None,
 
 #: sha256 (first 16 hex digits) of ``repr(build_layer_plans(cfg))`` of
 #: every registry config before the multipliers existed; Granite's is of
-#: its config with the multipliers set neutral
+#: its config with the multipliers set neutral; Moonlight's (MLA, a
+#: sigmoid router) is of its plans as they were when it was added.  The
+#: MoE configs' are of those plans with ``MoePlan.gate_sm`` named
+#: ``gate`` and the combine's dyadic (``dn_combine``, which the dropless
+#: combine no longer runs) left out: the same text otherwise
 PLAN_DIGESTS = {
     "codeqwen1.5-7b": "533c62a3a3c17f0d",
     "deit-s": "5386a9dff2b17097",
     "granite-3-2b": "47cb8622f6a3eab0",
     "h2o-danube-3-4b": "3c4e66cef24e8bff",
-    "jamba-v0.1-52b": "83d73b94122ab43e",
+    "jamba-v0.1-52b": "9a12434b25566691",
     "llama-3.2-vision-90b": "c58ecb4401a73001",
     "llama3-8b": "f0c355138321c44d",
     "mamba2-130m": "a42475b8adce15a4",
-    "qwen2-moe-a2.7b": "9eff3b24167ddf86",
-    "qwen3-moe-235b-a22b": "2a7f1ef8f33790a2",
+    "moonlight-16b-a3b": "7f00e2f72b13432a",
+    "qwen2-moe-a2.7b": "f57501c516b9237b",
+    "qwen3-moe-235b-a22b": "9fe49051530df8d9",
     "roberta-base": "434aed00a2c504e3",
     "roberta-large": "2c36005659424a3d",
     "seamless-m4t-large-v2": "1efbe59a5c8062e2",

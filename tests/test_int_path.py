@@ -15,8 +15,7 @@ FAMS = ["llama3-8b", "h2o-danube-3-4b", "mamba2-130m", "qwen2-moe-a2.7b"]
 
 
 def _setup(name, b=2, s=16):
-    cfg = M.reduce_config(get_config(name), dtype="float32",
-                          capacity_factor=8.0)
+    cfg = M.reduce_config(get_config(name), dtype="float32")
     params = tf.init_params(jax.random.key(0), cfg)
     batch = {"tokens": jax.random.randint(jax.random.key(1), (b, s), 0,
                                           cfg.vocab)}

@@ -11,8 +11,7 @@ from repro.serving import Request, ServingEngine
 
 @pytest.fixture(scope="module")
 def engine_setup():
-    cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32",
-                          capacity_factor=8.0)
+    cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32")
     params = tf.init_params(jax.random.key(0), cfg)
     qp, plans = convert.quantize_params(params, cfg)
     return cfg, qp, plans

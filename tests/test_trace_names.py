@@ -97,11 +97,20 @@ def _int_decode_attention():
             ((2, 1, 2, 32), I8), cache, cache, ((2,), I32))
 
 
+def _int_grouped_matmul():
+    from repro.kernels.int_grouped_matmul import int_grouped_matmul_pallas
+    return (lambda x, w, gs, b: int_grouped_matmul_pallas(
+                x, w, gs, b, c=20, pre=4, tm=16, tn=128, interpret=True),
+            ((64, 128), I8), ((4, 128, 256), I8), ((4,), I32),
+            ((4, 256), I32))
+
+
 LAUNCHES = {"int8_matmul": _int8_matmul, "int_norm": _int_norm,
             "int_gelu": _int_gelu,
             "int_attention_fused": _int_attention_fused,
             "int_paged_prefill_fused": _int_paged_prefill_fused,
-            "int_decode_attention": _int_decode_attention}
+            "int_decode_attention": _int_decode_attention,
+            "int_grouped_matmul": _int_grouped_matmul}
 
 
 def test_every_kernel_has_a_launch_case():
@@ -196,12 +205,39 @@ def test_int_prefill_names_every_sublayer_and_launch(tiny_models, arch,
             assert owner[k[0]] & set(p.split("/")), p
 
 
+def test_moe_stages_nest_inside_moe(tiny_models):
+    """Moonlight's MoE sublayer: its four stages are scopes inside
+    ``moe`` (so a reader of ``moe`` alone still sees all of it), the
+    grouped launch runs under ``moe.experts``, and the MLA attention
+    launch under ``attn``."""
+    cfg, qp, plans = tiny_models("moonlight-16b-a3b")
+
+    def encode(q, t):
+        return it.int_prefill(q, {"tokens": t}, plans, cfg,
+                              ops="pallas_fused")
+    text = jax.jit(encode).lower(
+        qp, jax.ShapeDtypeStruct((2, 32), I32)).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    stages = ("moe.route", "moe.dispatch", "moe.experts", "moe.combine")
+    for st in stages:
+        inside = [p.split("/") for p in paths if st in p.split("/")]
+        assert inside, st
+        assert all("moe" in c[:c.index(st)] for c in inside), st
+    found = {k[0] for k in map(_kernels_in, paths) if k}
+    assert {"int_grouped_matmul", "int_attention_fused"} <= found
+    for p in paths:
+        comps = p.split("/")
+        if "int_grouped_matmul" in _kernels_in(p):
+            assert "moe.experts" in comps, p
+        if "int_attention_fused" in _kernels_in(p):
+            assert "attn" in comps, p
+
+
 # ------------------------------------------------------- engine names --
 
 @pytest.fixture(scope="module")
 def spec_engine():
-    cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32",
-                          capacity_factor=8.0)
+    cfg = M.reduce_config(get_config("llama3-8b"), dtype="float32")
     params = tf.init_params(jax.random.key(0), cfg)
     qp, plans = convert.quantize_params(params, cfg)
     return ServingEngine(qp, plans, cfg, batch_size=2, cache_len=64,
